@@ -29,8 +29,10 @@ def init_distributed(cfg: TrainConfig) -> None:
     allgathers a hash of its rank-invariant config and fails fast on
     divergence (SURVEY.md §5.2 — a mismatched rank would otherwise
     deadlock in the first collective)."""
+    from tpudml.core.compile_cache import enable_compile_cache
     from tpudml.core.dist import assert_same_program, distributed_init
 
+    enable_compile_cache()
     distributed_init(cfg.dist)
     assert_same_program(cfg.fingerprint(), "task config")
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 import math
 
 from tasks.common import final_checkpoint, setup_checkpointing
+from tpudml.core.compile_cache import enable_compile_cache
 from tpudml.core.config import TrainConfig, build_parser, config_from_args
 from tpudml.core.prng import seed_key
 from tpudml.data import DataLoader, load_dataset
@@ -39,6 +40,7 @@ def reference_defaults() -> TrainConfig:
 
 
 def run(cfg: TrainConfig) -> dict:
+    enable_compile_cache()
     train_set = load_dataset(
         cfg.data.dataset, cfg.data.data_dir, "train",
         synthetic_fallback=cfg.data.synthetic_fallback,

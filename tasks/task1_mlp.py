@@ -14,6 +14,7 @@ Run: ``python -m tasks.task1_mlp [--epochs 10] [--optimizer sgd] ...``
 from __future__ import annotations
 
 from tpudml.api import LossMonitor, Model
+from tpudml.core.compile_cache import enable_compile_cache
 from tpudml.core.config import TrainConfig, build_parser, config_from_args
 from tpudml.data import DataLoader, load_dataset
 from tpudml.metrics import MetricsWriter
@@ -31,6 +32,7 @@ def reference_defaults() -> TrainConfig:
 
 
 def run(cfg: TrainConfig) -> dict:
+    enable_compile_cache()
     train_set = load_dataset(
         cfg.data.dataset, cfg.data.data_dir, "train",
         synthetic_fallback=cfg.data.synthetic_fallback,

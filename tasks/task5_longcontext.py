@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from tpudml.capabilities import reject
+from tpudml.core.compile_cache import enable_compile_cache
 from tpudml.core.config import MeshConfig
 from tpudml.core.dist import assert_same_program, distributed_init, make_mesh
 from tpudml.core.prng import seed_key
@@ -371,6 +372,7 @@ def build_engine(args, devices):
 def run(args) -> dict:
     if args.steps < 1:
         raise ValueError("--steps must be >= 1")
+    enable_compile_cache()
     distributed_init()
     # Same-program guard (SURVEY.md §5.2): all ranks must agree on argv
     # (minus host-local paths, which may be rank-templated).
@@ -416,6 +418,7 @@ def run(args) -> dict:
     rng = np.random.default_rng(args.seed)
     t0 = None
     loss = float("nan")
+    loss_history = []  # (step, loss) at every logged step
     hit_target = None
     time_to_target = None
     final_step = args.steps
@@ -441,6 +444,7 @@ def run(args) -> dict:
         logged = args.log_every and i % args.log_every == 0
         if logged:
             loss = float(metrics["loss"])
+            loss_history.append((i, loss))
             writer.add_scalar("Train Loss", loss, i)
             print(f"step {i}: loss {loss:.4f}")
         if args.target_loss is not None and t0 is not None and (logged or (
@@ -494,6 +498,10 @@ def run(args) -> dict:
         "steps_run": final_step,
         "target_reached_at": hit_target,
         "time_to_target_s": time_to_target,
+        "loss_history": loss_history,
+        # The final (placed) state, so a caller can keep training from it
+        # or inspect where the engine put each leaf.
+        "train_state": ts,
     }
 
 

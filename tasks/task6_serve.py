@@ -34,6 +34,7 @@ import argparse
 
 import jax
 
+from tpudml.core.compile_cache import enable_compile_cache
 from tpudml.core.dist import assert_same_program, distributed_init
 from tpudml.metrics import MetricsWriter
 from tpudml.models import TransformerLM
@@ -140,6 +141,7 @@ def build_engine(args) -> ServingEngine:
 
 
 def run(args) -> dict:
+    enable_compile_cache()
     distributed_init()
     rank_invariant = {k: v for k, v in vars(args).items() if k != "log_dir"}
     assert_same_program(repr(sorted(rank_invariant.items())), "task6 args")
@@ -221,6 +223,7 @@ def run(args) -> dict:
         "mean_accepted_len": report.mean_accepted_len,
         "pool_stats": report.pool_stats,
         "trace_path": str(trace_path) if trace_path else None,
+        "tokens": {rid: list(st.tokens) for rid, st in report.requests.items()},
         **lat,
     }
 

@@ -1,6 +1,6 @@
 """J112 firing: a shard_map body computes a per-shard partial (the mean
 of its local batch slice) and returns it through ``out_specs=P()`` —
-declared replicated — with no reducing collective. check_rep=False (the
+declared replicated — with no reducing collective. check_vma=False (the
 engines' setting, forced by custom_vjp regions) means JAX never checks
 the claim: every device silently returns a different loss. This is the
 missing-psum / lost-transpose-factor class the fused-xent backward had

@@ -121,7 +121,9 @@ def test_grouped_dw_bf16_in_f32_accum():
     want = _stock_dw(xb.astype(jnp.float32), gb.astype(jnp.float32), gs)
     got = grouped_dw(xb, gb, gs, tiling=(8, 128, 128), interpret=True)
     assert got.dtype == jnp.float32  # accumulator dtype survives to the output
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+    # One f32 ulp of reassociation on this XLA: 2 of 3072 elements differ
+    # by 1.19e-7 absolute (2.8e-6 relative) from the stock contraction.
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=5e-6)
 
 
 def test_grouped_dw_validates_operands():

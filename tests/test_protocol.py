@@ -184,7 +184,7 @@ def test_traced_collective_signatures_drive_p302():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import numpy as np
 

@@ -1,0 +1,219 @@
+"""The kernels of ``chip_smoke.py``'s path, compiled by the chip's own
+compiler for a DESCRIBED ``v5e:2x2`` at the flagship widths (the r05
+"large" row: B=8, T=2048, 8 heads × 128, d=1024, V=32768).
+
+Interpret-mode parity tests cannot see what Mosaic refuses — a tile that
+outgrows scoped VMEM, a slice off the native tiling — and a chip run costs
+chip time. These compiles cost ~2 s each and no chip: nothing runs, so they
+say nothing about results or speed; they only guard that every later PR
+still hands the compiler kernels it accepts.
+
+Everything that touches the topology lives in the module-scoped fixtures
+below (never at import): only one process may hold the TPU library, so only
+the worker that is handed this file may load it. All of these tests stay in
+this one file for the same reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+B, T, H, DH, D, V = 8, 2048, 8, 128, 1024, 32768
+N = B * T
+SERVE_SLOTS, SERVE_CHUNK = 8, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A described-device compile is written to a persistent cache but can
+    # never be read back without the chip; keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, structs) -> str:
+    """The chip compiler's verdict on ``fn``: raises what the chip would
+    refuse; returns the program text, which must hold a Pallas kernel."""
+    text = jax.jit(fn).lower(*structs).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Compile ``fn`` for one described chip from ``(shape, dtype)``
+    operands."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def compile_for_chip(fn, *operands):
+        return _compile(fn, [jax.ShapeDtypeStruct(s, dt, sharding=one)
+                             for s, dt in operands])
+
+    return compile_for_chip
+
+
+def _grad_sum(fn, argnums):
+    """Scalar-ised fwd+bwd of ``fn`` w.r.t. ``argnums`` (sums every
+    output so each one's cotangent path is compiled)."""
+    def loss(*a):
+        out = fn(*a)
+        leaves = jax.tree.leaves(out)
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in leaves)
+
+    return jax.value_and_grad(loss, argnums=argnums)
+
+
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+def test_flash_attention_fwd_bwd_bf16(chip):
+    from tpudml.ops.attention_kernel import flash_attention
+
+    qkv = ((B, T, H, DH), bf16)
+    chip(_grad_sum(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False), (0, 1, 2)), qkv, qkv, qkv)
+
+
+def test_fused_add_layernorm_fwd_bwd(chip):
+    from tpudml.ops.layernorm_kernel import fused_add_layernorm
+
+    chip(_grad_sum(lambda x, r, s, b: fused_add_layernorm(
+        x, r, s, b, interpret=False), (0, 1, 2, 3)),
+        ((N, D), bf16), ((N, D), bf16), ((D,), f32), ((D,), f32))
+
+
+@pytest.mark.parametrize("save_s", [True, False], ids=["save_s", "lean"])
+def test_linear_cross_entropy_fwd_bwd(chip, save_s):
+    from tpudml.ops.xent_kernel import linear_cross_entropy
+
+    chip(_grad_sum(lambda x, w, y: linear_cross_entropy(
+        x, w, y, interpret=False, save_s=save_s), (0, 1)),
+        ((N, D), bf16), ((D, V), bf16), ((N,), i32))
+
+
+@pytest.mark.parametrize("w_dtype", [f32, bf16], ids=["f32", "bf16"])
+def test_fused_decode_head(chip, w_dtype):
+    from tpudml.ops.decode_head import fused_decode_head
+
+    chip(lambda x, w, b: fused_decode_head(x, w, b, interpret=False),
+         ((SERVE_SLOTS, D), w_dtype), ((D, V), w_dtype), ((V,), w_dtype))
+
+
+def test_fused_decode_head_int8(chip):
+    from tpudml.ops.decode_head import fused_decode_head_int8
+
+    chip(lambda x, wq, s: fused_decode_head_int8(x, wq, s, interpret=False),
+         ((SERVE_SLOTS, D), f32), ((D, V), i8), ((V,), f32))
+
+
+def test_grouped_dw(chip):
+    from tpudml.ops.moe_kernel import grouped_dw
+
+    chip(lambda x, g, gs: grouped_dw(x, g, gs, interpret=False),
+         ((N, D), bf16), ((N, 4 * D), bf16), ((8,), i32))
+
+
+def test_fused_attn_junction_fwd_bwd(chip):
+    from tpudml.ops.junction_kernel import fused_attn_junction
+
+    qkv = ((B, T, H, DH), bf16)
+    chip(_grad_sum(lambda q, k, v, r, wo, bo, s, b: fused_attn_junction(
+        q, k, v, r, wo, bo, s, b, interpret=False), tuple(range(8))),
+        qkv, qkv, qkv, ((B, T, D), bf16), ((D, D), bf16), ((D,), bf16),
+        ((D,), f32), ((D,), f32))
+
+
+@pytest.mark.parametrize("dtype", [f32, bf16], ids=["f32", "bf16"])
+def test_serving_chunk_window_flash(chip, dtype):
+    """Chunked prefill's window attention at a non-zero chunk index: one
+    SERVE_CHUNK-token chunk at global offset 3·chunk over its [0, 4·chunk)
+    window (task6_serve builds an f32 model; bf16 is the trained one)."""
+    from tpudml.nn.attention import _chunk_flash_window
+
+    start = 3 * SERVE_CHUNK
+    chip(lambda q, k, v: _chunk_flash_window(q, k, v, start),
+         ((1, SERVE_CHUNK, H, DH), dtype),
+         ((1, start + SERVE_CHUNK, H, DH), dtype),
+         ((1, start + SERVE_CHUNK, H, DH), dtype))
+
+
+# ------------------------------------------------- across the four chips
+# What exists only on a mesh: the SPMD partitioner refuses a bare Mosaic
+# kernel, so under the GSPMD engines the kernels run per shard; and the
+# vocab-sharded head merges per-shard statistics with collectives.
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """Compile ``fn(mesh, *operands)`` for the four described chips as one
+    ``axis`` mesh from ``(shape, dtype, PartitionSpec)`` operands."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    def compile_on_mesh(fn, axis, *operands):
+        mesh = Mesh(np.array(topo.devices), (axis,))
+        return _compile(
+            lambda *a: fn(mesh, *a),
+            [jax.ShapeDtypeStruct(s, dt, sharding=NamedSharding(mesh, spec))
+             for s, dt, spec in operands])
+
+    return compile_on_mesh
+
+
+@pytest.mark.parametrize("axis,batch,head", [("data", "data", None),
+                                             ("model", None, "model")],
+                         ids=["fsdp_layout", "tp_layout"])
+def test_trunk_kernels_per_shard_under_gspmd(mesh4, axis, batch, head):
+    """flash attention + the add+LN junction inside a GSPMD-partitioned
+    jit, fwd+bwd, under the layout the FSDP / TP engines declare."""
+    from jax.sharding import PartitionSpec as P
+
+    from tpudml.ops.attention_kernel import flash_attention
+    from tpudml.ops.layernorm_kernel import fused_add_layernorm
+    from tpudml.parallel.sharding import kernel_layout
+
+    def trunk(mesh, q, k, v, r, s, b):
+        def loss(q, k, v, r, s, b):
+            with kernel_layout(mesh, batch=batch, head=head):
+                o = flash_attention(q, k, v, causal=True, interpret=False)
+                o = o.reshape(r.shape)
+                stream, y = fused_add_layernorm(r, o, s, b, interpret=False)
+            return jnp.sum(y.astype(f32)) + jnp.sum(stream.astype(f32))
+
+        return jax.grad(loss, argnums=tuple(range(6)))(q, k, v, r, s, b)
+
+    qkv = ((4, T, H, DH), bf16, P(batch, None, head, None))
+    mesh4(trunk, axis, qkv, qkv, qkv, ((4, T, D), bf16, P(batch)),
+          ((D,), f32, P()), ((D,), f32, P()))
+
+
+def test_sharded_linear_cross_entropy_fwd_bwd(mesh4):
+    """The vocab-sharded fused head (TP): per-shard kernel + lse merge."""
+    from jax.sharding import PartitionSpec as P
+
+    from tpudml.ops.xent_kernel import sharded_linear_cross_entropy
+    from tpudml.parallel.sharding import shard_map_fn
+
+    def head(mesh, x, w, y):
+        region = shard_map_fn(
+            lambda x, w, y: sharded_linear_cross_entropy(
+                x, w, y, axis_name="model", interpret=False),
+            mesh, in_specs=(P(), P(None, "model"), P()), out_specs=P())
+        return jax.value_and_grad(region, argnums=(0, 1))(x, w, y)
+
+    mesh4(head, "model", ((4 * T, D), bf16, P()),
+          ((D, V), bf16, P(None, "model")), ((4 * T,), i32, P()))

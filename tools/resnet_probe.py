@@ -89,7 +89,7 @@ def run_layers(peak, batch=None, convs=None, fwd_too=True):
             return pull(y)  # dX and dW with dY = y (shape-right cotangent)
 
         f = conv_flops(ci, co, h, w, k, stride, batch)
-        # Sub-ms kernels: long fori windows so relay jitter differences out.
+        # Sub-ms kernels: long fori windows so host-timing jitter differences out.
         line = f"   {name:26s} x{count}:"
         if fwd_too:
             t_fwd = time_fn(f"{name} fwd", conv, x, wgt, iters_lo=24, iters_hi=96)

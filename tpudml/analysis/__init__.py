@@ -16,7 +16,7 @@ full rule catalogue):
   without ``block_until_ready``;
 - the **dataflow pass** (``dataflow``, rules J112–J116) abstractly
   interprets the same traced programs under a per-(value, mesh-axis)
-  replication lattice — missing psums under ``check_rep=False``,
+  replication lattice — missing psums under ``check_vma=False``,
   shard-dependent while trip counts around collectives, donated-buffer
   reuse, allreduce-then-shard waste — and feeds the static comm/HBM
   cost reports in ``cost`` (``--cost`` / ``analysis/cost_report.json``);

@@ -13,7 +13,7 @@ every value and every mesh axis, an element of the replication lattice
     ``sharded``     a GLOBAL array dim-partitioned over the axis
                     (outside-shard_map state, seeded from in_specs)
     ``varying``     per-shard bytes may differ (derived from
-                    in_names-split data or ``axis_index`` without an
+                    in_specs-split data or ``axis_index`` without an
                     intervening reducing collective)
     ``unknown``     no claim (join of conflicting facts)
 
@@ -37,9 +37,9 @@ Transfer rules for the collectives that matter:
 On top of the walk this module implements:
 
 - **J112** (missing psum / lost transpose factor): a ``shard_map``
-  output whose ``out_names`` declare it UNSHARDED over a bound axis
+  output whose ``out_specs`` declare it UNSHARDED over a bound axis
   while the body value is ``varying`` over that axis. With
-  ``check_rep=False`` (every engine here — custom_vjp regions force it)
+  ``check_vma=False`` (every engine here — custom_vjp regions force it)
   JAX cannot catch this, and each device silently returns different
   bytes for a nominally replicated global — the exact class of bug the
   fused cross-entropy backward had to hand-fix with an out-cotangent
@@ -99,15 +99,12 @@ def _repo_rel(path: str) -> str:
 
 def _src_loc(eqn) -> tuple[str, int]:
     """(file, line) of the user frame that built an equation."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return _repo_rel(frame.file_name), int(frame.start_line)
-    except Exception:
-        pass
-    return "", 0
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "", 0
+    return _repo_rel(frame.file_name), int(frame.start_line)
 
 
 def _axis_strs(value: Any) -> tuple[str, ...]:
@@ -119,6 +116,16 @@ def _axis_strs(value: Any) -> tuple[str, ...]:
             out.extend(_axis_strs(v))
         return tuple(out)
     return ()
+
+
+def shard_map_dim_axes(specs) -> list[dict[int, tuple[str, ...]]]:
+    """Per operand of a ``shard_map`` equation, ``{dim: mesh axes that
+    split it}``, read from its ``in_specs`` / ``out_specs`` params
+    (PartitionSpecs)."""
+    return [
+        {d: _axis_strs(entry) for d, entry in enumerate(spec) if entry}
+        for spec in specs or ()
+    ]
 
 
 def _eqn_axes(eqn) -> tuple[str, ...]:
@@ -354,8 +361,8 @@ class _Interpreter:
     def _shard_map(self, eqn, trips: int) -> None:
         mesh = eqn.params.get("mesh")
         body = eqn.params.get("jaxpr")
-        in_names = eqn.params.get("in_names")
-        out_names = eqn.params.get("out_names")
+        in_names = shard_map_dim_axes(eqn.params.get("in_specs"))
+        out_names = shard_map_dim_axes(eqn.params.get("out_specs"))
         if mesh is None or body is None:
             return
         try:
@@ -365,15 +372,15 @@ class _Interpreter:
             mesh_axes = {str(a): int(mesh.shape[a]) for a in mesh.axis_names}
         self.result.axis_sizes.update(mesh_axes)
         jaxpr = _inner_jaxpr(body)
-        # Body invar states are fully determined by in_names: axes the
-        # names split a dim over differ per shard; the rest of the bound
+        # Body invar states are fully determined by in_specs: axes the
+        # specs split a dim over differ per shard; the rest of the bound
         # axes see identical bytes of the one global value. Axes bound
         # further out (nested shard_map) propagate from the outer state.
-        for var, names in zip(jaxpr.invars, in_names or ()):
+        for var, names in zip(jaxpr.invars, in_names):
             st: AxisState = {}
             split_axes = set()
-            for dim_axes in (names or {}).values():
-                split_axes.update(str(a) for a in _axis_strs(tuple(dim_axes)))
+            for dim_axes in names.values():
+                split_axes.update(dim_axes)
             for a in mesh_axes:
                 st[a] = VARYING if a in split_axes else REPLICATED
             self.set_state(var, st)
@@ -390,20 +397,18 @@ class _Interpreter:
                         st[a] = UNKNOWN
                 self.set_state(var, st)
         self.interpret(body, trips)
-        check_rep = bool(eqn.params.get("check_rep", False))
-        for ov, body_ov, names in zip(
-            eqn.outvars, jaxpr.outvars, out_names or ()
-        ):
+        check_vma = bool(eqn.params.get("check_vma", False))
+        for ov, body_ov, names in zip(eqn.outvars, jaxpr.outvars, out_names):
             declared = set()
-            for dim_axes in (names or {}).values():
-                declared.update(str(a) for a in _axis_strs(tuple(dim_axes)))
+            for dim_axes in names.values():
+                declared.update(dim_axes)
             body_st = self.state(body_ov)
             out_st: AxisState = {}
             for a in mesh_axes:
                 if a in declared:
                     out_st[a] = SHARDED
                 elif body_st.get(a, REPLICATED) == VARYING:
-                    if not check_rep:
+                    if not check_vma:
                         prod_eqn = self._producer_of(jaxpr, body_ov)
                         f, ln = (_src_loc(prod_eqn) if prod_eqn is not None
                                  else _src_loc(eqn))
@@ -414,7 +419,7 @@ class _Interpreter:
                             f"per shard — no reducing collective (psum/"
                             f"all_gather) stands between the shard-local "
                             f"computation and the replicated output; with "
-                            f"check_rep=False each device silently returns "
+                            f"check_vma=False each device silently returns "
                             f"different bytes (the missing-psum / lost "
                             f"transpose-factor class)",
                             file=f, line=ln, entrypoint=self.entrypoint,

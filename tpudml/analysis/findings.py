@@ -49,7 +49,7 @@ RULES: dict[str, tuple[str, str]] = {
                    "poisons the weights unrecoverably)"),
     "J112": (ERROR, "shard_map output declared replicated over an axis the "
                     "body value varies on (missing psum / lost transpose "
-                    "factor under check_rep=False)"),
+                    "factor under check_vma=False)"),
     "J113": (ERROR, "while loop trip count varies per shard while its "
                     "body/cond issue collectives over the same axis "
                     "(collective imbalance: the slice deadlocks)"),

@@ -31,11 +31,11 @@ multi-host slice:
         sight — every chip pays the full optimizer FLOPs/HBM, the exact
         waste ZeRO-1 weight-update sharding (``optim.zero1``) removes.
 - J109  ``lax.ragged_dot``'s stock grouped-transpose dW surviving into a
-        backward: both dW operands materialized as ``[E, P, ·]``
-        range-masked broadcasts feeding a batched ``dot_general`` — E×
-        the dense dW FLOPs (the 3.4× ragged-MoE backward of BASELINE
-        round 5); the grouped-dW kernel path (``ops.moe_kernel``) never
-        builds those broadcasts and stays silent.
+        backward: a ``ragged_dot_general`` that contracts its ragged
+        dim, which the generic lowering rolls out as ``[E, P, ·]``
+        range-masked operands — E× the dense dW FLOPs (the 3.4×
+        ragged-MoE backward of BASELINE round 5); the grouped-dW kernel
+        path (``ops.moe_kernel``) never emits it and stays silent.
 - J110  a decode-marked program (``tpudml.serve``'s jitted per-token
         step) that recomputes FULL-sequence attention per emitted token:
         a softmax ``exp`` over scores whose trailing two (query, key)
@@ -62,7 +62,7 @@ multi-host slice:
 Since the replication-lattice interpreter landed
 (:mod:`tpudml.analysis.dataflow`), ``analyze_closed_jaxpr`` also runs
 the sharding-aware dataflow rules over the same traced program: J112
-(missing psum under ``check_rep=False``), J113 (shard-dependent while
+(missing psum under ``check_vma=False``), J113 (shard-dependent while
 trip counts around collectives), J115 (allreduce-then-shard), and —
 when an HBM budget is supplied — J116 from the static cost walk
 (:mod:`tpudml.analysis.cost`).
@@ -72,10 +72,10 @@ The pass is backend-free: everything works on abstract values on CPU.
 
 from __future__ import annotations
 
-import os
 import re
 from typing import Any, Callable, Iterable
 
+from tpudml.analysis.dataflow import _axis_strs, _src_loc, shard_map_dim_axes
 from tpudml.analysis.findings import Finding
 
 # Primitives that require a bound axis name (J101). The subset that
@@ -88,7 +88,7 @@ COLLECTIVE_PRIMS = frozenset({
 COMM_PRIMS = COLLECTIVE_PRIMS - {"axis_index"}
 
 CALLBACK_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback", "callback",
     "outside_call", "host_callback_call", "infeed", "outfeed",
 })
 
@@ -159,44 +159,6 @@ _J111_PRESERVING = frozenset({
 })
 
 
-def _repo_rel(path: str) -> str:
-    """Repo/cwd-relative path for stable reporting + allowlist matching."""
-    if not path:
-        return path
-    cwd = os.getcwd()
-    try:
-        rel = os.path.relpath(path, cwd)
-    except ValueError:  # pragma: no cover - different drive (windows)
-        return path
-    return path if rel.startswith("..") else rel
-
-
-def _src_loc(eqn) -> tuple[str, int]:
-    """(file, line) of the user frame that built an equation."""
-    try:
-        from jax._src import source_info_util
-
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return _repo_rel(frame.file_name), int(frame.start_line)
-    except Exception:
-        pass
-    return "", 0
-
-
-def _axis_strs(value: Any) -> tuple[str, ...]:
-    """String axis names out of an ``axes``/``axis_name`` param value
-    (str | int | tuple thereof; ints are positional vmap axes)."""
-    if isinstance(value, str):
-        return (value,)
-    if isinstance(value, (tuple, list, frozenset, set)):
-        out: list[str] = []
-        for v in value:
-            out.extend(_axis_strs(v))
-        return tuple(out)
-    return ()
-
-
 def _eqn_axes(eqn) -> tuple[str, ...]:
     axes: list[str] = []
     for key in ("axes", "axis_name"):
@@ -259,12 +221,10 @@ def collective_shape_signature(obj) -> tuple:
     jaxpr, _ = _inner_jaxpr(obj)
     sig: list = []
     for eqn in jaxpr.eqns:
-        # shard_map's rewrite pass emits numbered variants (psum -> psum2)
-        # of the same wire collective; normalize so signatures compare
-        # across pmap- and shard_map-traced ranks.
-        name = eqn.primitive.name
-        if name not in COMM_PRIMS and name.rstrip("0123456789") in COMM_PRIMS:
-            name = name.rstrip("0123456789")
+        # Under shard_map(check_vma=True) psum/all_gather bind as
+        # ``*_invariant`` variants of the same wire collective; normalize
+        # so signatures compare across ranks traced either way.
+        name = eqn.primitive.name.removesuffix("_invariant")
         if name in COMM_PRIMS:
             shape = ()
             if eqn.invars:
@@ -314,64 +274,38 @@ def _check_upcasts(jaxpr, entrypoint: str, findings: list[Finding]) -> None:
 def _check_ragged_transpose(jaxpr, entrypoint: str,
                             findings: list[Finding]) -> None:
     """J109 within one jaxpr level: ``lax.ragged_dot``'s transpose rule
-    left in a backward. The stock VJP materializes BOTH dW operands as
-    ``[E, P, ·]`` range-masked broadcasts (``select_n`` of a
-    ``broadcast_in_dim`` over dims (1, 2) of a rank-2 array) and
-    contracts them with a batched ``dot_general`` over the P dim — E×
-    the dense dW FLOPs plus an E-fold activation materialization. The
-    grouped-dW path (ops.moe_kernel) never builds those broadcasts, so
-    it stays silent; only levels that also contain a ``ragged_dot``
-    (i.e. an actual ragged-MoE backward) are considered."""
-    if not any(e.primitive.name == "ragged_dot" for e in jaxpr.eqns):
-        return
-    producers = {id(v): e for e in jaxpr.eqns for v in e.outvars}
-
-    def chase(var):
-        eqn = producers.get(id(var))
-        while eqn is not None and eqn.primitive.name == "convert_element_type":
-            eqn = producers.get(id(eqn.invars[0]))
-        return eqn
-
-    def is_masked_bcast(var) -> bool:
-        eqn = chase(var)
-        if eqn is None or eqn.primitive.name != "select_n":
-            return False
-        for v in eqn.invars:
-            p = chase(v)
-            if (p is not None and p.primitive.name == "broadcast_in_dim"
-                    and tuple(p.params.get("broadcast_dimensions", ())) == (1, 2)
-                    and getattr(getattr(p.invars[0], "aval", None), "ndim",
-                                None) == 2):
-                return True
-        return False
-
+    left in a backward. The stock VJP's dW is a ``ragged_dot_general``
+    whose RAGGED dimension is the one it contracts (``[P, K] × [P, N] →
+    [E, K, N]``): JAX's generic lowering rolls it out as E range-masked
+    copies of both operands — E× the dense dW FLOPs plus an E-fold
+    activation materialization — and the chip's own instruction for it
+    has not been measured against the grouped-dW path (ops.moe_kernel),
+    which never emits the primitive and so stays silent."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name != "dot_general":
+        if eqn.primitive.name != "ragged_dot_general":
             continue
-        dims = eqn.params.get("dimension_numbers")
-        if dims != (((1,), (1,)), ((0,), (0,))):
+        dims = eqn.params["ragged_dot_dimension_numbers"]
+        (lhs_contract, _), _ = dims.dot_dimension_numbers
+        if not set(dims.lhs_ragged_dimensions) <= set(lhs_contract):
             continue
-        if any(getattr(getattr(v, "aval", None), "ndim", 0) != 3
-               for v in eqn.invars[:2]):
-            continue
-        if is_masked_bcast(eqn.invars[0]) and is_masked_bcast(eqn.invars[1]):
-            f, ln = _src_loc(eqn)
-            e_dim = eqn.invars[0].aval.shape[0]
-            findings.append(Finding(
-                "J109",
-                f"ragged_dot grouped-transpose dW: batched dot_general over "
-                f"two [{e_dim}, P, ·] range-masked broadcasts — {e_dim}× the "
-                f"dense dW FLOPs in the backward",
-                file=f, line=ln, entrypoint=entrypoint,
-            ))
+        f, ln = _src_loc(eqn)
+        e_dim = eqn.outvars[0].aval.shape[0]
+        findings.append(Finding(
+            "J109",
+            f"ragged_dot grouped-transpose dW: ragged_dot_general "
+            f"contracting its ragged dim into [{e_dim}, ·, ·] — {e_dim}× the "
+            f"dense dW FLOPs in the backward where it is rolled out as "
+            f"range masks",
+            file=f, line=ln, entrypoint=entrypoint,
+        ))
 
 
 def _fused_xent_seed(eqn) -> dict[int, tuple[str, ...]]:
     """J107 taint seed for one shard_map equation: body invars whose
-    LAST dimension the in_names shard, mapped to the sharding axes."""
-    in_names = eqn.params.get("in_names")
+    LAST dimension the in_specs shard, mapped to the sharding axes."""
+    in_names = shard_map_dim_axes(eqn.params.get("in_specs"))
     body = eqn.params.get("jaxpr")
-    if in_names is None or body is None:
+    if body is None:
         return {}
     jaxpr, _ = _inner_jaxpr(body)
     tainted: dict[int, tuple[str, ...]] = {}
@@ -379,7 +313,7 @@ def _fused_xent_seed(eqn) -> dict[int, tuple[str, ...]]:
         ndim = getattr(getattr(var, "aval", None), "ndim", 0)
         axes = names.get(ndim - 1, ()) if ndim else ()
         if axes:
-            tainted[id(var)] = tuple(str(a) for a in axes)
+            tainted[id(var)] = axes
     return tainted
 
 
@@ -393,7 +327,7 @@ def _check_fused_xent(obj, tainted: dict[int, tuple[str, ...]],
     jaxpr, _ = _inner_jaxpr(obj)
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
-        if name == "pjit":
+        if name == "jit":
             jit_name = str(eqn.params.get("name", ""))
             if jit_name == FUSED_XENT_NAME:
                 axes = (tainted.get(id(eqn.invars[1]))
@@ -447,7 +381,7 @@ def _find_wide_softmax_exp(obj):
     not attention scores."""
     jaxpr, _ = _inner_jaxpr(obj)
     for eqn in jaxpr.eqns:
-        if (eqn.primitive.name == "pjit"
+        if (eqn.primitive.name == "jit"
                 and str(eqn.params.get("name", "")) in FUSED_HEAD_NAMES):
             continue
         if eqn.primitive.name == "exp":
@@ -560,7 +494,7 @@ def _scan_unfused_tail(obj, dot_dims: set, hits: list) -> None:
     jaxpr, _ = _inner_jaxpr(obj)
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
-        if (name == "pjit"
+        if (name == "jit"
                 and str(eqn.params.get("name", "")) in FUSED_HEAD_NAMES):
             continue
         if name == "dot_general":
@@ -614,7 +548,7 @@ def _contains_pjit_named(obj, names: tuple) -> bool:
     marker ``names`` — J119's overlap-claim verification."""
     jaxpr, _ = _inner_jaxpr(obj)
     for eqn in jaxpr.eqns:
-        if (eqn.primitive.name == "pjit"
+        if (eqn.primitive.name == "jit"
                 and str(eqn.params.get("name", "")) in names):
             return True
         for sub, _extra in _sub_jaxprs(eqn):
@@ -655,14 +589,14 @@ def _check_replicated_update(eqn, entrypoint: str,
                              findings: list[Finding]) -> None:
     """J108 for one shard_map equation: the body allreduces ≥2 tensor
     gradients over a data axis, returns ≥2 matching-shape outputs
-    REPLICATED over that axis (per out_names), and never reduce-scatters
+    REPLICATED over that axis (per out_specs), and never reduce-scatters
     — i.e. a replicated weight update. A ZeRO-1 body (psum_scatter on
     the grads, state outputs sharded over the axis) stays silent, as
     does a reduce-scatter aggregation strategy."""
     mesh = eqn.params.get("mesh")
     body = eqn.params.get("jaxpr")
-    out_names = eqn.params.get("out_names")
-    if mesh is None or body is None or out_names is None:
+    out_names = shard_map_dim_axes(eqn.params.get("out_specs"))
+    if mesh is None or body is None:
         return
     axes = tuple(
         a for a in (str(x) for x in mesh.axis_names) if a in _DATA_AXIS_NAMES
@@ -682,9 +616,7 @@ def _check_replicated_update(eqn, entrypoint: str,
         shape = tuple(getattr(getattr(var, "aval", None), "shape", ()))
         if not shape or budget.get(shape, 0) <= 0:
             continue
-        sharded_over = set()
-        for dim_axes in names.values():
-            sharded_over.update(str(a) for a in _axis_strs(tuple(dim_axes)))
+        sharded_over = {a for dim_axes in names.values() for a in dim_axes}
         if any(a in sharded_over for a in axes):
             continue
         budget[shape] -= 1
@@ -787,7 +719,7 @@ def _check_donated_reuse(jaxpr, entrypoint: str,
     """
     for idx, eqn in enumerate(jaxpr.eqns):
         donated = eqn.params.get("donated_invars")
-        if eqn.primitive.name != "pjit" or not donated or not any(donated):
+        if eqn.primitive.name != "jit" or not donated or not any(donated):
             continue
         callee = str(eqn.params.get("name", "")) or "<anonymous>"
         for pos, (v, don) in enumerate(zip(eqn.invars, donated)):
@@ -860,11 +792,11 @@ def _walk(obj, bound: frozenset[str], entrypoint: str,
                     f"sequences — {desc}",
                     file=f, line=ln, entrypoint=entrypoint,
                 ))
-        if name == "pjit" and str(eqn.params.get("name", "")) == SERVE_DECODE_NAME:
+        if name == "jit" and str(eqn.params.get("name", "")) == SERVE_DECODE_NAME:
             _check_cacheless_decode(eqn, entrypoint, findings)
-        if name == "pjit" and str(eqn.params.get("name", "")) in PAGED_DECODE_NAMES:
+        if name == "jit" and str(eqn.params.get("name", "")) in PAGED_DECODE_NAMES:
             _check_full_pool_gather(eqn, entrypoint, findings)
-        if name == "pjit" and str(eqn.params.get("name", "")) in _DECODE_TAIL_NAMES:
+        if name == "jit" and str(eqn.params.get("name", "")) in _DECODE_TAIL_NAMES:
             _check_unfused_decode_tail(eqn, entrypoint, findings)
         if name == "shard_map":
             seed = _fused_xent_seed(eqn)
@@ -877,11 +809,17 @@ def _walk(obj, bound: frozenset[str], entrypoint: str,
 
 
 def _check_consts(consts, entrypoint: str, findings: list[Finding]) -> None:
+    import numpy as np
+
     for c in consts:
-        nbytes = getattr(c, "nbytes", 0)
-        if nbytes and nbytes > LARGE_CONST_BYTES:
-            shape = getattr(c, "shape", ())
-            dtype = getattr(c, "dtype", "?")
+        shape = getattr(c, "shape", None)
+        dtype = getattr(c, "dtype", None)
+        if shape is None or dtype is None:
+            continue
+        # Closed-over constants are jax TypedNdArrays, which carry
+        # shape/dtype but no ``nbytes``.
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        if nbytes > LARGE_CONST_BYTES:
             findings.append(Finding(
                 "J105",
                 f"{nbytes / (1 << 20):.1f} MiB constant "

@@ -27,17 +27,8 @@ PyTree = Any
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis.
-
-    ``lax.axis_size`` is newer than the pinned jax (0.4.37 raises
-    AttributeError — tpudml.analysis rule J100 caught this breaking every
-    ring/CP path); ``psum`` of the literal 1 is the long-standing static
-    equivalent and constant-folds to a Python int at trace time.
-    """
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a bound mesh axis (a Python int at trace time)."""
+    return lax.axis_size(axis_name)
 
 
 def pmax_tree(tree: PyTree, axis_name: str) -> PyTree:
@@ -58,9 +49,9 @@ def plogsumexp(x: jax.Array, axis_name: str) -> jax.Array:
     weight, flows entirely through the psum term. Differentiable; used
     by the vocab-sharded fused cross-entropy head to merge per-shard
     partial-vocab statistics."""
-    # stop_gradient on the INPUT, not the result: pmax has no JVP rule
-    # on the pinned jax, and with a symbolic-zero tangent the primitive
-    # is never differentiated at all.
+    # stop_gradient on the INPUT, not the result: pmax has no JVP rule,
+    # and with a symbolic-zero tangent the primitive is never
+    # differentiated at all.
     m = lax.pmax(lax.stop_gradient(x), axis_name)
     return m + jnp.log(lax.psum(jnp.exp(x - m), axis_name))
 

@@ -36,8 +36,8 @@ class DistributedConfig:
     backend: str | None = None  # None = autodetect platform
     initialize_timeout_s: int = 300
     # Cross-process collective implementation for the CPU backend. XLA's
-    # CPU client cannot run multi-process computations natively; jax
-    # 0.4.37 wires MPI or gloo underneath via
+    # CPU client cannot run multi-process computations natively; JAX
+    # wires MPI or gloo underneath via
     # ``jax_cpu_collectives_implementation``. None = auto: "gloo" whenever
     # the job is multi-process AND the platform is CPU (JAX_PLATFORMS=cpu
     # or backend="cpu"), nothing otherwise. "none" opts out explicitly.

@@ -1,17 +1,23 @@
 """Native (C++) host data-plane, bound via ctypes.
 
 Lazy-builds ``dataplane.cpp`` with g++ into a cached shared library on
-first use and exposes thin numpy wrappers. Every entry point has a pure
+first use and exposes thin numpy wrappers. The library's file name carries
+a hash of the source it was built from, so only a library built from THIS
+``dataplane.cpp`` is ever loaded — a stale git-ignored build that rode
+along with a copied tree is simply not found. Every entry point has a pure
 numpy fallback, so the framework runs unchanged where no toolchain exists
 (``TPUDML_NO_NATIVE=1`` forces the fallback; ``available()`` reports which
-path is active).
+path is active); a build that was attempted and failed says so once on
+stderr instead of falling back in silence.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from pathlib import Path
 
@@ -20,7 +26,6 @@ import numpy as np
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "dataplane.cpp"
 _BUILD_DIR = _HERE / "_build"
-_LIB_PATH = _BUILD_DIR / "libtpudml_dataplane.so"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -35,17 +40,19 @@ _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 def _build() -> ctypes.CDLL | None:
     if os.environ.get("TPUDML_NO_NATIVE"):
         return None
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    lib_path = _BUILD_DIR / f"libtpudml_dataplane-{digest}.so"
     try:
-        if not _LIB_PATH.exists() or _LIB_PATH.stat().st_mtime < _SRC.stat().st_mtime:
+        if not lib_path.exists():
             _BUILD_DIR.mkdir(exist_ok=True)
-            tmp = _LIB_PATH.with_suffix(f".tmp{os.getpid()}.so")
+            tmp = lib_path.with_suffix(f".tmp{os.getpid()}.so")
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)],
                 check=True,
                 capture_output=True,
             )
-            os.replace(tmp, _LIB_PATH)  # atomic: concurrent builders race safely
-        lib = ctypes.CDLL(str(_LIB_PATH))
+            os.replace(tmp, lib_path)  # atomic: concurrent builders race safely
+        lib = ctypes.CDLL(str(lib_path))
         lib.tpudml_gather_rows_f32.argtypes = [
             _f32p, _i64p, ctypes.c_int64, ctypes.c_int64, _f32p,
         ]
@@ -62,7 +69,13 @@ def _build() -> ctypes.CDLL | None:
         ]
         lib.tpudml_byteswap.restype = ctypes.c_int
         return lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        print(
+            f"[tpudml.native] C++ data plane unavailable, using the numpy "
+            f"path: {e!r} {detail.decode(errors='replace').strip()}",
+            file=sys.stderr,
+        )
         return None
 
 

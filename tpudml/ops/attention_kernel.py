@@ -488,7 +488,23 @@ def flash_attention(
     pair; ``block_q``/``block_k`` default (None) to the measured-best
     tiles for the head dim (``_default_blocks``: the forward prefers
     large Q tiles, the backward small; dh>=128 takes bigger K blocks).
-    ``_plan`` still caps every block at the padded T."""
+    ``_plan`` still caps every block at the padded T.
+
+    Under a GSPMD engine (an active ``parallel.sharding.KernelLayout``)
+    the call runs per shard of batch and heads: the SPMD partitioner
+    cannot partition the kernel itself."""
+    from tpudml.parallel.sharding import per_shard
+
+    bthd = ("batch", None, "head", None)
+    return per_shard(
+        lambda q, k, v: _flash_attention_local(
+            q, k, v, causal, block_q, block_k, interpret, blocked_backward),
+        (bthd, bthd, bthd), bthd,
+    )(q, k, v)
+
+
+def _flash_attention_local(q, k, v, causal, block_q, block_k, interpret,
+                           blocked_backward):
     if interpret is None:
         if jax.default_backend() != "tpu":
             return dot_product_attention(q, k, v, causal=causal)

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudml.ops.tiling import WIDE_TILE_PARAMS
 from tpudml.ops.xent_kernel import _padded_dims
 
 _INT_SENTINEL = jnp.iinfo(jnp.int32).max
@@ -141,6 +142,7 @@ def _head_call(kernel, inputs, vocab_rows, n, d, v, block_n, block_v,
             pltpu.VMEM((block_n, 1), jnp.int32),    # running argmax col
         ],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(*inputs)
     return toks[:n, 0], mx[:n, 0], lse[:n, 0]
 

@@ -340,7 +340,11 @@ def fused_add_layernorm(
     kernel per direction; the backward merges the downstream residual
     cotangent of ``s`` into the LN input gradient (module comment above).
     ``x``/``r`` [..., d]. Dispatches to the reference composition on
-    non-TPU backends unless ``interpret=True``."""
+    non-TPU backends unless ``interpret=True``. Under a GSPMD engine (an
+    active ``parallel.sharding.KernelLayout``) the rows run per batch
+    shard: the SPMD partitioner cannot partition the kernel itself."""
+    from tpudml.parallel.sharding import per_shard
+
     d = x.shape[-1]
     if x.shape != r.shape:
         raise ValueError(f"x {x.shape} != r {r.shape}")
@@ -348,6 +352,16 @@ def fused_add_layernorm(
         raise ValueError(
             f"scale/bias {scale.shape}/{bias.shape} must be ({d},)"
         )
+    rows = ("batch",) + (None,) * (x.ndim - 1)
+    return per_shard(
+        lambda x, r, scale, bias: _fused_add_layernorm_local(
+            x, r, scale, bias, eps, block_n, interpret),
+        (rows, rows, (None,), (None,)), (rows, rows),
+    )(x, r, scale, bias)
+
+
+def _fused_add_layernorm_local(x, r, scale, bias, eps, block_n, interpret):
+    d = x.shape[-1]
     if interpret is None:
         if jax.default_backend() != "tpu":
             s = x + r

@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+from tpudml.ops.tiling import WIDE_TILE_PARAMS
 from tpudml.ops.tiling import round_up as _round_up  # shared tiling helper
 
 
@@ -158,6 +159,7 @@ def _fused_forward(x, w, b, labels, block_n, block_v, interpret,
             pltpu.VMEM((block_n, 1), jnp.float32),  # picked accumulator
         ],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(xf, wf, bf, lf)
     if save_s:
         lse, picked, s = outs
@@ -289,6 +291,7 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(s, wf, lf, lsef)[:n]
     # dW tile cap: the f32 s tiles + f32 accumulator must fit scoped VMEM
     # (~16 MB): 4·d·bv (acc) + 8·bn·bv (s ×2 buffers) + 8·d·bv (dw out
@@ -320,6 +323,7 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
             pltpu.VMEM((1, bv_dw), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(s, xf, lf, lsef)
     return _scale_cotangents(dx, dw[:, :v], db[0, :v], g, x, w, b)
 
@@ -410,6 +414,7 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(xf, wf, bf, lf, lsef)[:n]
     v_pad_dw = _round_up(v, block_v_dw)
     wfd = jnp.pad(w, ((0, 0), (0, v_pad_dw - v))) if v_pad_dw != v else w
@@ -437,6 +442,7 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
             pltpu.VMEM((1, block_v_dw), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=WIDE_TILE_PARAMS,
     )(wfd, xf, bfd, lf, lsef)
     return _scale_cotangents(dx, dw[:, :v], db[0, :v], g, x, w, b)
 
@@ -653,7 +659,7 @@ def _fused_sharded_bwd(axis_name, block_n, block_v, interpret, save_s,
     import numpy as np
 
     x, w, b, ln, lse, s = res
-    # shard_map (check_rep=False) transposition convention: the
+    # shard_map (check_vma=False) transposition convention: the
     # cotangent of an output whose spec does not mention an axis arrives
     # DIVIDED by that axis size, and body psums transpose to psums —
     # that is how the pure-autodiff reference path regains the factor

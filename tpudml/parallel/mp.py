@@ -43,7 +43,7 @@ from tpudml.nn.layers import Module
 from tpudml.nn.losses import softmax_cross_entropy
 from tpudml.obs.tracer import NULL_SPAN, Tracer
 from tpudml.optim import Optimizer
-from tpudml.parallel.sharding import DispatchThrottle
+from tpudml.parallel.sharding import DispatchThrottle, kernel_layout
 from tpudml.train import (
     TrainState,
     accumulate_grads,
@@ -190,9 +190,10 @@ class GSPMDParallel:
         if fused_xent and (accum_steps != 1 or loss is not softmax_cross_entropy):
             reject("gspmd_fused_xent_accum")
         # flash_attn: run the dense causal trunk on the Pallas flash
-        # kernel (same capability row as the DP engine — GSPMD shards
-        # batch/heads, never the softmax's sequence axis, so the kernel
-        # composes with TP/FSDP rules unchanged).
+        # kernel (same capability row as the DP engine). GSPMD shards
+        # batch/heads, never the softmax's sequence axis, but it cannot
+        # partition the kernel itself: the step declares its activation
+        # layout (_kernel_layout) and the kernel runs per shard.
         self.flash_attn = flash_attn
         if flash_attn:
             import dataclasses
@@ -280,6 +281,25 @@ class GSPMDParallel:
         self._specs = self.state_specs(ts)
         return jax.device_put(ts, self._shardings(self._specs))
 
+    def _kernel_layout(self):
+        """The activation layout the placed parameters imply, declared
+        while a step is traced so Pallas-backed ops (flash attention, the
+        fused add+LN junction) run per shard — the SPMD partitioner
+        refuses to partition them. Batch rows split over ``batch_axis``;
+        attention heads split over whatever axis the rules put on the
+        output columns of an attention ``q`` projection, unless that axis
+        is the batch axis doing double duty as FSDP parameter storage."""
+        head = None
+        for path, spec in jax.tree_util.tree_flatten_with_path(
+            self._specs.params, is_leaf=lambda x: isinstance(x, P)
+        )[0]:
+            if _path_names(path)[-2:] == ("q", "kernel") and len(spec) == 2:
+                cols = spec[1]
+                if isinstance(cols, str) and cols != self.batch_axis:
+                    head = cols
+                break
+        return kernel_layout(self.mesh, batch=self.batch_axis, head=head)
+
     # ----------------------------------------------------------------- step
 
     def make_train_step(self) -> Callable:
@@ -315,16 +335,18 @@ class GSPMDParallel:
             rng = None
             if self.rng_root is not None:
                 rng = jax.random.fold_in(self.rng_root, ts.step)
-            if fused_loss_fn is not None:
-                (loss, model_state), grads = jax.value_and_grad(
-                    fused_loss_fn, has_aux=True
-                )(ts.params, ts.model_state, images, labels, rng)
-                metrics = {"loss": loss}
-            else:
-                grads, model_state, metrics = accumulate_grads(
-                    self._loss_fn, ts.params, ts.model_state, images, labels,
-                    rng, self.accum_steps, taint=self.sentinel is not None,
-                )
+            with self._kernel_layout():
+                if fused_loss_fn is not None:
+                    (loss, model_state), grads = jax.value_and_grad(
+                        fused_loss_fn, has_aux=True
+                    )(ts.params, ts.model_state, images, labels, rng)
+                    metrics = {"loss": loss}
+                else:
+                    grads, model_state, metrics = accumulate_grads(
+                        self._loss_fn, ts.params, ts.model_state, images,
+                        labels, rng, self.accum_steps,
+                        taint=self.sentinel is not None,
+                    )
             new_params, new_opt = self.optimizer.update(grads, ts.opt_state, ts.params)
             if self._obs_stats:
                 from tpudml.obs.stepstats import grad_normsq, make_step_stats
@@ -391,7 +413,9 @@ class GSPMDParallel:
         )
 
         def eval_impl(params, model_state, images, labels):
-            logits, _ = self.model.apply(params, model_state, images, train=False)
+            with self._kernel_layout():
+                logits, _ = self.model.apply(
+                    params, model_state, images, train=False)
             return jnp.sum((jnp.argmax(logits, -1) == labels).astype(jnp.int32))
 
         jitted = jax.jit(

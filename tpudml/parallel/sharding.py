@@ -1,20 +1,19 @@
 """Sharding utilities over a named device mesh.
 
 The thin layer every parallel engine shares: NamedSharding constructors,
-host→mesh placement helpers, and a version-portable ``shard_map`` wrapper.
+host→mesh placement helpers, and a ``shard_map`` wrapper with this repo's
+defaults.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import dataclasses
 from typing import Any
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # JAX ≥ 0.4.35 exposes shard_map at top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 PyTree = Any
 
@@ -23,14 +22,64 @@ def shard_map_fn(fn, mesh: Mesh, in_specs, out_specs, check_rep: bool = False):
     """``shard_map`` with this repo's defaults (rep-check off: collective
     aggregation intentionally produces replicated outputs from sharded
     inputs, which the static replication checker can't always verify)."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelLayout:
+    """How a GSPMD engine lays activations over its mesh: the axis that
+    splits the batch dim and the axis that splits attention heads (None =
+    not split). The SPMD partitioner cannot partition a Pallas kernel
+    ("Mosaic kernels cannot be automatically partitioned"), so ops backed
+    by one run per shard under this layout (:func:`per_shard`)."""
+
+    mesh: Mesh
+    batch: str | None = None
+    head: str | None = None
+
+
+_KERNEL_LAYOUT: contextvars.ContextVar[KernelLayout | None] = (
+    contextvars.ContextVar("tpudml_kernel_layout", default=None)
+)
+
+
+@contextlib.contextmanager
+def kernel_layout(mesh: Mesh, batch: str | None = None,
+                  head: str | None = None):
+    """Declare the activation layout while a GSPMD step is traced."""
+    token = _KERNEL_LAYOUT.set(KernelLayout(mesh, batch, head))
     try:
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep
-        )
-    except TypeError:  # pragma: no cover - JAX < 0.6 spells it check_rep
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep
-        )
+        yield
+    finally:
+        _KERNEL_LAYOUT.reset(token)
+
+
+def per_shard(fn, in_roles, out_roles):
+    """``fn`` as is when no :class:`KernelLayout` is active (single device,
+    or already inside an engine's ``shard_map``); otherwise ``fn`` run per
+    shard in a ``shard_map`` over the layout's mesh. ``in_roles`` gives,
+    per operand, a tuple naming each dim ``"batch"``, ``"head"`` or None;
+    ``out_roles`` likewise for the output (a tuple of such tuples for
+    several outputs). Dims split over no axis compute replicated, and
+    shard_map's transpose (psum of replicated operands' cotangents,
+    cotangents of replicated outputs divided by the axis size) keeps the
+    gradients those of the unsharded call."""
+    layout = _KERNEL_LAYOUT.get()
+    if layout is None:
+        return fn
+
+    def spec(roles):
+        return P(*(getattr(layout, r) if r else None for r in roles))
+
+    multi = bool(out_roles) and isinstance(out_roles[0], tuple)
+    return shard_map_fn(
+        fn, layout.mesh,
+        in_specs=tuple(spec(r) for r in in_roles),
+        out_specs=tuple(spec(r) for r in out_roles) if multi
+        else spec(out_roles),
+    )
 
 
 def serialize_dispatch(mesh: Mesh) -> bool:
