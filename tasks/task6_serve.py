@@ -38,6 +38,7 @@ from tpudml.core.compile_cache import enable_compile_cache
 from tpudml.core.dist import assert_same_program, distributed_init
 from tpudml.metrics import MetricsWriter
 from tpudml.models import TransformerLM
+from tpudml.obs import Tracer, use_tracer, write_serve_trace
 from tpudml.serve import ServeConfig, ServingEngine, poisson_workload
 
 
@@ -92,8 +93,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--log_dir", type=str, default="./logs")
     # observability
     p.add_argument("--obs", action="store_true",
-                   help="write run_dir/trace.json from the event log "
-                        "(pure conversion, Perfetto-openable)")
+                   help="write run_dir/trace.json (Perfetto-openable): the "
+                        "engine's live spans on the wall clock; with "
+                        "--step_time_s the event log's pure conversion")
     p.add_argument("--step_time_s", type=float, default=None,
                    help="virtual decode-step clock (deterministic runs + "
                         "real-time trace timestamps)")
@@ -153,7 +155,12 @@ def run(args) -> dict:
         prompt_len=tuple(args.prompt_len),
         new_tokens=tuple(args.new_tokens),
     )
-    report = engine.run(requests)
+    # --obs on the wall clock records the engine's own spans while it
+    # runs; on the virtual clock the trace is rebuilt from the event log
+    # (obs/convert.py), which is what makes it byte-deterministic.
+    live = Tracer() if args.obs and args.step_time_s is None else None
+    with use_tracer(live):
+        report = engine.run(requests)
 
     owed = sum(o["max_new_tokens"] for o in ledger.values())
     assert report.generated_tokens == owed, (
@@ -178,13 +185,14 @@ def run(args) -> dict:
     writer.add_scalar("E2E p99 (s)", lat["e2e_p99_s"], 0)
     writer.close()
     trace_path = None
-    if args.obs:
-        from tpudml.obs import write_serve_trace
-
+    if live is not None:
+        trace_path = live.export(writer.run_dir / "trace.json")
+    elif args.obs:
         trace_path = write_serve_trace(
             report, writer.run_dir / "trace.json",
             step_time_s=args.step_time_s,
         )
+    if trace_path is not None:
         print(f"[obs] trace: {trace_path}")
 
     refills = sum(1 for e in report.events if e[0] == "admit" and e[3] > 0)

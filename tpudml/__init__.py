@@ -21,7 +21,7 @@ TPU-first:
 - ``tpudml.ops``      — Pallas TPU kernels (fused attention).
 - ``tpudml.native``   — C++ host data-plane (fused gather+dequantize, byteswap).
 - ``tpudml.checkpoint`` — atomic pytree checkpoints + budget-based resume.
-- ``tpudml.metrics``  — scalar writer (JSONL/TensorBoard), profiler, span timers
+- ``tpudml.metrics``  — scalar writer (JSONL/TensorBoard), profiler
                         (reference: codes/datawriter.py).
 - ``tpudml.launch``   — supervised multi-process launcher (compose replacement).
 - ``tpudml.api``      — high-level Model(train/eval) facade (MindSpore-track).

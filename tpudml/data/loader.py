@@ -22,6 +22,7 @@ import numpy as np
 
 from tpudml.data.datasets import ArrayDataset
 from tpudml.data.sampler import Sampler, SequentialSampler
+from tpudml.obs.tracer import span
 
 
 class DataLoader:
@@ -54,10 +55,12 @@ class DataLoader:
         gather = getattr(self.dataset, "gather", None)
         for start in range(0, end, self.batch_size):
             batch = idx[start : start + self.batch_size]
-            if gather is not None:
-                yield gather(batch)
-            else:
-                yield self.dataset.images[batch], self.dataset.labels[batch]
+            with span("gather", "data", rows=len(batch)):
+                if gather is not None:
+                    out = gather(batch)
+                else:
+                    out = self.dataset.images[batch], self.dataset.labels[batch]
+            yield out
 
 
 class ShardedDataLoader:

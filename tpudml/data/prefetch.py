@@ -15,6 +15,8 @@ from typing import Iterable, Iterator
 
 import jax
 
+from tpudml.obs.tracer import span
+
 
 def prefetch_to_device(
     iterator: Iterable,
@@ -47,7 +49,9 @@ def _prefetch_gen(iterator, size, sharding):
                 item = next(it)
             except StopIteration:
                 return
-            queue.append(jax.device_put(item, sharding))
+            n_bytes = sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree.leaves(item))
+            with span("device_put", "data", n_bytes=n_bytes):
+                queue.append(jax.device_put(item, sharding))
 
     enqueue(size)
     while queue:

@@ -2,21 +2,16 @@
 
 The reference measures performance with inline ``time.time()`` spans and
 recommends ``torch.cuda.Event`` timing (codes/task2/model-mp.py:48-79,
-sections/task2.tex:69-80); it has no profiler. Here both layers exist:
-
-- :func:`trace` captures an XLA/TPU profile via ``jax.profiler`` into the
-  run directory — open in TensorBoard (or Perfetto) to see per-op device
-  time, fusion boundaries, and collective overlap; the TPU-accurate
-  answer to "where did the step time go".
-- :class:`SpanTimer` is the host-side wall-clock layer (the model-mp.py
-  accounting, device-synchronized like the ``torch.cuda.Event`` recipe):
-  named spans with totals/counts, e.g. ``step`` vs ``comm``.
+sections/task2.tex:69-80); it has no profiler. Here :func:`trace`
+captures an XLA/TPU profile via ``jax.profiler`` into the run directory —
+open in TensorBoard (or Perfetto) to see per-op device time, fusion
+boundaries, and collective overlap; the TPU-accurate answer to "where did
+the step time go". The program's own host spans (``tpudml.obs.tracer.span``)
+land in the same trace, on its clock, as ``tpudml:<cat>/<name>`` events.
 """
 
 from __future__ import annotations
 
-import time
-from collections import defaultdict
 from contextlib import contextmanager
 from pathlib import Path
 from typing import Iterator
@@ -39,81 +34,3 @@ def annotate(name: str):
     """Label a host-side region so it shows up on the trace timeline
     (thin alias of ``jax.profiler.TraceAnnotation``)."""
     return jax.profiler.TraceAnnotation(name)
-
-
-class SpanTimer:
-    """Named wall-clock spans with device synchronization.
-
-    ``sync=`` values are blocked on (``jax.block_until_ready``) before the
-    span closes, so async-dispatched XLA work is charged to the span that
-    launched it — the semantic of the reference's cuda-Event timing
-    (sections/task2.tex:72-80).
-
-    Each span's per-call durations feed a :class:`CommStats`, so
-    ``report()`` carries p50/p99 alongside the mean (totals-only means
-    hide tail latency — the quantity serving/step-time work cares about)
-    on the same interpolation as every other percentile in the repo.
-
-    A :class:`tpudml.obs.Tracer` passed as ``tracer=`` additionally
-    receives every span as a structured trace event — SpanTimer is the
-    thin wall-clock façade; the tracer is the flight recorder that
-    subsumes it.
-
-    Usage::
-
-        timer = SpanTimer()
-        with timer.span("step", sync=metrics["loss"]):
-            ts, metrics = step(ts, x, y)
-        print(timer.report())
-    """
-
-    def __init__(self, tracer=None):
-        from tpudml.comm.timing import CommStats
-
-        self.totals: dict[str, float] = defaultdict(float)
-        self.counts: dict[str, int] = defaultdict(int)
-        self.stats: dict[str, CommStats] = defaultdict(CommStats)
-        self.tracer = tracer
-
-    @contextmanager
-    def span(self, name: str, sync=None) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                jax.block_until_ready(sync)
-            dt = time.perf_counter() - t0
-            self.totals[name] += dt
-            self.counts[name] += 1
-            self.stats[name].add(dt)
-            if self.tracer is not None and self.tracer.enabled:
-                dur_us = int(dt * 1e6)
-                self.tracer.add_complete(
-                    name, cat="timer",
-                    ts_us=max(self.tracer.now_us() - dur_us, 0),
-                    dur_us=dur_us,
-                )
-
-    def mean(self, name: str) -> float:
-        return self.totals[name] / max(self.counts[name], 1)
-
-    def percentiles(self, name: str) -> dict:
-        """p50/p99 seconds for one span (``{}`` before any call) —
-        delegated to ``CommStats.percentiles`` so SpanTimer and the comm
-        accounting interpolate identically."""
-        return self.stats[name].percentiles()
-
-    def report(self) -> str:
-        parts = []
-        for name in sorted(self.totals):
-            line = (
-                f"{name}: {self.totals[name]:.4f}s over {self.counts[name]} "
-                f"calls (mean {self.mean(name) * 1e3:.2f}ms"
-            )
-            pct = self.percentiles(name)
-            if pct:
-                line += (f", p50 {pct['p50_s'] * 1e3:.2f}ms,"
-                         f" p99 {pct['p99_s'] * 1e3:.2f}ms")
-            parts.append(line + ")")
-        return "\n".join(parts)

@@ -21,6 +21,7 @@ from tpudml.obs.tracer import (
     get_tracer,
     merge_chrome_traces,
     set_tracer,
+    span,
     use_tracer,
     validate_chrome_trace,
 )
@@ -39,6 +40,7 @@ __all__ = [
     "merge_chrome_traces",
     "serve_trace_events",
     "set_tracer",
+    "span",
     "use_tracer",
     "validate_chrome_trace",
     "write_serve_trace",

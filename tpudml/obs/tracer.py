@@ -1,13 +1,20 @@
 """Unified flight recorder: nested, thread-safe structured spans with
 Chrome-trace-event export (SURVEY.md §5.1's "one timeline" gap).
 
-Every telemetry silo the framework grew — `SpanTimer` wall spans,
-`CommStats` collective timings, the serving engine's event log, sentinel
-trips, checkpoint save/restore/verify, launcher restarts — feeds one
+Every telemetry silo the framework grew — the serving engine's and the
+trainer's loop spans, `CommStats` collective timings, sentinel trips,
+checkpoint save/restore/verify, launcher restarts — feeds one
 :class:`Tracer`, which exports a single ``trace.json`` in the Chrome
 trace-event format (one ``pid`` track per ``jax.process_index()``),
 openable directly in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``. See docs/OBSERVABILITY.md for the span model.
+
+One way to open a span: :func:`span` (or ``Tracer.span``, the same
+code). It always enters a ``jax.profiler.TraceAnnotation`` named
+``tpudml:<cat>/<name>`` whose keyword arguments are the span's counters
+— inert without a profiler session, an event on the profiler trace's
+host line, on the device operations' clock, when one is running — and
+additionally records a :class:`Span` in the tracer when that is enabled.
 
 Determinism contract: the export sorts events by ``(ts, -dur, tid, cat,
 name)`` and serializes with sorted keys + canonical separators, so a
@@ -15,10 +22,10 @@ fixed event log produces byte-identical ``trace.json`` — the property
 the serving-trace golden tests pin (events carry the engine's virtual
 clock, not wall time).
 
-Disabled tracers allocate NOTHING: ``Tracer(enabled=False).span(...)``
-returns a shared no-op context manager and records no :class:`Span`
-(the module-level ``SPANS_ALLOCATED`` counter lets tests assert this),
-so the ``obs=`` knob's off position costs one attribute check per step.
+Disabled tracers record NOTHING: ``Tracer(enabled=False).span(...)``
+returns the bare annotation and allocates no :class:`Span` (the
+module-level ``SPANS_ALLOCATED`` counter lets tests assert this), so the
+off position costs one inert annotation (under a microsecond) per span.
 """
 
 from __future__ import annotations
@@ -31,7 +38,13 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Iterator
 
+import jax
+
 TRACE_SCHEMA_VERSION = 1
+
+# Program spans in a profiler trace are the events whose name starts with
+# this (benchmarks/program_spans.py reads them back by it).
+ANNOTATION_PREFIX = "tpudml:"
 
 # Every Span ever constructed bumps this (see tests/test_obs.py's
 # tracer-off A/B): the cheapest honest way to prove the disabled path
@@ -74,6 +87,38 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _RecordedSpan:
+    """The enabled path of :meth:`Tracer.span`: the profiler annotation
+    plus a :class:`Span` in the tracer when the region closes."""
+
+    __slots__ = ("_tracer", "_annotation", "_name", "_cat", "_sync", "_args", "_t0")
+
+    def __init__(self, tracer, annotation, name, cat, sync, args):
+        self._tracer, self._annotation = tracer, annotation
+        self._name, self._cat, self._sync, self._args = name, cat, sync, args
+
+    def set_metadata(self, **args) -> None:
+        """Counters known only once the region has run (the annotation's
+        own method of the same name, so both paths take the call)."""
+        self._annotation.set_metadata(**args)
+        self._args = {**(self._args or {}), **args}
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = self._tracer._clock()
+        return self
+
+    def __exit__(self, *exc):
+        tracer = self._tracer
+        if self._sync is not None:
+            jax.block_until_ready(self._sync)
+        ts_us = int((self._t0 - tracer._t0) * 1e6)
+        dur_us = int((tracer._clock() - self._t0) * 1e6)
+        tracer._record(Span(self._name, self._cat, ts_us, dur_us, "X",
+                            tracer._tid(), self._args))
+        return self._annotation.__exit__(*exc)
+
+
 class Tracer:
     """Thread-safe structured-span recorder.
 
@@ -89,7 +134,7 @@ class Tracer:
     ``tid``); each OS thread gets its own track, numbered densely in
     first-seen order. ``sync=`` values are blocked on before a span
     closes (``jax.block_until_ready``), charging async-dispatched XLA
-    work to the span that launched it — :class:`SpanTimer` semantics.
+    work to the span that launched it.
     """
 
     def __init__(self, enabled: bool = True, clock=time.perf_counter):
@@ -118,25 +163,15 @@ class Tracer:
             self.events.append(span)
 
     def span(self, name: str, cat: str = "host", sync=None, args: dict | None = None):
-        """Context manager timing a host region as a complete span. No-op
-        (and no allocation) when the tracer is disabled."""
+        """Context manager around a host region: a profiler annotation
+        ``tpudml:<cat>/<name>`` carrying ``args`` (ints, floats or short
+        strings) always, and a complete span in this tracer when it is
+        enabled. Disabled: the bare annotation, no :class:`Span`."""
+        annotation = jax.profiler.TraceAnnotation(
+            f"{ANNOTATION_PREFIX}{cat}/{name}", **(args or {}))
         if not self.enabled:
-            return NULL_SPAN
-        return self._timed_span(name, cat, sync, args)
-
-    @contextmanager
-    def _timed_span(self, name, cat, sync, args) -> Iterator[None]:
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                import jax
-
-                jax.block_until_ready(sync)
-            ts_us = int((t0 - self._t0) * 1e6)
-            dur_us = int((self._clock() - t0) * 1e6)
-            self._record(Span(name, cat, ts_us, dur_us, "X", self._tid(), args))
+            return annotation
+        return _RecordedSpan(self, annotation, name, cat, sync, args)
 
     def instant(self, name: str, cat: str = "host", args: dict | None = None,
                 ts_us: int | None = None) -> None:
@@ -331,6 +366,13 @@ def set_tracer(tracer: Tracer | None) -> Tracer:
         prev = _ambient
         _ambient = tracer if tracer is not None else NULL_TRACER
     return prev
+
+
+def span(name: str, cat: str = "host", **args):
+    """Open a span in the ambient tracer (:meth:`Tracer.span`); keyword
+    arguments are its counters. A request's spans all carry ``rid``, a
+    loop pass's all carry ``step``."""
+    return _ambient.span(name, cat, args=args or None)
 
 
 @contextmanager

@@ -166,6 +166,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, k_shift=0):
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((block_q, 1), jnp.float32),  # running normalizer
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(qf, kf, vf)
     return _unfold(out, b, h, t, d), lse
@@ -303,6 +304,7 @@ def _backward_calls(qf, kf, vf, dof, lse, delta, b, h, t, d, causal, block_q,
         ],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
@@ -325,6 +327,7 @@ def _backward_calls(qf, kf, vf, dof, lse, delta, b, h, t, d, causal, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(kf, vf, qf, dof, lse, delta)
 

@@ -142,6 +142,7 @@ def _ln_forward(x, g, b, eps, block_n, interpret):
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
+        name="ln_fwd",
         interpret=interpret,
     )(xf, g[None, :], b[None, :])
     return y[:n], mean, rstd
@@ -194,6 +195,7 @@ def _ln_bwd(eps, block_n, interpret, res, dy):
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
+        name="ln_bwd",
         interpret=interpret,
     )(xf, g[None, :], dyf, mean, rstd)
     return dx[:n], dg[0].astype(g.dtype), db[0].astype(b.dtype)
@@ -261,6 +263,7 @@ def _add_ln_forward(x, r, g, b, eps, block_n, interpret):
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
+        name="add_ln_fwd",
         interpret=interpret,
     )(xf, rf, g[None, :], b[None, :])
     return s[:n], y[:n], mean, rstd
@@ -313,6 +316,7 @@ def _add_ln_bwd(eps, block_n, interpret, res, cts):
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
+        name="add_ln_bwd",
         interpret=interpret,
     )(sf, g[None, :], dyf, dsf, mean, rstd)
     dx = dx[:n]

@@ -158,6 +158,7 @@ def _fused_forward(x, w, b, labels, block_n, block_v, interpret,
             pltpu.VMEM((block_n, 1), jnp.float32),  # running normalizer
             pltpu.VMEM((block_n, 1), jnp.float32),  # picked accumulator
         ],
+        name="xent_fwd_save" if save_s else "xent_fwd",
         interpret=interpret,
         compiler_params=WIDE_TILE_PARAMS,
     )(xf, wf, bf, lf)
@@ -290,6 +291,7 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
+        name="xent_bwd_dx_saved",
         interpret=interpret,
         compiler_params=WIDE_TILE_PARAMS,
     )(s, wf, lf, lsef)[:n]
@@ -322,6 +324,7 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
             pltpu.VMEM((d, bv_dw), jnp.float32),
             pltpu.VMEM((1, bv_dw), jnp.float32),
         ],
+        name="xent_bwd_dw_saved",
         interpret=interpret,
         compiler_params=WIDE_TILE_PARAMS,
     )(s, xf, lf, lsef)
@@ -413,6 +416,7 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
+        name="xent_bwd_dx",
         interpret=interpret,
         compiler_params=WIDE_TILE_PARAMS,
     )(xf, wf, bf, lf, lsef)[:n]
@@ -441,6 +445,7 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
             pltpu.VMEM((d, block_v_dw), jnp.float32),
             pltpu.VMEM((1, block_v_dw), jnp.float32),
         ],
+        name="xent_bwd_dw",
         interpret=interpret,
         compiler_params=WIDE_TILE_PARAMS,
     )(wfd, xf, bfd, lf, lsef)

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpudml.capabilities import CompositionError, reject
+from tpudml.obs.tracer import span
 from tpudml.ops.decode_head import fused_decode_head, fused_decode_head_int8
 from tpudml.serve.cache import KINDS
 from tpudml.serve.load import Request
@@ -302,6 +303,7 @@ class RequestStats:
     prompt_len: int
     max_new_tokens: int
     arrival: float
+    staged: float | None = None  # the loop noticed the arrival (queued or rejected)
     admit_start: float | None = None  # admission began (prefill starts)
     admitted: float | None = None  # prefill finished, slot occupied
     first_token: float | None = None
@@ -404,8 +406,15 @@ class ServeReport:
         timestamps within a request, seeded by the admit time) and of
         end-to-end request latency (arrival → last token), plus
         time-to-first-token (arrival → first token: queueing + prefill
-        + one decode step)."""
+        + one decode step). Over every request the loop staged, the wait
+        before admission in its two parts: lateness (arrival → staged:
+        the loop was busy in a step and had not looked yet) and queueing
+        (staged → admission start)."""
         gaps, e2e, ttft = [], [], []
+        late = [s.staged - s.arrival for s in self.requests.values()
+                if s.staged is not None]
+        queued = [s.admit_start - s.staged for s in self.requests.values()
+                  if s.admit_start is not None]
         for s in self.requests.values():
             if s.finished is None:
                 continue
@@ -426,6 +435,10 @@ class ServeReport:
             "e2e_p99_s": pct(e2e, 99),
             "ttft_p50_s": pct(ttft, 50),
             "ttft_p99_s": pct(ttft, 99),
+            "stage_lateness_p50_s": pct(late, 50),
+            "stage_lateness_p99_s": pct(late, 99),
+            "queue_wait_p50_s": pct(queued, 50),
+            "queue_wait_p99_s": pct(queued, 99),
         }
 
 
@@ -648,16 +661,29 @@ class ServingEngine:
         prompt = self._validate_request(req)
         p = prompt.size - 1
         c = self.cfg.prefill_chunk
-        slot_j = jnp.asarray(slot, jnp.int32)
-        for s0 in range(0, p, c):
-            chunk = np.zeros((1, c), np.int32)
-            n = min(c, p - s0)
-            chunk[0, :n] = prompt[s0:s0 + n]
-            self.caches = self._prefill_at(s0)(
-                self.params, self.caches, jnp.asarray(chunk), slot_j
-            )
-        self._prefill_draft(slot, prompt)
+        starts = range(0, p, c)
+        with self._admit_span(req, slot, prompt, len(starts), 0):
+            slot_j = jnp.asarray(slot, jnp.int32)
+            for s0 in starts:
+                chunk = np.zeros((1, c), np.int32)
+                n = min(c, p - s0)
+                chunk[0, :n] = prompt[s0:s0 + n]
+                self.caches = self._prefill_at(s0)(
+                    self.params, self.caches, jnp.asarray(chunk), slot_j
+                )
+            self._prefill_draft(slot, prompt)
         return p, int(prompt[-1])
+
+    def _admit_span(self, req: Request, slot: int, prompt: np.ndarray,
+                    chunks: int, shared_pages: int):
+        """``serve/admit``: the host side of one request's prefill, opened
+        once admission is certain. ``chunks`` counts the prefill programs
+        it launches (the draft's too under speculative decoding)."""
+        if self._spec is not None:
+            chunks += len(range(0, prompt.size - 1, self.cfg.prefill_chunk))
+        return span("admit", "serve", rid=req.rid, slot=slot,
+                    prompt_len=int(prompt.size), chunks=chunks,
+                    shared_pages=shared_pages)
 
     def _admit_paged(self, slot: int, req: Request,
                      stats: RequestStats) -> tuple[int, int] | None:
@@ -689,32 +715,34 @@ class ServingEngine:
         if shared:
             pool.prefix_hits += 1
             pool.pages_reused += len(shared)
-        pages = shared + fresh
-        row = np.zeros(cfg.max_pages, np.int32)
-        row[: len(pages)] = pages
-        self._table[slot] = row
-        self._slot_pages[slot] = pages
-        stats.shared_pages = len(shared)
         # Prefill [n_shared·P, p) — a chunk-aligned start by the
         # page_size % prefill_chunk == 0 config rule, so a fresh chunk
         # never writes into a shared page.
         c = cfg.prefill_chunk
-        row_j = jnp.asarray(row)
-        for s0 in range(len(shared) * cfg.page_size, p, c):
-            chunk = np.zeros((1, c), np.int32)
-            n = min(c, p - s0)
-            chunk[0, :n] = prompt[s0:s0 + n]
-            self.caches = self._prefill_at(s0)(
-                self.params, self.caches, jnp.asarray(chunk), row_j
-            )
-        if pool.prefix_sharing:
-            # Publish this request's fully-prefilled fresh pages: page j
-            # is shareable iff it ends strictly before the first decode
-            # write at p, so no future occupant ever writes it.
-            for j in range(len(shared), len(pages)):
-                if (j + 1) * cfg.page_size <= p:
-                    pool.register(pages[j], prompt, j)
-        self._prefill_draft(slot, prompt)
+        starts = range(len(shared) * cfg.page_size, p, c)
+        with self._admit_span(req, slot, prompt, len(starts), len(shared)):
+            pages = shared + fresh
+            row = np.zeros(cfg.max_pages, np.int32)
+            row[: len(pages)] = pages
+            self._table[slot] = row
+            self._slot_pages[slot] = pages
+            stats.shared_pages = len(shared)
+            row_j = jnp.asarray(row)
+            for s0 in starts:
+                chunk = np.zeros((1, c), np.int32)
+                n = min(c, p - s0)
+                chunk[0, :n] = prompt[s0:s0 + n]
+                self.caches = self._prefill_at(s0)(
+                    self.params, self.caches, jnp.asarray(chunk), row_j
+                )
+            if pool.prefix_sharing:
+                # Publish this request's fully-prefilled fresh pages: page
+                # j is shareable iff it ends strictly before the first
+                # decode write at p, so no future occupant ever writes it.
+                for j in range(len(shared), len(pages)):
+                    if (j + 1) * cfg.page_size <= p:
+                        pool.register(pages[j], prompt, j)
+            self._prefill_draft(slot, prompt)
         return p, int(prompt[-1])
 
     def _release_slot(self, slot: int) -> None:
@@ -776,167 +804,197 @@ class ServingEngine:
 
         while arrivals or queue or active.any():
             t = now()
-            # Stage arrivals into the waiting queue; a full bounded queue
-            # rejects at the door (slot -1 in the event tuple).
-            while arrivals and arrivals[0].arrival_time <= t:
-                req = arrivals.popleft()
-                if cfg.max_queue is not None and len(queue) >= cfg.max_queue:
-                    stats[req.rid].rejected = t
-                    events.append(("reject", req.rid, -1, steps))
-                else:
-                    queue.append(req)
-            peak_queue = max(peak_queue, len(queue))
-            # Expire queued requests strictly past arrival + deadline
-            # BEFORE admission — never spend prefill on a dead request.
-            if cfg.deadline_s is not None:
-                kept: deque[Request] = deque()
-                while queue:
-                    req = queue.popleft()
-                    if t > req.arrival_time + cfg.deadline_s:
-                        stats[req.rid].expired = t
-                        events.append(("expire", req.rid, -1, steps))
-                    else:
-                        kept.append(req)
-                queue = kept
-            # Admit: free slots in index order, queue in arrival order.
-            # The head is only ever PEEKED until admission succeeds —
-            # an SLO deferral or a page-starved pool leaves it queued,
-            # and nothing behind it may overtake (FIFO + (arrival, rid)
-            # order is the determinism contract).
-            for i in range(b):
-                if active[i] or not queue:
-                    continue
-                req = queue[0]
-                if self._cost is not None and not self._cost.admit_ok(
-                    int(active.sum())
-                ):
-                    if req.rid not in deferred_logged:
-                        deferred_logged.add(req.rid)
-                        events.append(("defer", req.rid, -1, steps))
-                    break
-                st = stats[req.rid]
-                st.admit_start = now()
-                if self._paged:
-                    admitted = self._admit_paged(i, req, st)
-                    if admitted is None:
-                        if not active.any():
-                            raise ValueError(
-                                f"request {req.rid} needs more pages "
-                                f"than the pool can ever supply "
-                                f"({cfg.total_pages} pages incl. the "
-                                f"garbage page)"
-                            )
+            with span("iter", "serve", step=steps,
+                      active=int(active.sum())) as this_pass:
+                # Stage arrivals into the waiting queue; a full bounded
+                # queue rejects at the door (slot -1 in the event tuple).
+                while arrivals and arrivals[0].arrival_time <= t:
+                    req = arrivals.popleft()
+                    rejected = (cfg.max_queue is not None
+                                and len(queue) >= cfg.max_queue)
+                    with span("arrive", "serve", rid=req.rid,
+                              late_us=int((t - req.arrival_time) * 1e6),
+                              rejected=int(rejected)):
+                        stats[req.rid].staged = t
+                        if rejected:
+                            stats[req.rid].rejected = t
+                            events.append(("reject", req.rid, -1, steps))
+                        else:
+                            queue.append(req)
+                this_pass.set_metadata(queue=len(queue))  # depth after staging
+                peak_queue = max(peak_queue, len(queue))
+                # Expire queued requests strictly past arrival + deadline
+                # BEFORE admission — never spend prefill on a dead request.
+                if cfg.deadline_s is not None:
+                    kept: deque[Request] = deque()
+                    while queue:
+                        req = queue.popleft()
+                        if t > req.arrival_time + cfg.deadline_s:
+                            stats[req.rid].expired = t
+                            events.append(("expire", req.rid, -1, steps))
+                        else:
+                            kept.append(req)
+                    queue = kept
+                # Admit: free slots in index order, queue in arrival order.
+                # The head is only ever PEEKED until admission succeeds —
+                # an SLO deferral or a page-starved pool leaves it queued,
+                # and nothing behind it may overtake (FIFO + (arrival, rid)
+                # order is the determinism contract).
+                for i in range(b):
+                    if active[i] or not queue:
+                        continue
+                    req = queue[0]
+                    if self._cost is not None and not self._cost.admit_ok(
+                        int(active.sum())
+                    ):
                         if req.rid not in deferred_logged:
                             deferred_logged.add(req.rid)
                             events.append(("defer", req.rid, -1, steps))
                         break
-                else:
-                    admitted = self._admit(i, req)
-                queue.popleft()
-                pos[i], last[i] = admitted
-                remaining[i] = req.max_new_tokens
-                slot_rid[i] = req.rid
-                slot_deadline[i] = (
-                    req.arrival_time + cfg.deadline_s
-                    if cfg.deadline_s is not None
-                    else np.inf
-                )
-                active[i] = True
-                st.admitted = now()
-                st.slot = i
-                events.append(("admit", req.rid, i, steps))
-            if not active.any():
-                if not arrivals:
-                    continue  # queue drained by expiry; loop re-checks
-                # Idle: nothing in flight, queue head hasn't arrived yet.
-                gap = arrivals[0].arrival_time - now()
-                if cfg.step_time_s is not None:
-                    v_extra += max(gap, 0.0)  # skip virtual time forward
-                elif gap > 0:
-                    time.sleep(min(gap, 0.05))
-                continue
-            # One decode step for ALL slots. Inactive slots run garbage
-            # tokens at stale positions — harmless by the mask argument
-            # in the module docstring (paged: their zero table rows point
-            # every write at the garbage page) — so the compiled shape
-            # never changes with occupancy. Spec steps return a K+1-wide
-            # window + per-slot commit counts; plain steps reduce to the
-            # same contract at width 1.
-            busy_slot_steps += int(active.sum())
-            last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
-            if self._spec is not None:
-                if self._paged:
-                    emitted, n_emit, _, self.caches, self._dcaches = (
-                        self._spec(self.params, self._dparams, self.caches,
-                                   self._dcaches, jnp.asarray(self._table),
-                                   last_j, pos_j)
+                    st = stats[req.rid]
+                    st.admit_start = now()
+                    if self._paged:
+                        admitted = self._admit_paged(i, req, st)
+                        if admitted is None:
+                            if not active.any():
+                                raise ValueError(
+                                    f"request {req.rid} needs more pages "
+                                    f"than the pool can ever supply "
+                                    f"({cfg.total_pages} pages incl. the "
+                                    f"garbage page)"
+                                )
+                            if req.rid not in deferred_logged:
+                                deferred_logged.add(req.rid)
+                                events.append(("defer", req.rid, -1, steps))
+                            break
+                    else:
+                        admitted = self._admit(i, req)
+                    queue.popleft()
+                    pos[i], last[i] = admitted
+                    remaining[i] = req.max_new_tokens
+                    slot_rid[i] = req.rid
+                    slot_deadline[i] = (
+                        req.arrival_time + cfg.deadline_s
+                        if cfg.deadline_s is not None
+                        else np.inf
                     )
-                else:
-                    emitted, n_emit, _, self.caches, self._dcaches = (
-                        self._spec(self.params, self._dparams, self.caches,
-                                   self._dcaches, last_j, pos_j)
-                    )
-                emitted_np = np.asarray(jax.device_get(emitted))
-                n_emit_np = np.asarray(jax.device_get(n_emit))
-            else:
-                if self._paged:
-                    next_t, _, self.caches = self._decode(
-                        self.params, self.caches, jnp.asarray(self._table),
-                        last_j, pos_j,
-                    )
-                else:
-                    next_t, _, self.caches = self._decode(
-                        self.params, self.caches, last_j, pos_j
-                    )
-                emitted_np = np.asarray(jax.device_get(next_t))[:, None]
-                n_emit_np = np.ones(b, np.int64)
-            steps += 1
-            t_step = now()
-            for i in range(b):
-                if not active[i]:
+                    active[i] = True
+                    st.admitted = now()
+                    st.slot = i
+                    events.append(("admit", req.rid, i, steps))
+                n_active = int(active.sum())
+                if not n_active:
+                    if not arrivals:
+                        continue  # queue drained by expiry; loop re-checks
+                    # Idle: nothing in flight, queue head hasn't arrived yet.
+                    gap = arrivals[0].arrival_time - now()
+                    if cfg.step_time_s is not None:
+                        v_extra += max(gap, 0.0)  # skip virtual time forward
+                    elif gap > 0:
+                        with span("idle", "serve"):
+                            time.sleep(min(gap, 0.05))
                     continue
-                st = stats[slot_rid[i]]
-                done = False
-                committed = 0
-                for tok in emitted_np[i, : int(n_emit_np[i])]:
-                    tok = int(tok)
-                    st.tokens.append(tok)
-                    st.token_times.append(t_step)
-                    committed += 1
-                    if st.first_token is None:
-                        st.first_token = t_step
-                    pos[i] += 1
-                    last[i] = tok
-                    remaining[i] -= 1
-                    if remaining[i] <= 0 or (
-                        cfg.eos_token is not None and tok == cfg.eos_token
-                    ):
-                        done = True
-                        break
-                if self._spec is not None:
-                    # accepted_len counts draft tokens actually COMMITTED
-                    # (committed - 1: the last commit is the target's
-                    # bonus/correction token) — a window truncated by EOS
-                    # or the max_new_tokens budget logs only what landed
-                    # in the ledger, so mean_accepted_len stays an exact
-                    # tokens-per-target-step accounting.
-                    events.append(("spec", int(slot_rid[i]), i, steps,
-                                   committed - 1))
-                if done:
-                    st.finished = t_step
-                    active[i] = False
-                    events.append(("evict", int(slot_rid[i]), i, steps))
-                    slot_rid[i] = -1
-                    self._release_slot(i)
-                elif t_step > slot_deadline[i]:
-                    # Mid-flight deadline eviction at the step boundary:
-                    # the slot frees for the queue head, the partial
-                    # tokens stay in the ledger, finished stays None.
-                    st.expired = t_step
-                    active[i] = False
-                    events.append(("expire", int(slot_rid[i]), i, steps))
-                    slot_rid[i] = -1
-                    self._release_slot(i)
+                # One decode step for ALL slots. Inactive slots run garbage
+                # tokens at stale positions — harmless by the mask argument
+                # in the module docstring (paged: their zero table rows
+                # point every write at the garbage page) — so the compiled
+                # shape never changes with occupancy. Spec steps return a
+                # K+1-wide window + per-slot commit counts; plain steps
+                # reduce to the same contract at width 1.
+                busy_slot_steps += n_active
+                # ``rows``: cache rows that hold a token, of the
+                # slots x max_len the dense step reads.
+                with span("dispatch", "serve", step=steps, active=n_active,
+                          rows=int(pos[active].sum())):
+                    last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
+                    if self._spec is not None:
+                        if self._paged:
+                            emitted, n_emit, _, self.caches, self._dcaches = (
+                                self._spec(self.params, self._dparams,
+                                           self.caches, self._dcaches,
+                                           jnp.asarray(self._table),
+                                           last_j, pos_j)
+                            )
+                        else:
+                            emitted, n_emit, _, self.caches, self._dcaches = (
+                                self._spec(self.params, self._dparams,
+                                           self.caches, self._dcaches,
+                                           last_j, pos_j)
+                            )
+                    elif self._paged:
+                        next_t, _, self.caches = self._decode(
+                            self.params, self.caches,
+                            jnp.asarray(self._table), last_j, pos_j,
+                        )
+                    else:
+                        next_t, _, self.caches = self._decode(
+                            self.params, self.caches, last_j, pos_j
+                        )
+                # Where the host waits for the device.
+                with span("fetch", "serve", step=steps):
+                    if self._spec is not None:
+                        emitted_np = np.asarray(jax.device_get(emitted))
+                        n_emit_np = np.asarray(jax.device_get(n_emit))
+                    else:
+                        emitted_np = np.asarray(jax.device_get(next_t))[:, None]
+                        n_emit_np = np.ones(b, np.int64)
+                steps += 1
+                t_step = now()
+                with span("commit", "serve", step=steps - 1) as commit:
+                    n_tokens = n_finished = n_expired = 0
+                    for i in range(b):
+                        if not active[i]:
+                            continue
+                        st = stats[slot_rid[i]]
+                        done = False
+                        committed = 0
+                        for tok in emitted_np[i, : int(n_emit_np[i])]:
+                            tok = int(tok)
+                            st.tokens.append(tok)
+                            st.token_times.append(t_step)
+                            committed += 1
+                            if st.first_token is None:
+                                st.first_token = t_step
+                            pos[i] += 1
+                            last[i] = tok
+                            remaining[i] -= 1
+                            if remaining[i] <= 0 or (
+                                cfg.eos_token is not None
+                                and tok == cfg.eos_token
+                            ):
+                                done = True
+                                break
+                        n_tokens += committed
+                        if self._spec is not None:
+                            # accepted_len counts draft tokens actually
+                            # COMMITTED (committed - 1: the last commit is
+                            # the target's bonus/correction token) — a
+                            # window truncated by EOS or the max_new_tokens
+                            # budget logs only what landed in the ledger,
+                            # so mean_accepted_len stays an exact
+                            # tokens-per-target-step accounting.
+                            events.append(("spec", int(slot_rid[i]), i, steps,
+                                           committed - 1))
+                        if done:
+                            st.finished = t_step
+                            active[i] = False
+                            events.append(("evict", int(slot_rid[i]), i, steps))
+                            slot_rid[i] = -1
+                            self._release_slot(i)
+                            n_finished += 1
+                        elif t_step > slot_deadline[i]:
+                            # Mid-flight deadline eviction at the step
+                            # boundary: the slot frees for the queue head,
+                            # the partial tokens stay in the ledger,
+                            # finished stays None.
+                            st.expired = t_step
+                            active[i] = False
+                            events.append(("expire", int(slot_rid[i]), i, steps))
+                            slot_rid[i] = -1
+                            self._release_slot(i)
+                            n_expired += 1
+                    commit.set_metadata(tokens=n_tokens, finished=n_finished,
+                                        expired=n_expired)
         pool_stats = None
         if self._pool is not None:
             pool_stats = {

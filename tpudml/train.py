@@ -13,6 +13,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 from functools import lru_cache, partial
+from itertools import count
 from typing import Any, Callable
 
 import jax
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from tpudml.metrics import MetricsWriter
 from tpudml.nn.layers import Module
 from tpudml.nn.losses import accuracy, softmax_cross_entropy
+from tpudml.obs.tracer import span
 from tpudml.optim import Optimizer
 
 
@@ -595,30 +597,46 @@ def train_loop(
     for epoch in range(start_epoch, num_epochs):
         if hasattr(train_loader, "set_epoch"):
             train_loader.set_epoch(epoch)
-        for i, (images, labels) in enumerate(train_loader):
-            if epoch == start_epoch and i < skip_batches:
-                continue  # fast-forward the sampler to the resume point
-            ts, metrics = step(ts, images, labels)
-            counter += 1
-            if log_every and counter % log_every == 0:
-                loss = float(metrics["loss"])
-                if writer is not None:
-                    writer.add_scalar("Train Loss", loss, counter)
-                    stats = metrics.get("step_stats")
-                    if stats is not None and hasattr(stats, "to_scalars"):
-                        # In-graph telemetry (tpudml.obs): the StepStats
-                        # pytree streams as obs/* scalars on the same
-                        # cadence as the loss.
-                        writer.add_scalars(
-                            {
-                                f"obs/{k}": float(v)
-                                for k, v in stats.to_scalars().items()
-                            },
-                            counter,
-                        )
-                print(f"epoch {epoch} iter {counter}: loss {loss:.4f}")
-            for h in hooks or ():
-                h(epoch=epoch, step=counter, train_state=ts, metrics=metrics)
+        batches = iter(train_loader)
+        for i in count():
+            # One pass of the loop: ``step`` is the step it dispatches
+            # (a pass that fast-forwards, or finds the loader exhausted,
+            # has only the ``next_batch`` child).
+            with span("iter", "train", step=counter + 1):
+                with span("next_batch", "train", step=counter + 1):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
+                if epoch == start_epoch and i < skip_batches:
+                    continue  # fast-forward the sampler to the resume point
+                images, labels = batch
+                with span("step", "train", step=counter + 1):
+                    ts, metrics = step(ts, images, labels)
+                counter += 1
+                if log_every and counter % log_every == 0:
+                    # The one host sync of the loop: the loss comes to the host.
+                    with span("log_sync", "train", step=counter):
+                        loss = float(metrics["loss"])
+                        if writer is not None:
+                            writer.add_scalar("Train Loss", loss, counter)
+                            stats = metrics.get("step_stats")
+                            if stats is not None and hasattr(stats, "to_scalars"):
+                                # In-graph telemetry (tpudml.obs): the
+                                # StepStats pytree streams as obs/* scalars
+                                # on the same cadence as the loss.
+                                writer.add_scalars(
+                                    {
+                                        f"obs/{k}": float(v)
+                                        for k, v in stats.to_scalars().items()
+                                    },
+                                    counter,
+                                )
+                        print(f"epoch {epoch} iter {counter}: loss {loss:.4f}")
+                if hooks:
+                    with span("hooks", "train", step=counter):
+                        for h in hooks:
+                            h(epoch=epoch, step=counter, train_state=ts,
+                              metrics=metrics)
     jax.block_until_ready(ts.params)
     train_time = time.time() - t0
     print(f"Training time: {train_time:.3f}s")
