@@ -103,6 +103,8 @@ def test_serve_dispatch_counters_match_the_report(traced_serve):
     assert all(s.args["expired"] == 0 for s in commit)
     # rows: the cache rows that hold a token, under slots x max_len
     assert all(0 < s.args["rows"] <= 3 * 64 for s in dispatch)
+    # head_dim 8 does not fill a 128-lane tile: one update per slot
+    assert all(s.args["row_scatter"] == 0 for s in dispatch)
 
 
 def test_serve_children_lie_inside_their_pass(traced_serve):
@@ -148,7 +150,10 @@ def test_serve_tracer_off_same_tokens_and_no_span(lm, traced_serve):
     before = tracer_mod.SPANS_ALLOCATED
     _, plain = _serve(lm)
     assert tracer_mod.SPANS_ALLOCATED == before
-    assert plain.events == traced.events
+    # Which pass first sees an arrival is wall-clock timing; who is
+    # admitted and evicted, and what they are served, is not.
+    assert sorted(e[:2] for e in plain.events) == sorted(
+        e[:2] for e in traced.events)
     for rid, st in plain.requests.items():
         assert st.tokens == traced.requests[rid].tokens
 

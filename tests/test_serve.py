@@ -33,6 +33,10 @@ CONFIGS = {
     "rope_dense": dict(rope=True),
     "rope_gqa": dict(rope=True, num_kv_heads=2),
     "pos_table": dict(rope=False),
+    # head_dim 128: the widths at which the decode step writes its K/V
+    # rows by one scatter a tensor (serve/cache.py:row_scatter)
+    "mqa_dh128": dict(rope=False, embed_dim=256, num_heads=2, num_kv_heads=1),
+    "rope_dh128": dict(rope=True, embed_dim=256, num_heads=2),
 }
 
 
@@ -138,7 +142,7 @@ def test_quantized_cache_matches_sim_oracle(kind, sim):
 # ----------------------------------------------------------- TP parity
 
 
-@pytest.mark.parametrize("cfg", ["rope_dense", "rope_gqa"])
+@pytest.mark.parametrize("cfg", ["rope_dense", "rope_gqa", "rope_dh128"])
 def test_tp_decode_logits_match_full_forward(cfg):
     """The shard_map TP decode step (params via tensor_parallel_rules,
     cache sharded over kv_heads) is logit-exact against the unsharded
@@ -192,6 +196,69 @@ def test_write_token_per_slot_positions():
         mask = np.ones(8, bool)
         mask[p] = False
         assert np.all(np.asarray(kk[b])[mask] == 0)
+
+
+def _write_token_oracle(cache, k_new, v_new, pos):
+    """``write_token`` as it was before the row scatter (PR 29): one
+    ``dynamic_update_slice`` per slot, which clamps ``pos`` into
+    [0, L - Q]."""
+    from jax import lax
+
+    from tpudml.serve.cache import KVCache, _encode
+
+    def put(buf, rows):
+        at = (0,) * (buf.ndim - 2)
+        return jax.vmap(
+            lambda c, r, p: lax.dynamic_update_slice(c, r, (p, *at))
+        )(buf, rows, pos)
+
+    ks, kscale = _encode(k_new, cache.kind)
+    vs, vscale = _encode(v_new, cache.kind)
+    k_sc, v_sc = cache.k_scale, cache.v_scale
+    if cache.kind == "int8":
+        k_sc, v_sc = put(k_sc, kscale), put(v_sc, vscale)
+    return KVCache(k=put(cache.k, ks), v=put(cache.v, vs),
+                   k_scale=k_sc, v_scale=v_sc, kind=cache.kind)
+
+
+@pytest.mark.parametrize("q", [1, 4], ids=["q1", "q4"])
+@pytest.mark.parametrize("hkv,dh", [(1, 128), (8, 128), (16, 64)],
+                         ids=["1x128", "8x128", "16x64"])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_write_token_bit_identical_to_per_slot_update(kind, hkv, dh, q):
+    """Every element of the cache after ``write_token`` — codes and, for
+    int8, scales — equals the per-slot ``dynamic_update_slice`` form's,
+    for rows at 0, odd, even, the last legal start ``L - Q``, and out of
+    range on both sides (clamped, as ``dynamic_update_slice`` clamps)."""
+    from tpudml.serve.cache import KVCache
+
+    length = 24
+    pos = jnp.asarray([0, 5, 8, length - q, length - q + 1, length + 7, -3,
+                       13], jnp.int32)
+    b = pos.shape[0]
+    rng = np.random.default_rng(hkv * dh + q)
+    blank = init_cache(b, length, hkv, dh, kind)
+
+    def noise(x):  # a used cache: stale rows everywhere
+        return jnp.asarray(rng.integers(-100, 100, x.shape), x.dtype)
+
+    cache = KVCache(k=noise(blank.k), v=noise(blank.v),
+                    k_scale=noise(blank.k_scale),
+                    v_scale=noise(blank.v_scale), kind=kind)
+    k_new = jnp.asarray(rng.standard_normal((b, q, hkv, dh)), jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((b, q, hkv, dh)), jnp.float32)
+    got = jax.jit(write_token)(cache, k_new, v_new, pos)
+    want = jax.jit(_write_token_oracle)(cache, k_new, v_new, pos)
+    for name in ("k", "v", "k_scale", "v_scale"):
+        g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(
+            g.view(np.uint8), w.view(np.uint8), err_msg=name)
+    # the write landed: slot 1's rows 5 .. 5+q-1 changed, row 4 did not
+    assert not np.array_equal(np.asarray(got.k[1, 5:5 + q]),
+                              np.asarray(cache.k[1, 5:5 + q]))
+    np.testing.assert_array_equal(np.asarray(got.k[1, 4]),
+                                  np.asarray(cache.k[1, 4]))
 
 
 def test_write_chunk_targets_one_slot():

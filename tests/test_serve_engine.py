@@ -76,6 +76,47 @@ def test_event_log_is_deterministic(setup):
         assert rep1.requests[rid].slot == rep2.requests[rid].slot
 
 
+@pytest.mark.parametrize("mode", ["f32", "int8", "spec", "tp"])
+def test_row_scatter_serves_the_per_slot_update_tokens(mode, monkeypatch):
+    """At head_dim 128 the decode step writes its K/V rows by one scatter
+    a tensor (serve/cache.py:row_scatter). Ten requests through three
+    slots — released and re-admitted over stale rows — are served the
+    tokens, in the slots and at the steps, of the per-slot
+    ``dynamic_update_slice`` form: plain decode, an int8 cache (codes and
+    scales), the speculative verify window (Q = 3 rows a slot) and the
+    head-sharded TP step."""
+    from tpudml.serve import cache
+
+    model = TransformerLM(vocab_size=V, embed_dim=256, num_heads=2,
+                          num_layers=2, max_len=64, rope=True,
+                          num_kv_heads=2 if mode == "tp" else 1)
+    params, _ = model.init(jax.random.key(1))
+    cfg = ServeConfig(slots=3, max_len=64, prefill_chunk=8,
+                      cache_kind="int8" if mode == "int8" else "f32",
+                      spec_k=2 if mode == "spec" else 0)
+    extra = {}
+    if mode == "tp":
+        from tpudml.core.config import MeshConfig
+        from tpudml.core.dist import make_mesh
+
+        extra = dict(mesh=make_mesh(MeshConfig({"model": 2}),
+                                    jax.devices()[:2]), axis_name="model")
+    reqs, _ = poisson_workload(10, math.inf, 11, vocab_size=V,
+                               prompt_len=(2, 12), new_tokens=(3, 8))
+
+    def serve():
+        return ServingEngine(model, params, cfg, **extra).run(reqs)
+
+    assert cache.row_scatter(256 // 2)
+    got = serve()
+    monkeypatch.setattr(cache, "row_scatter", lambda head_dim: False)
+    want = serve()
+    assert got.events == want.events
+    assert sum(e[0] == "evict" for e in got.events) == 10
+    for rid, st in want.requests.items():
+        assert got.requests[rid].tokens == st.tokens
+
+
 def test_slots_are_refilled_mid_flight(setup):
     """Continuous batching: with more requests than slots, some admit
     happens at a decode step > 0 (a freed slot re-enters the batch while

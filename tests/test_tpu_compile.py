@@ -151,6 +151,52 @@ def test_serving_chunk_window_flash(chip, dtype):
          ((1, start + SERVE_CHUNK, H, DH), dtype))
 
 
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+             "u32": 4, "f32": 4}
+
+
+def _copied_bytes(text: str) -> list[int]:
+    """Bytes of the result of every ``copy`` in a compiled program."""
+    import math
+    import re
+
+    return [math.prod(int(d) for d in dims.split(",") if d) * _ITEMSIZE[dt]
+            for dt, dims in re.findall(
+                r"= (\w+)\[([\d,]*)\]\{[^}]*\} copy\(", text)]
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_serve_decode_step_writes_rows_in_place(topo, kind):
+    """The engine's decode step at the serving cell's cache (64 slots x
+    8192 rows, multi-query 16 x 128, donated; two layers are enough):
+    the step's K/V rows go in by scatters — no ``while`` over the slots
+    (64 passes a tensor before PR 29) and no ``copy`` as large as one
+    layer's K."""
+    from tpudml.models import TransformerLM
+    from tpudml.serve.engine import make_decode_step
+
+    slots, rows = 64, 8192
+    model = TransformerLM(vocab_size=1024, embed_dim=2048, num_heads=16,
+                          num_layers=2, max_len=rows, rope=False,
+                          num_kv_heads=1, impl="flash", dtype=bf16,
+                          compute_dtype=bf16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(
+        lambda: model.init_decode_cache(slots, rows, kind))
+    ints = jax.ShapeDtypeStruct((slots,), i32, sharding=one)
+    text = make_decode_step(model).lower(
+        described(params), described(caches), ints, ints).compile().as_text()
+    assert " scatter(" in text and " while(" not in text
+    k = caches[0].k
+    assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
+
+
 # ------------------------------------------------- across the four chips
 # What exists only on a mesh: the SPMD partitioner refuses a bare Mosaic
 # kernel, so under the GSPMD engines the kernels run per shard; and the

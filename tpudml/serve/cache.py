@@ -112,27 +112,63 @@ def _encode(x: jax.Array, kind: str) -> tuple[jax.Array, jax.Array | None]:
     return x.astype(jnp.float32), None
 
 
+def row_scatter(head_dim: int) -> bool:
+    """Whether ``write_token`` writes its rows by one scatter a tensor.
+
+    The chip stores ``[B, L, Hkv, Dh]`` row-major only when a row fills
+    whole 128-lane tiles; the scatter is then one in-place operation
+    (5 us a tensor at 64 x 8192 x 1 x 128 on the v5e, against 219 us
+    for the per-slot form; PERF.md §6, PR 29). With ``Dh`` 64 or 96 the
+    chip lays the cache out with L minor-most (``{1,3,2,0:T(8,128)}``)
+    and a row scatter transposes the whole cache to row-major and back,
+    two cache-sized ``copy`` operations a tensor (1,249 against 535 us
+    at 64 x 1024 x 16 x 64). Those layouts keep the per-slot
+    ``dynamic_update_slice``, which the compiler runs in place, as a
+    ``while`` of B passes."""
+    return head_dim % 128 == 0
+
+
+def _update_rows(buf: jax.Array, rows: jax.Array,
+                 pos: jax.Array) -> jax.Array:
+    """rows [B, Q, ...] into buf [B, L, ...] at per-slot rows
+    [start, start + Q), one ``dynamic_update_slice`` per slot: a negative
+    ``pos`` counts from the end, then the window is clamped into
+    [0, L - Q]."""
+    at = (0,) * (buf.ndim - 2)
+    return jax.vmap(
+        lambda c, r, p: lax.dynamic_update_slice(c, r, (p, *at))
+    )(buf, rows, pos)
+
+
+def _scatter_rows(buf: jax.Array, rows: jax.Array,
+                  pos: jax.Array) -> jax.Array:
+    """``_update_rows`` as one scatter: the same rows for every ``pos``."""
+    b, length = buf.shape[:2]
+    q = rows.shape[1]
+    start = jnp.clip(jnp.where(pos < 0, pos + length, pos), 0, length - q)
+    slot = jnp.arange(b)[:, None]
+    row = start[:, None] + jnp.arange(q)[None, :]
+    # Slots never share a row and a slot's rows ascend; in bounds by the
+    # clamp. The promises let the compiler emit one in-place scatter.
+    return buf.at[slot, row].set(
+        rows, mode="promise_in_bounds", unique_indices=True,
+        indices_are_sorted=True)
+
+
 def write_token(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
                 pos: jax.Array) -> KVCache:
-    """Write one token per slot: k_new/v_new [B, 1, Hkv, Dh] at per-slot
-    positions ``pos`` [B] (continuous batching: every slot sits at its
-    own depth)."""
+    """Write Q tokens per slot: k_new/v_new [B, Q, Hkv, Dh] at per-slot
+    rows ``pos`` .. ``pos + Q - 1`` (``pos`` [B]; continuous batching:
+    every slot sits at its own depth; Q = 1 for plain decode, the verify
+    window for speculative decode)."""
     ks, kscale = _encode(k_new, cache.kind)
     vs, vscale = _encode(v_new, cache.kind)
-
-    def one(ck, kn, p):  # ck [L, Hkv, Dh], kn [1, Hkv, Dh]
-        return lax.dynamic_update_slice(ck, kn, (p, 0, 0))
-
-    k = jax.vmap(one)(cache.k, ks, pos)
-    v = jax.vmap(one)(cache.v, vs, pos)
+    put = _scatter_rows if row_scatter(cache.k.shape[-1]) else _update_rows
     k_sc, v_sc = cache.k_scale, cache.v_scale
     if cache.kind == "int8":
-        def one_s(cs, sn, p):  # cs [L, Hkv], sn [1, Hkv]
-            return lax.dynamic_update_slice(cs, sn, (p, 0))
-
-        k_sc = jax.vmap(one_s)(k_sc, kscale, pos)
-        v_sc = jax.vmap(one_s)(v_sc, vscale, pos)
-    return KVCache(k=k, v=v, k_scale=k_sc, v_scale=v_sc, kind=cache.kind)
+        k_sc, v_sc = put(k_sc, kscale, pos), put(v_sc, vscale, pos)
+    return KVCache(k=put(cache.k, ks, pos), v=put(cache.v, vs, pos),
+                   k_scale=k_sc, v_scale=v_sc, kind=cache.kind)
 
 
 def write_chunk(cache: KVCache, k_new: jax.Array, v_new: jax.Array,

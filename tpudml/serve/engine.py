@@ -52,7 +52,7 @@ import numpy as np
 from tpudml.capabilities import CompositionError, reject
 from tpudml.obs.tracer import span
 from tpudml.ops.decode_head import fused_decode_head, fused_decode_head_int8
-from tpudml.serve.cache import KINDS
+from tpudml.serve.cache import KINDS, row_scatter
 from tpudml.serve.load import Request
 from tpudml.serve.paged import PAGED_DECODE_MARKER, PagePool
 from tpudml.serve.sched import DecodeCostModel, SLOConfig
@@ -463,6 +463,11 @@ class ServingEngine:
                 f"table ({model.max_len}); only RoPE models extrapolate"
             )
         self._paged = cfg.cache_layout == "paged"
+        # How the decode step writes its K/V rows (``serve/dispatch``'s
+        # ``row_scatter``): the page pool always scatters, the dense
+        # cache where its layout allows (serve/cache.py:row_scatter).
+        self._row_scatter = int(
+            self._paged or row_scatter(model.embed_dim // model.num_heads))
         if mesh is not None and (self._paged or cfg.spec_k):
             # The TP decode step shards cache heads through a shard_map
             # body that knows nothing of page tables or verify windows.
@@ -905,7 +910,8 @@ class ServingEngine:
                 # ``rows``: cache rows that hold a token, of the
                 # slots x max_len the dense step reads.
                 with span("dispatch", "serve", step=steps, active=n_active,
-                          rows=int(pos[active].sum())):
+                          rows=int(pos[active].sum()),
+                          row_scatter=self._row_scatter):
                     last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
                     if self._spec is not None:
                         if self._paged:
