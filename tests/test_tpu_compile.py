@@ -197,6 +197,47 @@ def test_serve_decode_step_writes_rows_in_place(topo, kind):
     assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
 
 
+def test_pattern_model_decode_step_copies_no_weights_and_no_state(topo):
+    """The pattern model's decode step at the published widths (one layer of
+    each kind, 64 of 128 experts held, 128 slots x 4096 rows, donated): the
+    held experts' weights go into their two matmuls as they are stored — a
+    grouped matmul over sorted rows transposed all 640 MB of ``up`` every
+    step, the chip laying out [64, 2688, 1856] with 2688 minor-most
+    (PERF.md §6, PR 30) — and the recurrent state is updated in place."""
+    from tpudml.models import HybridLM
+    from tpudml.serve.engine import make_stateful_decode_step
+
+    slots, rows = 128, 4096
+    model = HybridLM(
+        vocab_size=1024, pattern="ME*", embed_dim=2688, num_heads=32,
+        num_kv_heads=2, head_dim=128, impl="flash", mamba_heads=64,
+        mamba_head_dim=64, n_groups=8, state_size=128, chunk_size=128,
+        num_experts=128, top_k=6, expert_dim=1856, shared_dim=3712,
+        routed_scale=2.5, held=(0, 64), dtype=bf16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(lambda: model.init_decode_cache(slots, rows, "bf16"))
+    state = jax.ShapeDtypeStruct((3, slots), i32, sharding=one)
+    text = make_stateful_decode_step(model).lower(
+        described(params), described(caches), state).compile().as_text()
+    assert " while(" not in text
+    up = params["layer1"]["mixer"]["experts"]["up"]
+    assert max(_copied_bytes(text)) < up.size * up.dtype.itemsize / 2
+    # The state is not among the copies, as stored or as the scan sees it.
+    # (The bfloat16 K/V cache IS copied, K and V of each attention layer a
+    # step: the grouped einsum wants [B, Hkv, L, D]. PERF.md §5.)
+    import re
+
+    assert caches[0].ssm.shape == (128, 64, 64, 128)
+    for dims in ("128,64,64,128", "128,8,8,64,128"):
+        assert not re.search(rf"= f32\[{dims}\]\{{[^}}]*\}} copy\(", text)
+
+
 # ------------------------------------------------- across the four chips
 # What exists only on a mesh: the SPMD partitioner refuses a bare Mosaic
 # kernel, so under the GSPMD engines the kernels run per shard; and the

@@ -27,7 +27,9 @@ fsdp_tp / pp_dp / ep``), ``mesh`` (axis-name → size dict), ``zero1``,
 ``moe_experts``, ``grad_clip``, ``schedule``, ``flash_attn``, ``impl``,
 ``seq_sharded``, ``tp_overlap``, ``serve_tp``, ``serve_cache_layout``,
 ``serve_spec_k``, ``serve_weight_quant``, ``serve_fused_head``,
-``serve_fleet``, ``mpmd``, ``serve``.  Entries with ``when=None``
+``serve_fleet``, ``serve_pattern`` (the served model is a pattern model,
+``tpudml.models.hybrid``), ``serve_slo``, ``serve_handoff``, ``mpmd``,
+``serve``.  Entries with ``when=None``
 are constructor-level invariants the planner can never generate (e.g.
 handing a pre-wrapped ZeRO1 optimizer to a non-zero1 engine) — they
 still own their runtime message here so the guard text stays in the
@@ -294,6 +296,82 @@ _ENTRIES = (
             _g(c, "serve_cache_layout", "dense") != "dense"
             or _g(c, "serve_spec_k", 0) > 0
         ),
+    ),
+    Capability(
+        key="serve_pattern_paged",
+        owner="tpudml.serve.engine",
+        message=(
+            "a pattern model (tpudml.models.hybrid) serves with "
+            "cache_layout='dense' only: the page pool holds K/V pages and "
+            "has no page for a recurrent state, nor a rule for sharing "
+            "one across a prefix"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern"))
+        and _g(c, "serve_cache_layout", "dense") != "dense",
+    ),
+    Capability(
+        key="serve_pattern_spec",
+        owner="tpudml.serve.engine",
+        message=(
+            "a pattern model does not compose with spec_k>0 yet: a "
+            "rejected draft token has already advanced the recurrent "
+            "state, and the verify window has no way to roll it back"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern"))
+        and _g(c, "serve_spec_k", 0) > 0,
+    ),
+    Capability(
+        key="serve_pattern_tp",
+        owner="tpudml.serve.engine",
+        message=(
+            "a pattern model does not compose with tensor-parallel "
+            "serving yet: TPServing's shard_map body knows the GPT block "
+            "only (no recurrent state, no expert exchange)"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern")) and bool(_g(c, "serve_tp")),
+    ),
+    Capability(
+        key="serve_pattern_fused_head",
+        owner="tpudml.serve.engine",
+        message=(
+            "a pattern model does not compose with fused_head yet: the "
+            "fused tail reads TransformerLM's LayerNorm features and a "
+            "biased head"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern"))
+        and bool(_g(c, "serve_fused_head")),
+    ),
+    Capability(
+        key="serve_pattern_weight_quant",
+        owner="tpudml.serve.engine",
+        message=(
+            "a pattern model does not compose with weight_quant yet: the "
+            "per-output-channel int8 rule is written for 2-D Dense "
+            "kernels, not for stacked expert weights or a float32 router"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern"))
+        and _g(c, "serve_weight_quant") is not None,
+    ),
+    Capability(
+        key="serve_pattern_slo",
+        owner="tpudml.serve.sched",
+        message=(
+            "DecodeCostModel prices a GPT-shaped block (K/V rows and a 4x "
+            "MLP); it cannot price a pattern model's experts and "
+            "recurrent state, so slo= is rejected for one"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern")) and bool(_g(c, "serve_slo")),
+    ),
+    Capability(
+        key="serve_pattern_handoff",
+        owner="tpudml.serve.fleet.disagg",
+        message=(
+            "disaggregated prefill hands off content-hashed K/V pages; a "
+            "pattern model's recurrent state is not a page and is not "
+            "handed off yet"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern"))
+        and bool(_g(c, "serve_handoff")),
     ),
     Capability(
         key="mpmd_moe_aux_loss",

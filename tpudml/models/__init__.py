@@ -1,3 +1,4 @@
+from tpudml.models.hybrid import HybridLM
 from tpudml.models.lenet import LeNet
 from tpudml.models.mlp import ForwardMLP
 from tpudml.models.resnet import ResNet, ResNet18, ResNet34, ResNet50
@@ -10,6 +11,7 @@ from tpudml.models.transformer import (
 )
 
 __all__ = [
+    "HybridLM",
     "LeNet",
     "ForwardMLP",
     "ResNet",

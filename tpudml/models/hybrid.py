@@ -1,0 +1,229 @@
+"""A decoder-only language model whose layers are listed by a pattern.
+
+``pattern`` is one character a layer: ``M`` a Mamba-2 mixer
+(`tpudml.nn.mamba.Mamba2`), ``E`` a sigmoid-routed mixture of experts with
+a shared expert (`tpudml.nn.moe.SigmoidMoE`), ``*`` causal grouped-query
+attention with an explicit head size, no bias and no positional encoding.
+Every layer is ``h <- h + mixer(RMSNorm(h))``; after the last,
+``logits = RMSNorm_f(h) @ W_head`` (untied, no bias). There is no position
+table: the state-space layers carry order.
+
+The model serves through the unmodified ``ServingEngine`` entry point with
+the dense cache layout. Its per-layer cache tuple holds two kinds of
+per-slot state (`tpudml.serve.cache`): a ``KVCache`` for ``*``, a
+``RecurrentState`` for ``M``, and ``None`` for ``E``. Because a recurrent
+state has no mask to hide stale or padded tokens behind, the model is
+``stateful`` and the engine then (1) zeroes a slot's state when a request
+takes the slot (``reset_slot``), (2) tells prefill how many tokens of a
+padded chunk are real, and (3) tells decode which slots are active — the
+others keep their state and reach no expert. The decode step also returns
+the expert layers' counters for active slots, and both steps return every
+token's expert choices (``routes``: ``route_width`` int32 a token, the
+``E`` layers in order, ``top_k`` each), which the engine keeps a request in
+``RequestStats.routes`` — what a reference needs to follow the program's
+routing, and what an expert-placement study reads.
+
+``held = (first, count)`` gives every ``E`` layer one chip's share of the
+experts (`SigmoidMoE`): it routes over all ``num_experts`` and computes its
+own experts' part.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from tpudml.nn.attention import MultiHeadAttention
+from tpudml.nn.layers import Module, RMSNorm
+from tpudml.nn.mamba import Mamba2
+from tpudml.nn.moe import SigmoidMoE
+
+KINDS = "ME*"
+
+
+@dataclass(frozen=True)
+class HybridLM(Module):
+    vocab_size: int
+    pattern: str = "ME*M"
+    embed_dim: int = 64
+    # attention (`*`)
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    impl: str = "full"  # "flash": the Pallas kernel in `apply` and prefill on TPU
+    # Mamba-2 (`M`)
+    mamba_heads: int = 8
+    mamba_head_dim: int = 16
+    n_groups: int = 2
+    state_size: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # mixture of experts (`E`)
+    num_experts: int = 8
+    top_k: int = 2
+    expert_dim: int = 32
+    shared_dim: int = 64
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    held: tuple[int, int] | None = None
+    eps: float = 1e-5
+    dtype: Any = jnp.float32  # parameters and the residual stream
+    state_dtype: Any = jnp.float32  # the recurrence's stored state
+
+    # What ServingEngine reads (see the module docstring).
+    stateful = True
+    counter_names = ("moe_routed", "moe_held", "experts_touched", "expert_load_max")
+    max_positions = None  # no position table bounds the cache
+
+    def __post_init__(self):
+        bad = set(self.pattern) - set(KINDS)
+        if bad or not self.pattern:
+            raise ValueError(f"pattern {self.pattern!r}: layer kinds are {KINDS!r}")
+        if self.impl not in ("full", "flash"):
+            raise ValueError(f"impl must be 'full' or 'flash', got {self.impl!r}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def route_width(self) -> int:
+        """int32 values a token in ``routes``: ``top_k`` an ``E`` layer."""
+        return self.pattern.count("E") * self.top_k
+
+    def _norm(self) -> RMSNorm:
+        return RMSNorm(self.embed_dim, self.eps, self.dtype)
+
+    def _mixer(self, kind: str) -> Module:
+        if kind == "M":
+            return Mamba2(self.embed_dim, self.mamba_heads, self.mamba_head_dim,
+                          self.n_groups, self.state_size, self.conv_kernel,
+                          self.chunk_size, self.eps, self.dtype, self.state_dtype)
+        if kind == "E":
+            return SigmoidMoE(self.embed_dim, self.num_experts, self.top_k,
+                              self.expert_dim, self.shared_dim, self.routed_scale,
+                              self.norm_topk, self.held, self.dtype)
+        return MultiHeadAttention(
+            self.embed_dim, self.num_heads, causal=True, impl=self.impl,
+            num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+            use_bias=False, dtype=self.dtype)
+
+    def init(self, key):
+        keys = jax.random.split(key, self.num_layers + 2)
+        params = {
+            "embed": (0.02 * jax.random.normal(
+                keys[0], (self.vocab_size, self.embed_dim), jnp.float32)).astype(self.dtype),
+            "norm_f": self._norm().init(key)[0],
+            "head": {"kernel": (0.02 * jax.random.normal(
+                keys[1], (self.embed_dim, self.vocab_size), jnp.float32)).astype(self.dtype)},
+        }
+        for i, kind in enumerate(self.pattern):
+            params[f"layer{i}"] = {"norm": self._norm().init(key)[0],
+                                   "mixer": self._mixer(kind).init(keys[i + 2])[0]}
+        return params, {}
+
+    def _layers(self, params, h, mix):
+        """The residual trunk: ``mix(i, kind, mixer, p, u)`` gives layer i's
+        mixer output on the normed stream u."""
+        for i, kind in enumerate(self.pattern):
+            p = params[f"layer{i}"]
+            u, _ = self._norm().apply(p["norm"], {}, h)
+            h = h + mix(i, kind, self._mixer(kind), p["mixer"], u)
+        return h
+
+    def _logits(self, params, h):
+        y, _ = self._norm().apply(params["norm_f"], {}, h)
+        return y @ params["head"]["kernel"]
+
+    def apply(self, params, state, tokens, *, train=False, rng=None):
+        """tokens [B, T] -> logits [B, T, V]: the whole sequence, no cache."""
+        h = self._layers(params, params["embed"][tokens],
+                         lambda i, kind, mixer, p, u: mixer.apply(p, {}, u)[0])
+        return self._logits(params, h), state
+
+    # ------------------------------------------------------------ serving
+
+    def init_decode_cache(self, batch: int, max_len: int, kind: str = "f32"):
+        """The per-layer cache tuple for ``batch`` slots. ``kind`` governs
+        the K/V caches only; a recurrent state is ``state_dtype`` and its
+        convolution window the stream's dtype."""
+        from tpudml.serve.cache import init_cache, init_recurrent_state
+
+        m = self._mixer("M")
+        made = {
+            "M": lambda: init_recurrent_state(
+                batch, self.conv_kernel - 1, m.conv_dim, self.mamba_heads,
+                self.mamba_head_dim, self.state_size, self.dtype, self.state_dtype),
+            "E": lambda: None,
+            "*": lambda: init_cache(batch, max_len, self.num_kv_heads, self.head_dim, kind),
+        }
+        return tuple(made[k]() for k in self.pattern)
+
+    def reset_slot(self, caches, slot):
+        from tpudml.serve.cache import reset_slot_state
+
+        return reset_slot_state(caches, slot)
+
+    def apply_prefill(self, params, caches, chunk, slot, start: int, n_real):
+        """Prefill one chunk of one slot's prompt: ``chunk`` [1, C] tokens at
+        positions [start, start + C) of which the first ``n_real`` (traced)
+        are real -> (updated caches, routes [C, route_width]). ``start`` is
+        static, as for every model the engine serves; the recurrent layers
+        continue from the slot's stored state, which admission zeroed before
+        the first chunk."""
+        new = list(caches)
+        routes = []
+        real = (jnp.arange(chunk.shape[1]) < n_real)[None]
+
+        def mix(i, kind, mixer, p, u):
+            if kind == "M":
+                out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, n_real)
+            elif kind == "*":
+                out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, start)
+            else:
+                out, counts = mixer.forward(p, u, real)
+                routes.append(counts["choices"])
+            return out
+
+        self._layers(params, params["embed"][chunk], mix)
+        return tuple(new), self._routes(routes, chunk.shape[1])
+
+    def apply_decode(self, params, caches, tokens, pos, active):
+        """One decode step: ``tokens`` [B] at per-slot positions ``pos`` [B],
+        ``active`` [B] bool -> (logits [B, V], updated caches, counters,
+        routes [B, route_width]).
+        The counters are int32 scalars over the expert layers, for active
+        slots only: ``moe_routed`` (token, choice) pairs, ``moe_held`` of
+        them on held experts, ``experts_touched`` held experts with at
+        least one token (summed over layers), ``expert_load_max`` the most
+        tokens on one expert of one layer."""
+        new = list(caches)
+        seen = []
+
+        def mix(i, kind, mixer, p, u):
+            if kind == "M":
+                out, new[i] = mixer.apply_decode(p, caches[i], u, active)
+            elif kind == "*":
+                out, new[i] = mixer.apply_decode(p, caches[i], u, pos)
+            else:
+                out, counts = mixer.forward(p, u, active[:, None])
+                seen.append(counts)
+            return out
+
+        h = self._layers(params, params["embed"][tokens][:, None, :], mix)
+        zero = jnp.zeros((), jnp.int32)
+        counters = {
+            "moe_routed": sum((c["routed"] for c in seen), zero),
+            "moe_held": sum((c["held"] for c in seen), zero),
+            "experts_touched": sum((c["touched"] for c in seen), zero),
+            "expert_load_max": jnp.max(jnp.stack([c["load_max"] for c in seen] or [zero])),
+        }
+        routes = self._routes([c["choices"] for c in seen], tokens.shape[0])
+        return self._logits(params, h)[:, 0, :], tuple(new), counters, routes
+
+    def _routes(self, choices: list, n: int):
+        """The ``E`` layers' choices [n, top_k] side by side: [n, route_width]."""
+        return jnp.concatenate(choices or [jnp.zeros((n, 0), jnp.int32)], axis=-1)

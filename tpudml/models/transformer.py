@@ -375,6 +375,16 @@ class TransformerLM(Module):
     # unset) must keep computing in bf16, not get upcast.
     compute_dtype: Any = None
 
+    @property
+    def head_dim(self) -> int:
+        return self.embed_dim // self.num_heads
+
+    @property
+    def max_positions(self) -> int | None:
+        """Positions the model can embed: the learned table's length, or
+        None under RoPE (which extrapolates)."""
+        return None if self.rope else self.max_len
+
     def _block(self) -> TransformerBlock:
         return TransformerBlock(
             self.embed_dim,

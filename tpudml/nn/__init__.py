@@ -6,13 +6,16 @@ from tpudml.nn.layers import (
     Dense,
     Dropout,
     Flatten,
+    GatedGroupRMSNorm,
     LayerNorm,
     MaxPool,
     Module,
+    RMSNorm,
     Sequential,
 )
 from tpudml.nn.attention import MultiHeadAttention, dot_product_attention
-from tpudml.nn.moe import MoELayer, load_balancing_loss
+from tpudml.nn.mamba import Mamba2
+from tpudml.nn.moe import MoELayer, SigmoidMoE, load_balancing_loss
 
 __all__ = [
     "Module",
@@ -25,9 +28,13 @@ __all__ = [
     "BatchNorm",
     "Dropout",
     "LayerNorm",
+    "RMSNorm",
+    "GatedGroupRMSNorm",
     "Sequential",
     "MultiHeadAttention",
     "dot_product_attention",
     "MoELayer",
+    "SigmoidMoE",
+    "Mamba2",
     "load_balancing_loss",
 ]

@@ -122,6 +122,25 @@ def decode_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
+def decode_attention_grouped(
+    q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array
+) -> jax.Array:
+    """:func:`decode_attention` for grouped-query attention without the
+    repeat: q [B, 1, H, D] over k/v [B, L, Hkv, D], query head h reading K/V
+    head h // (H / Hkv). Repeating the cache to H heads first would
+    materialize H / Hkv copies of it every step."""
+    b, _, h, d = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, hkv, h // hkv, d)
+    s = jnp.einsum(
+        "bkgd,blkd->bkgl", qg, k, preferred_element_type=jnp.float32
+    ) / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    mask = jnp.arange(k.shape[1])[None, :] <= pos[:, None]  # [B, L]
+    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bkgl,blkd->bkgd", p, v).reshape(b, 1, h, d)
+
+
 def decode_attention_window(
     q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array
 ) -> jax.Array:
@@ -213,9 +232,14 @@ class MultiHeadAttention(Module):
     # the balanced causal-ring layout; positions become idx + W·j).
     seq_layout: str = "contiguous"
     dtype: Any = jnp.float32
+    # Width of one head; None derives it as embed_dim // num_heads. Set, the
+    # q and out projections are embed_dim x num_heads·head_dim, which need
+    # not equal embed_dim.
+    head_dim: int | None = None
+    use_bias: bool = True
 
     def __post_init__(self):
-        if self.embed_dim % self.num_heads:
+        if self.head_dim is None and self.embed_dim % self.num_heads:
             raise ValueError(
                 f"embed_dim {self.embed_dim} % num_heads {self.num_heads} != 0"
             )
@@ -234,17 +258,30 @@ class MultiHeadAttention(Module):
             raise ValueError(
                 f"seq_layout='striped' requires impl='ring', got {self.impl!r}"
             )
-        if self.rope and (self.embed_dim // self.num_heads) % 2:
+        if self.rope and self._head_dim % 2:
             # RoPE rotates feature PAIRS; an odd head_dim would silently
             # broadcast to the wrong width instead of erroring later.
             raise ValueError(
-                f"rope requires an even head_dim, got "
-                f"{self.embed_dim // self.num_heads}"
+                f"rope requires an even head_dim, got {self._head_dim}"
             )
 
     @property
     def _kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def _head_dim(self) -> int:
+        return self.head_dim or self.embed_dim // self.num_heads
+
+    @property
+    def _inner(self) -> int:
+        """Width of the concatenated heads (embed_dim unless head_dim is set)."""
+        return self.num_heads * self._head_dim
+
+    @staticmethod
+    def _dense(p, x):
+        y = x @ p["kernel"]
+        return y + p["bias"] if "bias" in p else y
 
     def init(self, key):
         # Separate q/k/v projections (not a fused [d, 3d] kernel): shards of
@@ -258,27 +295,27 @@ class MultiHeadAttention(Module):
         # shrink to kv_heads·head_dim — fewer KV parameters and a
         # kv_heads-sized cache at inference.
         kq, kk, kv, ko = jax.random.split(key, 4)
-        head_dim = self.embed_dim // self.num_heads
-        proj = Dense(self.embed_dim, self.embed_dim, dtype=self.dtype)
-        kv_proj = Dense(self.embed_dim, self._kv_heads * head_dim, dtype=self.dtype)
+        bias = self.use_bias
+        proj = Dense(self.embed_dim, self._inner, bias, dtype=self.dtype)
+        kv_proj = Dense(self.embed_dim, self._kv_heads * self._head_dim, bias,
+                        dtype=self.dtype)
+        out = Dense(self._inner, self.embed_dim, bias, dtype=self.dtype)
         return {
             "q": proj.init(kq)[0],
             "k": kv_proj.init(kk)[0],
             "v": kv_proj.init(kv)[0],
-            "out": proj.init(ko)[0],
+            "out": out.init(ko)[0],
         }, {}
 
     def _heads(self, x, n_heads):
         b, t, _ = x.shape
-        return x.reshape(b, t, n_heads, self.embed_dim // self.num_heads)
+        return x.reshape(b, t, n_heads, self._head_dim)
 
     def apply(self, params, state, x, *, train=False, rng=None):
         b, t, _ = x.shape
-        q = self._heads(x @ params["q"]["kernel"] + params["q"]["bias"], self.num_heads)
+        q = self._heads(self._dense(params["q"], x), self.num_heads)
         k, v = (
-            self._heads(
-                x @ params[n]["kernel"] + params[n]["bias"], self._kv_heads
-            )
+            self._heads(self._dense(params[n], x), self._kv_heads)
             for n in ("k", "v")
         )
         if self.rope:
@@ -314,8 +351,8 @@ class MultiHeadAttention(Module):
             o = ulysses_attention(q, k, v, self.axis_name, causal=self.causal)
         else:
             raise ValueError(f"unknown attention impl {self.impl!r}")
-        o = o.reshape(b, t, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], state
+        o = o.reshape(b, t, self._inner)
+        return self._dense(params["out"], o), state
 
     # ----------------------------------------------------- serving paths
     # Incremental decode + chunked prefill over a tpudml.serve KVCache.
@@ -339,14 +376,10 @@ class MultiHeadAttention(Module):
         overridable so the TP decode step can run the same code on a
         head-sharded parameter shard."""
         q = self._heads(
-            x @ params["q"]["kernel"] + params["q"]["bias"],
-            n_local_heads or self.num_heads,
+            self._dense(params["q"], x), n_local_heads or self.num_heads
         )
         k, v = (
-            self._heads(
-                x @ params[n]["kernel"] + params[n]["bias"],
-                n_local_kv or self._kv_heads,
-            )
+            self._heads(self._dense(params[n], x), n_local_kv or self._kv_heads)
             for n in ("k", "v")
         )
         return q, k, v
@@ -372,9 +405,13 @@ class MultiHeadAttention(Module):
             k_new = rotary_embedding(k_new, pos[:, None], self.rope_base)
         cache = write_token(cache, k_new, v_new, pos)
         k, v = read_all(cache, x.dtype)
-        k, v = self._gqa_repeat(k, v, self.num_heads)
-        o = decode_attention(q, k, v, pos).reshape(b, 1, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], cache
+        if 1 < k.shape[2] < q.shape[2]:
+            o = decode_attention_grouped(q, k, v, pos)
+        else:  # MHA as it is; one K/V head broadcasts
+            k, v = self._gqa_repeat(k, v, self.num_heads)
+            o = decode_attention(q, k, v, pos)
+        o = o.reshape(b, 1, self._inner)
+        return self._dense(params["out"], o), cache
 
     def apply_decode_window(self, params, cache, x, pos):
         """Decode a window of Q consecutive tokens per slot: x [B, Q, d]
@@ -397,8 +434,8 @@ class MultiHeadAttention(Module):
         k, v = read_all(cache, x.dtype)
         k, v = self._gqa_repeat(k, v, self.num_heads)
         o = decode_attention_window(q, k, v, pos)
-        o = o.reshape(b, qlen, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], cache
+        o = o.reshape(b, qlen, self._inner)
+        return self._dense(params["out"], o), cache
 
     def apply_decode_paged(self, params, pool, table, x, pos):
         """Decode step over a paged pool: x [B, Q, d] (Q=1 plain decode,
@@ -421,8 +458,8 @@ class MultiHeadAttention(Module):
         k, v = read_table(pool, table, x.dtype)
         k, v = self._gqa_repeat(k, v, self.num_heads)
         o = decode_attention_window(q, k, v, pos)
-        o = o.reshape(b, qlen, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], pool
+        o = o.reshape(b, qlen, self._inner)
+        return self._dense(params["out"], o), pool
 
     def apply_prefill_paged(self, params, pool, table_row, x, start: int):
         """Prefill one chunk of the slot owning ``table_row``
@@ -444,8 +481,8 @@ class MultiHeadAttention(Module):
             o = _chunk_flash_window(q, k, v, start)
         else:
             o = dot_product_attention(q, k, v, causal=True, q_offset=start)
-        o = o.reshape(1, c, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], pool
+        o = o.reshape(1, c, self._inner)
+        return self._dense(params["out"], o), pool
 
     def apply_prefill(self, params, cache, x, slot, start: int):
         """Prefill one chunk of one slot: x [1, C, d] are features of
@@ -472,5 +509,5 @@ class MultiHeadAttention(Module):
             o = _chunk_flash_window(q, k, v, start)
         else:
             o = dot_product_attention(q, k, v, causal=True, q_offset=start)
-        o = o.reshape(1, c, self.embed_dim)
-        return o @ params["out"]["kernel"] + params["out"]["bias"], cache
+        o = o.reshape(1, c, self._inner)
+        return self._dense(params["out"], o), cache

@@ -288,6 +288,54 @@ class LayerNorm(Module):
 
 
 @dataclass(frozen=True)
+class RMSNorm(Module):
+    """Root-mean-square normalization over the trailing feature axis:
+    ``x * rsqrt(mean(x^2) + eps) * scale``, no centring and no bias.
+    Statistics in float32, result in the input dtype (as LayerNorm)."""
+
+    num_features: int
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    def init(self, key):
+        return {"scale": jnp.ones((self.num_features,), self.dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + self.eps)
+        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype), state
+
+
+@dataclass(frozen=True)
+class GatedGroupRMSNorm(Module):
+    """Mamba-2's output norm: ``RMSNorm_groups(x * silu(gate)) * scale``,
+    the gate applied BEFORE the norm and the statistics taken over each of
+    ``num_groups`` equal slices of the feature axis."""
+
+    num_features: int
+    num_groups: int = 1
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.num_features % self.num_groups:
+            raise ValueError(
+                f"num_features {self.num_features} % num_groups "
+                f"{self.num_groups} != 0"
+            )
+
+    def init(self, key):
+        return {"scale": jnp.ones((self.num_features,), self.dtype)}, {}
+
+    def apply(self, params, state, x, *, gate, train=False, rng=None):
+        xf = x.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+        g = xf.reshape(*xf.shape[:-1], self.num_groups, -1)
+        g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + self.eps)
+        y = g.reshape(xf.shape) * params["scale"].astype(jnp.float32)
+        return y.astype(x.dtype), state
+
+
+@dataclass(frozen=True)
 class Sequential(Module):
     """Chain of modules; params/state are dicts keyed ``layer{i}``."""
 

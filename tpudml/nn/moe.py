@@ -385,6 +385,130 @@ class MoELayer(Module):
         )
 
 
+@dataclass(frozen=True)
+class SigmoidMoE(Module):
+    """Sigmoid-routed mixture of experts with a shared expert, told which
+    experts it HOLDS.
+
+    In float32, ``s = sigmoid(u @ W_r)`` over the router's whole width
+    ``num_experts``; a token's experts are the top-k of ``s + bias`` (the
+    bias only chooses); their weights are ``routed_scale * s_e / (sum of
+    the k chosen s + 1e-20)`` (``norm_topk``). An expert is two matrices,
+    ``relu(u @ up)^2 @ down``; the shared expert, of width ``shared_dim``,
+    sees every token.
+
+    ``held = (first, count)``: the contiguous experts whose weights live
+    here (default: all of them). The layer routes over all ``num_experts``
+    and computes only its own experts' part of the sum, for the tokens
+    routed to them, plus the shared expert — one chip's share of an
+    expert-parallel layer, without the exchange. What the other experts
+    would add is left out, not approximated.
+
+    Dropless and dense over the held experts: every held expert runs over
+    every token and a [tokens, held] matrix of weights (zero where a token
+    was not routed to the expert, or is not ``active``) combines them, both
+    matmuls contracting over (expert, width) at once. On the v5e, at the
+    published widths with 64 experts held, this reads the experts' weights
+    at 90 % of the memory roof for 128 tokens (1.74 ms a layer) where
+    ``lax.ragged_dot`` over the sorted pairs took 15.3 ms (its kernel walks
+    64 groups of ~6 rows) and, the width 1856 not filling whole 128-lane
+    tiles, a 640 MB transposing copy of the weights a step on top (PERF.md
+    §6, PR 30). The cost is FLOPs on tokens an expert was not given: 4x at a
+    512-token prefill chunk, which a grouped kernel would win back.
+    """
+
+    embed_dim: int
+    num_experts: int
+    top_k: int
+    expert_dim: int
+    shared_dim: int
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    held: tuple[int, int] | None = None
+    dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        first, count = self._held
+        if not (0 <= first and count >= 1 and first + count <= self.num_experts):
+            raise ValueError(
+                f"held experts {self.held} outside [0, {self.num_experts})")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"top_k {self.top_k} must be in [1, num_experts={self.num_experts}]")
+
+    @property
+    def _held(self) -> tuple[int, int]:
+        return self.held or (0, self.num_experts)
+
+    def init(self, key):
+        d, h, hs = self.embed_dim, self.expert_dim, self.shared_dim
+        n = self._held[1]
+        kr, ku, kd, ksu, ksd = jax.random.split(key, 5)
+        return {
+            "router": {"kernel": _uniform_fan_in(kr, (d, self.num_experts), d,
+                                                 jnp.float32),
+                       "bias": jnp.zeros((self.num_experts,), jnp.float32)},
+            "experts": {"up": _uniform_fan_in(ku, (n, d, h), d, self.dtype),
+                        "down": _uniform_fan_in(kd, (n, h, d), h, self.dtype)},
+            "shared": {"up": _uniform_fan_in(ksu, (d, hs), d, self.dtype),
+                       "down": _uniform_fan_in(ksd, (hs, d), hs, self.dtype)},
+        }, {}
+
+    def scores(self, params, tokens):
+        """s [G, num_experts] float32 of tokens [G, d], computed in float32."""
+        return jax.nn.sigmoid(jnp.dot(
+            tokens.astype(jnp.float32), params["router"]["kernel"],
+            precision=lax.Precision.HIGHEST))
+
+    def route(self, params, tokens):
+        """(experts [G, k] int32, weights [G, k] float32) of tokens [G, d]."""
+        s = self.scores(params, tokens)
+        _, topi = lax.top_k(s + params["router"]["bias"], self.top_k)
+        w = jnp.take_along_axis(s, topi, axis=-1)
+        if self.norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return topi.astype(jnp.int32), self.routed_scale * w
+
+    def forward(self, params, x, active=None):
+        """x [..., d] -> (y [..., d], counts). ``active`` [...] bool marks
+        the tokens that count (default all): the others reach no expert.
+        ``counts`` are int32 scalars over active tokens: ``routed`` (token,
+        choice) pairs, ``held`` of them on held experts, ``touched`` held
+        experts with at least one token, ``load_max`` tokens on the busiest;
+        and ``choices`` [G, k] int32, every token's experts as the router
+        chose them (of all ``num_experts``, held or not, active or not).
+
+        ``touched`` counts what a step would have to read if it read only
+        the experts that have a token. This dense form reads and multiplies
+        all the held experts whatever ``touched`` says: the count is a lower
+        bound the program does not follow yet."""
+        shape = x.shape
+        tokens = x.reshape(-1, self.embed_dim)
+        g, k = tokens.shape[0], self.top_k
+        first, count = self._held
+        topi, w = self.route(params, tokens)
+        live = jnp.ones((g,), bool) if active is None else active.reshape(g)
+        local = topi - first
+        mine = (local >= 0) & (local < count) & live[:, None]  # [G, k]
+        # [G, k, count]: one-hot of each pair's held expert (none: all zero)
+        onehot = jax.nn.one_hot(jnp.where(mine, local, -1), count, dtype=jnp.float32)
+        comb = jnp.einsum("gk,gke->ge", w, onehot)  # weights, zero elsewhere
+        load = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)  # tokens an expert
+        ex = params["experts"]
+        hidden = jnp.square(jax.nn.relu(jnp.einsum("gd,edh->geh", tokens, ex["up"])))
+        hidden = (hidden.astype(jnp.float32) * comb[..., None]).astype(tokens.dtype)
+        y = jnp.einsum("geh,ehd->gd", hidden, ex["down"])
+        sh = params["shared"]
+        y = y + jnp.square(jax.nn.relu(tokens @ sh["up"])) @ sh["down"]
+        counts = {"routed": jnp.sum(live).astype(jnp.int32) * k,
+                  "held": jnp.sum(load), "touched": jnp.sum(load > 0).astype(jnp.int32),
+                  "load_max": jnp.max(load), "choices": topi}
+        return y.reshape(shape), counts
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        return self.forward(params, x)[0], state
+
+
 def load_balancing_loss(params: dict, x: jax.Array, num_experts: int) -> jax.Array:
     """Switch-style auxiliary loss: E · Σ_e fraction_e · mean_prob_e —
     minimized (→1) when routing is uniform. Add ``α·aux`` to the training

@@ -14,7 +14,7 @@ weight quantization — imported lazily, see ``tpudml.serve.fleet``).
 See docs/API.md §Serving.
 """
 
-from tpudml.serve.cache import KVCache, cache_bytes, init_cache
+from tpudml.serve.cache import KVCache, RecurrentState, cache_bytes, init_cache
 from tpudml.serve.engine import (
     SERVE_DECODE_MARKER,
     RequestStats,
@@ -59,6 +59,7 @@ def __getattr__(name):
 
 
 __all__ = [
+    "RecurrentState",
     "FleetConfig",
     "FleetReport",
     "FleetRequestStats",

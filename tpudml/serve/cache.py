@@ -28,6 +28,11 @@ Writes happen BEFORE the attention read at a step, so slot positions
 beyond a sequence's current token only ever hold zeros-or-stale values
 that the causal mask (``k_pos <= pos``) excludes; no masking state is
 stored in the cache itself.
+
+A second kind of per-slot state lives beside the K/V cache: the
+``RecurrentState`` of a state-space layer (end of this file). A model's
+per-layer cache tuple may hold both kinds, and ``None`` for a layer that
+keeps nothing between tokens.
 """
 
 from __future__ import annotations
@@ -222,3 +227,55 @@ def cache_bytes(cache: KVCache) -> int:
     exists to shrink."""
     return sum(x.size * x.dtype.itemsize
                for x in (cache.k, cache.v, cache.k_scale, cache.v_scale))
+
+
+# ----------------------------------------------------------- recurrent state
+# The second kind of per-slot state: what a state-space layer carries from
+# token to token. No mask hides it — a slot's state is whatever its last
+# update left — so the engine zeroes it when a new request takes the slot
+# (``reset_slot_state``) and prefill is told how many tokens of a padded
+# chunk are real (nn/mamba.py).
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class RecurrentState:
+    """One state-space layer's per-slot state."""
+
+    conv: jax.Array  # [B, K-1, conv_dim]: the convolution's last K-1 inputs
+    ssm: jax.Array  # [B, H, P, N]: the recurrence's state
+
+
+def init_recurrent_state(batch: int, window: int, conv_dim: int, heads: int,
+                         head_dim: int, state_size: int, dtype=jnp.float32,
+                         state_dtype=jnp.float32) -> RecurrentState:
+    return RecurrentState(
+        conv=jnp.zeros((batch, window, conv_dim), dtype),
+        ssm=jnp.zeros((batch, heads, head_dim, state_size), state_dtype))
+
+
+def read_slot_state(state: RecurrentState, slot: jax.Array):
+    """(window [1, K-1, conv_dim], ssm [1, H, P, N]) of one slot (traced)."""
+    return (lax.dynamic_slice_in_dim(state.conv, slot, 1, axis=0),
+            lax.dynamic_slice_in_dim(state.ssm, slot, 1, axis=0))
+
+
+def write_slot_state(state: RecurrentState, slot: jax.Array, window: jax.Array,
+                     ssm: jax.Array) -> RecurrentState:
+    return RecurrentState(
+        conv=lax.dynamic_update_slice_in_dim(
+            state.conv, window.astype(state.conv.dtype), slot, axis=0),
+        ssm=lax.dynamic_update_slice_in_dim(
+            state.ssm, ssm.astype(state.ssm.dtype), slot, axis=0))
+
+
+def reset_slot_state(caches: tuple, slot: jax.Array) -> tuple:
+    """Zero one slot's recurrent state in every layer that has one; K/V
+    caches (and layers with no cache, ``None``) pass through."""
+    def zero(c):
+        if not isinstance(c, RecurrentState):
+            return c
+        return write_slot_state(c, slot, jnp.zeros_like(c.conv[:1]),
+                                jnp.zeros_like(c.ssm[:1]))
+    return tuple(zero(c) for c in caches)
+

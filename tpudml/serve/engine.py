@@ -27,7 +27,11 @@ request churn never triggers a recompile.
 
 Stale cache rows need no zeroing on eviction: a slot's attention mask is
 ``k_pos <= pos``, and every position is written before it is first
-unmasked, so a new occupant can never read its predecessor's K/V.
+unmasked, so a new occupant can never read its predecessor's K/V. A
+recurrent state has no such mask: for a ``stateful`` model
+(``tpudml.models.hybrid``) admission zeroes the slot's state, prefill is
+told how many tokens of a padded chunk are real, and the decode step is
+told which slots are active (``make_stateful_decode_step``).
 
 Three multi-tenant levers compose on top, each flag-gated in
 ``ServeConfig`` and each greedy-parity-exact against the dense path:
@@ -88,6 +92,38 @@ def make_decode_step(model):
 
     def step(params, caches, tokens, pos):
         return inner(params, caches, tokens, pos)
+
+    return jax.jit(step, donate_argnums=(1,))
+
+
+def make_stateful_decode_step(model):
+    """:func:`make_decode_step` for a ``stateful`` model (one with per-slot
+    state no attention mask hides, `tpudml.models.hybrid`): (params, caches,
+    state int32 [3, B] = tokens, positions, active 0/1) -> (next tokens [B]
+    followed by the model's counters and its routes, logits [B, V], updated
+    caches). Slots that are not active keep their state and take part in no
+    expert's batch. ``model.counter_names`` names the int32 scalars over
+    active slots that ride behind the tokens, and ``model.route_width`` the
+    int32 values a slot behind those (every slot's expert choices at this
+    token), so that the run loop sends one array and fetches one (the host's
+    share of a 25 ms pass is what makes its tail noisy; PERF.md §6, PR 30).
+    The counters go on ``serve/commit``, the routes into
+    ``RequestStats.routes``."""
+
+    def _serve_decode_step(params, caches, state):
+        logits, caches, counters, routes = model.apply_decode(
+            params, caches, state[0], state[1], state[2] != 0)
+        packed = jnp.concatenate([
+            jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            jnp.stack([counters[k] for k in model.counter_names]),
+            routes.astype(jnp.int32).reshape(-1)])
+        return packed, logits, caches
+
+    assert _serve_decode_step.__name__ == SERVE_DECODE_MARKER
+    inner = jax.jit(_serve_decode_step)
+
+    def step(params, caches, state):
+        return inner(params, caches, state)
 
     return jax.jit(step, donate_argnums=(1,))
 
@@ -314,6 +350,11 @@ class RequestStats:
     tokens: list = field(default_factory=list)
     token_times: list = field(default_factory=list)
     shared_pages: int = 0  # prefix-cache pages reused at admit (paged)
+    # A model with expert layers (``route_width``): int32 blocks
+    # [n, route_width], every position's expert choices in order — the
+    # prompt but its last token from prefill, then one row a decode step
+    # (``np.concatenate`` gives [prompt_len - 1 + len(tokens), route_width]).
+    routes: list = field(default_factory=list)
 
     @property
     def ttft_s(self) -> float | None:
@@ -443,7 +484,8 @@ class ServeReport:
 
 
 class ServingEngine:
-    """Continuous-batching prefill/decode over a ``TransformerLM``.
+    """Continuous-batching prefill/decode over a ``TransformerLM`` or a
+    pattern model (``HybridLM``, dense layout only).
 
     Single-device by default; pass ``mesh`` (+ ``axis_name``) to shard
     params, cache heads, and the decode step over a tensor-parallel axis
@@ -457,17 +499,34 @@ class ServingEngine:
         self.model = model
         self.cfg = config or ServeConfig()
         cfg = self.cfg
-        if not model.rope and cfg.max_len > model.max_len:
+        if (model.max_positions is not None
+                and cfg.max_len > model.max_positions):
             raise ValueError(
                 f"cache max_len {cfg.max_len} exceeds the position "
-                f"table ({model.max_len}); only RoPE models extrapolate"
+                f"table ({model.max_positions}); only RoPE models extrapolate"
             )
         self._paged = cfg.cache_layout == "paged"
+        # A model whose per-slot state no mask hides (a recurrent state;
+        # models/hybrid.py): admission zeroes the slot's state, prefill is
+        # told a chunk's real length, decode is told which slots are
+        # active and returns the model's counters. Its other levers are
+        # not built yet: loud rejections, never a silently wrong path.
+        self._stateful = bool(getattr(model, "stateful", False))
+        if self._stateful:
+            if self._paged:
+                reject("serve_pattern_paged", exc=ServeCompositionError)
+            if cfg.spec_k:
+                reject("serve_pattern_spec", exc=ServeCompositionError)
+            if mesh is not None:
+                reject("serve_pattern_tp", exc=ServeCompositionError)
+            if cfg.fused_head:
+                reject("serve_pattern_fused_head", exc=ServeCompositionError)
+            if cfg.weight_quant is not None:
+                reject("serve_pattern_weight_quant", exc=ServeCompositionError)
         # How the decode step writes its K/V rows (``serve/dispatch``'s
         # ``row_scatter``): the page pool always scatters, the dense
         # cache where its layout allows (serve/cache.py:row_scatter).
-        self._row_scatter = int(
-            self._paged or row_scatter(model.embed_dim // model.num_heads))
+        self._row_scatter = int(self._paged or row_scatter(model.head_dim))
         if mesh is not None and (self._paged or cfg.spec_k):
             # The TP decode step shards cache heads through a shard_map
             # body that knows nothing of page tables or verify windows.
@@ -539,6 +598,11 @@ class ServingEngine:
                     self._decode = make_fused_decode_step(
                         model, head_q=hq, head_scale=hs
                     )
+                elif self._stateful:
+                    self._decode = make_stateful_decode_step(model)
+                    self._reset_slot = jax.jit(model.reset_slot,
+                                               donate_argnums=(0,))
+                    self._route_backlog: list = []
                 else:
                     self._decode = make_decode_step(model)
                 self._prefill_builder = self._build_prefill
@@ -588,8 +652,13 @@ class ServingEngine:
     def _build_prefill(self, start: int):
         model = self.model
 
-        def _serve_prefill_chunk(params, caches, chunk, slot):
-            return model.apply_prefill(params, caches, chunk, slot, start)
+        if self._stateful:
+            def _serve_prefill_chunk(params, caches, chunk, slot, n_real):
+                return model.apply_prefill(params, caches, chunk, slot, start,
+                                           n_real)
+        else:
+            def _serve_prefill_chunk(params, caches, chunk, slot):
+                return model.apply_prefill(params, caches, chunk, slot, start)
 
         return jax.jit(_serve_prefill_chunk, donate_argnums=(1,))
 
@@ -662,22 +731,44 @@ class ServingEngine:
         """Prefill ``req``'s prompt (all but the last token) into a
         slot's cache rows; returns (pos, last_token) for the decode
         state. Chunk tails are padded — padded rows land at positions
-        the mask excludes until decode overwrites them."""
+        the mask excludes until decode overwrites them. A stateful
+        model's slot state is zeroed first (a one-token prompt runs no
+        chunk at all and would inherit the last tenant's state), and its
+        prefill is told how many tokens of each chunk are real."""
         prompt = self._validate_request(req)
         p = prompt.size - 1
         c = self.cfg.prefill_chunk
         starts = range(0, p, c)
         with self._admit_span(req, slot, prompt, len(starts), 0):
             slot_j = jnp.asarray(slot, jnp.int32)
+            if self._stateful:
+                self.caches = self._reset_slot(self.caches, slot_j)
             for s0 in starts:
                 chunk = np.zeros((1, c), np.int32)
                 n = min(c, p - s0)
                 chunk[0, :n] = prompt[s0:s0 + n]
-                self.caches = self._prefill_at(s0)(
-                    self.params, self.caches, jnp.asarray(chunk), slot_j
-                )
+                if self._stateful:
+                    # The chunk's routes stay on the device until the
+                    # next decode step's fetch (``_collect_routes``).
+                    self.caches, routes = self._prefill_at(s0)(
+                        self.params, self.caches, jnp.asarray(chunk), slot_j,
+                        np.int32(n))
+                    if routes.shape[1]:
+                        self._route_backlog.append((req.rid, routes, n))
+                else:
+                    self.caches = self._prefill_at(s0)(
+                        self.params, self.caches, jnp.asarray(chunk), slot_j
+                    )
             self._prefill_draft(slot, prompt)
         return p, int(prompt[-1])
+
+    def _collect_routes(self, stats: dict) -> None:
+        """Move the prefill chunks' routes to their requests. Called
+        behind a decode step's fetch: the chunks ran before that step, so
+        each is a plain copy, not a wait."""
+        for rid, routes, n in self._route_backlog:
+            stats[rid].routes.append(np.asarray(routes)[:n])
+        self._route_backlog.clear()
 
     def _admit_span(self, req: Request, slot: int, prompt: np.ndarray,
                     chunks: int, shared_pages: int):
@@ -688,7 +779,8 @@ class ServingEngine:
             chunks += len(range(0, prompt.size - 1, self.cfg.prefill_chunk))
         return span("admit", "serve", rid=req.rid, slot=slot,
                     prompt_len=int(prompt.size), chunks=chunks,
-                    shared_pages=shared_pages)
+                    shared_pages=shared_pages,
+                    state_reset=int(self._stateful))
 
     def _admit_paged(self, slot: int, req: Request,
                      stats: RequestStats) -> tuple[int, int] | None:
@@ -909,10 +1001,15 @@ class ServingEngine:
                 busy_slot_steps += n_active
                 # ``rows``: cache rows that hold a token, of the
                 # slots x max_len the dense step reads.
+                # ``state_slots``: slots whose recurrent state the step
+                # reads and writes back (a stateful model's active slots).
                 with span("dispatch", "serve", step=steps, active=n_active,
                           rows=int(pos[active].sum()),
-                          row_scatter=self._row_scatter):
-                    last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
+                          row_scatter=self._row_scatter,
+                          state_slots=n_active if self._stateful else 0):
+                    counters = routes_np = None
+                    if not self._stateful:
+                        last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
                     if self._spec is not None:
                         if self._paged:
                             emitted, n_emit, _, self.caches, self._dcaches = (
@@ -932,6 +1029,11 @@ class ServingEngine:
                             self.params, self.caches,
                             jnp.asarray(self._table), last_j, pos_j,
                         )
+                    elif self._stateful:
+                        next_t, _, self.caches = self._decode(
+                            self.params, self.caches,
+                            np.stack([last, pos, active]).astype(np.int32),
+                        )
                     else:
                         next_t, _, self.caches = self._decode(
                             self.params, self.caches, last_j, pos_j
@@ -942,7 +1044,15 @@ class ServingEngine:
                         emitted_np = np.asarray(jax.device_get(emitted))
                         n_emit_np = np.asarray(jax.device_get(n_emit))
                     else:
-                        emitted_np = np.asarray(jax.device_get(next_t))[:, None]
+                        next_np = np.asarray(jax.device_get(next_t))
+                        if self._stateful:  # counters, then routes, ride behind the tokens
+                            names = self.model.counter_names
+                            counters = dict(zip(
+                                names, next_np[b:b + len(names)].tolist()))
+                            routes_np = next_np[b + len(names):].reshape(b, -1)
+                            if self._route_backlog:
+                                self._collect_routes(stats)
+                        emitted_np = next_np[:b, None]
                         n_emit_np = np.ones(b, np.int64)
                 steps += 1
                 t_step = now()
@@ -952,6 +1062,8 @@ class ServingEngine:
                         if not active[i]:
                             continue
                         st = stats[slot_rid[i]]
+                        if routes_np is not None and routes_np.shape[1]:
+                            st.routes.append(routes_np[i:i + 1])
                         done = False
                         committed = 0
                         for tok in emitted_np[i, : int(n_emit_np[i])]:
@@ -1000,7 +1112,7 @@ class ServingEngine:
                             self._release_slot(i)
                             n_expired += 1
                     commit.set_metadata(tokens=n_tokens, finished=n_finished,
-                                        expired=n_expired)
+                                        expired=n_expired, **(counters or {}))
         pool_stats = None
         if self._pool is not None:
             pool_stats = {

@@ -46,6 +46,7 @@ from tpudml.checkpoint.store import (
     save_checkpoint,
     _read_manifest,
 )
+from tpudml.capabilities import reject
 from tpudml.serve.engine import RequestStats, ServeConfig, ServingEngine
 from tpudml.serve.load import Request
 from tpudml.serve.paged import PagedKVCache
@@ -70,6 +71,8 @@ def write_handoff(model, params, cfg: ServeConfig, prompt,
     Returns ``{"n_pages", "covered_tokens", "path"}`` — ``n_pages`` may
     be 0 for a sub-page prompt (nothing shareable; adopt is a no-op and
     decode falls back to local prefill)."""
+    if getattr(model, "stateful", False):
+        reject("serve_pattern_handoff")
     _require_paged_sharing(cfg, "write_handoff")
     prompt = np.asarray(prompt, np.int32)
     if prompt.ndim != 1 or prompt.size < 1:

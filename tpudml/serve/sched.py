@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from tpudml.capabilities import reject
 from tpudml.comm.timing import collective_wire_bytes
 
 _CACHE_ITEMSIZE = {"f32": 4, "bf16": 2, "int8": 1, "bf16_sim": 4, "int8_sim": 4}
@@ -81,6 +82,11 @@ class DecodeCostModel:
 
     def __init__(self, model, cfg, slo: SLOConfig, *, world: int = 1,
                  draft_model=None):
+        if getattr(model, "stateful", False):
+            # This model prices a GPT-shaped block (embed_dim // num_heads
+            # heads, a 4x MLP, K/V rows only): not a pattern model's
+            # experts and recurrent state.
+            reject("serve_pattern_slo")
         self.slo = slo
         self.world = world
         kv_heads = model.num_kv_heads or model.num_heads
