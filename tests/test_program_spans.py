@@ -105,6 +105,8 @@ def test_serve_dispatch_counters_match_the_report(traced_serve):
     assert all(0 < s.args["rows"] <= 3 * 64 for s in dispatch)
     # head_dim 8 does not fill a 128-lane tile: one update per slot
     assert all(s.args["row_scatter"] == 0 for s in dispatch)
+    # nor does the Pallas decode-attention kernel read it (and no TPU here)
+    assert all(s.args["decode_kernel"] == 0 for s in dispatch)
 
 
 def test_serve_children_lie_inside_their_pass(traced_serve):

@@ -117,6 +117,80 @@ def test_row_scatter_serves_the_per_slot_update_tokens(mode, monkeypatch):
         assert got.requests[rid].tokens == st.tokens
 
 
+def _mqa_128():
+    return TransformerLM(vocab_size=V, embed_dim=256, num_heads=2,
+                         num_layers=2, max_len=64, rope=True, num_kv_heads=1)
+
+
+def _pattern_128():
+    from tpudml.models import HybridLM
+
+    return HybridLM(vocab_size=V, pattern="M*E*", embed_dim=64, num_heads=4,
+                    num_kv_heads=2, head_dim=128, mamba_heads=4,
+                    mamba_head_dim=16, n_groups=2, state_size=16,
+                    chunk_size=8, num_experts=8, top_k=2, expert_dim=24,
+                    shared_dim=40)
+
+
+@pytest.mark.parametrize("build", [_mqa_128, _pattern_128],
+                         ids=["transformer_mqa", "pattern_gqa"])
+def test_decode_kernel_serves_the_einsum_tokens(build, monkeypatch):
+    """Where query heads share a K/V head at head_dim 128 the decode step
+    reads the cache with the Pallas kernel (serve/cache.py:decode_kernel;
+    interpreted here). Ten requests through three slots — released and
+    re-admitted over stale rows — are served the einsum path's tokens, in
+    its slots and at its steps, and ``serve/dispatch`` says which read
+    the step made."""
+    from tpudml.obs import Tracer, use_tracer
+    from tpudml.ops import decode_attn
+
+    model = build()
+    params, _ = model.init(jax.random.key(1))
+    cfg = ServeConfig(slots=3, max_len=64, prefill_chunk=8)
+    reqs, _ = poisson_workload(10, math.inf, 11, vocab_size=V,
+                               prompt_len=(2, 12), new_tokens=(3, 8))
+
+    def serve():
+        tracer = Tracer()
+        with use_tracer(tracer):
+            rep = ServingEngine(model, params, cfg).run(reqs)
+        flags = {s.args["decode_kernel"] for s in tracer.events
+                 if (s.cat, s.name) == ("serve", "dispatch")}
+        return rep, flags
+
+    want, flags = serve()
+    assert flags == {0}
+    calls = []
+    real = decode_attn.decode_attn
+    monkeypatch.setattr(decode_attn, "kernel_interpret", lambda: True)
+    monkeypatch.setattr(decode_attn, "decode_attn",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    got, flags = serve()
+    assert flags == {1} and len(calls) == 2  # traced once, two layers
+    assert got.events == want.events
+    assert sum(e[0] == "evict" for e in got.events) == 10
+    for rid, st in want.requests.items():
+        assert got.requests[rid].tokens == st.tokens
+
+
+@pytest.mark.parametrize("kw", [dict(cache_layout="paged", page_size=8),
+                                dict(spec_k=2), dict(cache_kind="int8")],
+                         ids=["paged", "spec", "int8"])
+def test_decode_kernel_flag_is_down_where_the_step_einsums(kw, monkeypatch):
+    """The paged and speculative steps and the int8 cache keep their
+    einsums on a TPU too, and ``serve/dispatch`` says so."""
+    from tpudml.ops import decode_attn
+
+    monkeypatch.setattr(decode_attn, "kernel_interpret", lambda: True)
+    model = _mqa_128()
+    params, _ = model.init(jax.random.key(1))
+    plain = ServingEngine(model, params, ServeConfig(
+        slots=3, max_len=64, prefill_chunk=8))
+    other = ServingEngine(model, params, ServeConfig(
+        slots=3, max_len=64, prefill_chunk=8, **kw))
+    assert (plain._decode_kernel, other._decode_kernel) == (1, 0)
+
+
 def test_slots_are_refilled_mid_flight(setup):
     """Continuous batching: with more requests than slots, some admit
     happens at a decode step > 0 (a freed slot re-enters the batch while

@@ -165,19 +165,32 @@ def _copied_bytes(text: str) -> list[int]:
                 r"= (\w+)\[([\d,]*)\]\{[^}]*\} copy\(", text)]
 
 
-@pytest.mark.parametrize("kind", ["bf16", "int8"])
-def test_serve_decode_step_writes_rows_in_place(topo, kind):
+@pytest.fixture
+def on_chip_kernel(monkeypatch):
+    """``jax.default_backend()`` is the CPU during a described compile: put
+    the decode-attention kernel's dispatch where a TPU would."""
+    from tpudml.ops import decode_attn
+
+    monkeypatch.setattr(decode_attn, "kernel_interpret", lambda: False)
+
+
+def _entry_ops(text: str) -> int:
+    """Operations of the entry computation: what the device runs a step,
+    fusions and kernels counted once each."""
+    entry = text[text.index("\nENTRY "):]
+    return sum(" = " in line for line in entry.splitlines())
+
+
+def _serve_code_step(topo, kind, layers):
     """The engine's decode step at the serving cell's cache (64 slots x
-    8192 rows, multi-query 16 x 128, donated; two layers are enough):
-    the step's K/V rows go in by scatters — no ``while`` over the slots
-    (64 passes a tensor before PR 29) and no ``copy`` as large as one
-    layer's K."""
+    8192 rows, multi-query 16 x 128, donated), compiled: (program text,
+    one layer's K)."""
     from tpudml.models import TransformerLM
     from tpudml.serve.engine import make_decode_step
 
     slots, rows = 64, 8192
     model = TransformerLM(vocab_size=1024, embed_dim=2048, num_heads=16,
-                          num_layers=2, max_len=rows, rope=False,
+                          num_layers=layers, max_len=rows, rope=False,
                           num_kv_heads=1, impl="flash", dtype=bf16,
                           compute_dtype=bf16)
     one = SingleDeviceSharding(topo.devices[0])
@@ -192,12 +205,47 @@ def test_serve_decode_step_writes_rows_in_place(topo, kind):
     ints = jax.ShapeDtypeStruct((slots,), i32, sharding=one)
     text = make_decode_step(model).lower(
         described(params), described(caches), ints, ints).compile().as_text()
+    return text, caches[0].k
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_serve_decode_step_writes_rows_in_place(topo, kind, on_chip_kernel):
+    """Two layers are enough: the step's K/V rows go in by scatters — no
+    ``while`` over the slots (64 passes a tensor before PR 29) and no
+    ``copy`` as large as one layer's K. (bf16 reads with the kernel, int8
+    with the einsum: serve/cache.py:decode_kernel.)"""
+    text, k = _serve_code_step(topo, kind, layers=2)
     assert " scatter(" in text and " while(" not in text
-    k = caches[0].k
     assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
+    assert ("decode_attn" in text) == (kind == "bf16")
 
 
-def test_pattern_model_decode_step_copies_no_weights_and_no_state(topo):
+def test_serve_decode_step_reads_the_cache_once_in_place(topo, monkeypatch):
+    """The serving cell's whole decode step, 24 layers: one ``decode_attn``
+    kernel a layer on the cache buffers as stored — nothing as large as a
+    layer's K is copied, converted or broadcast to the 16 query heads on
+    its way in — and the step is no more device operations than the einsum
+    step it replaces (PERF.md §6, PR 31)."""
+    import re
+
+    from tpudml.ops import decode_attn
+
+    einsum, k = _serve_code_step(topo, "bf16", layers=24)
+    assert "decode_attn" not in einsum
+    monkeypatch.setattr(decode_attn, "kernel_interpret", lambda: False)
+    text, _ = _serve_code_step(topo, "bf16", layers=24)
+    calls = re.findall(r" custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert sum("decode_attn" in c for c in calls) == 24
+    assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
+    for dims in ("64,8192,1,128", "64,8192,16,128", "64,16,8192,128",
+                 "64,8192,128"):
+        assert not re.search(
+            rf"= \w+\[{dims}\]\{{[^}}]*\}} (convert|broadcast)\(", text), dims
+    assert _entry_ops(text) <= _entry_ops(einsum)
+
+
+def test_pattern_model_decode_step_copies_no_weights_and_no_state(
+        topo, on_chip_kernel):
     """The pattern model's decode step at the published widths (one layer of
     each kind, 64 of 128 experts held, 128 slots x 4096 rows, donated): the
     held experts' weights go into their two matmuls as they are stored — a
@@ -228,9 +276,13 @@ def test_pattern_model_decode_step_copies_no_weights_and_no_state(topo):
     assert " while(" not in text
     up = params["layer1"]["mixer"]["experts"]["up"]
     assert max(_copied_bytes(text)) < up.size * up.dtype.itemsize / 2
+    # Nor is the K/V cache: the decode-attention kernel reads [128, 4096,
+    # 2, 128] as stored, where the grouped einsum wanted [B, Hkv, L, D] and
+    # copied K and V of each attention layer a step (PERF.md §6, PR 31).
+    k = caches[2].k
+    assert k.shape == (128, 4096, 2, 128) and "decode_attn" in text
+    assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
     # The state is not among the copies, as stored or as the scan sees it.
-    # (The bfloat16 K/V cache IS copied, K and V of each attention layer a
-    # step: the grouped einsum wants [B, Hkv, L, D]. PERF.md §5.)
     import re
 
     assert caches[0].ssm.shape == (128, 64, 64, 128)

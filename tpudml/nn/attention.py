@@ -395,7 +395,7 @@ class MultiHeadAttention(Module):
         ``pos`` [B] its per-slot position. Writes this token's K/V into
         the cache at ``pos``, attends q over the cached prefix, returns
         (out [B, 1, d], updated cache)."""
-        from tpudml.serve.cache import read_all, write_token
+        from tpudml.serve.cache import decode_kernel, read_all, write_token
 
         self._serve_guard()
         b = x.shape[0]
@@ -404,12 +404,19 @@ class MultiHeadAttention(Module):
             q = rotary_embedding(q, pos[:, None], self.rope_base)
             k_new = rotary_embedding(k_new, pos[:, None], self.rope_base)
         cache = write_token(cache, k_new, v_new, pos)
-        k, v = read_all(cache, x.dtype)
-        if 1 < k.shape[2] < q.shape[2]:
-            o = decode_attention_grouped(q, k, v, pos)
-        else:  # MHA as it is; one K/V head broadcasts
-            k, v = self._gqa_repeat(k, v, self.num_heads)
-            o = decode_attention(q, k, v, pos)
+        if decode_kernel(cache.kind, *cache.k.shape[1:3], *q.shape[2:]):
+            # Shared K/V heads on a TPU: the cache is read where it lies.
+            from tpudml.ops.decode_attn import decode_attn, kernel_interpret
+
+            o = decode_attn(q, cache.k, cache.v, pos,
+                            interpret=kernel_interpret())
+        else:
+            k, v = read_all(cache, x.dtype)
+            if 1 < k.shape[2] < q.shape[2]:
+                o = decode_attention_grouped(q, k, v, pos)
+            else:  # MHA as it is; one K/V head broadcasts
+                k, v = self._gqa_repeat(k, v, self.num_heads)
+                o = decode_attention(q, k, v, pos)
         o = o.reshape(b, 1, self._inner)
         return self._dense(params["out"], o), cache
 

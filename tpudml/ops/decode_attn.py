@@ -1,0 +1,177 @@
+"""Decode attention over the serving cache where it lies (Pallas, TPU).
+
+One query row a slot against the whole K/V cache is a matrix-vector
+product for every head, which XLA lowers to multiply + reduce on the
+vector unit behind converts of the cache — and, for the grouped einsum,
+behind a transposed copy of it (PERF.md §5: 110 of a 121 ms decode step
+at 64 slots x 8192 rows, 9 % of the memory roof). Where several query
+heads share a K/V head the product has a matrix in it: the group's
+``H / Hkv`` query heads are the rows of ``q · Kᵀ`` and of ``P · V``. This
+kernel streams K and V through VMEM one row block at a time, straight
+from the cache buffers in the layout the chip gives them, runs both
+products on the MXU and keeps an online softmax (running max, sum and
+float32 accumulator) in scratch: every byte of the cache is read once and
+nothing cache-sized is written.
+
+The chip stores ``[B, L, Hkv, D]`` with the K/V heads of a row beside each
+other (with one head, ``[B, L, D]``), so a row block holds all of them and
+the grid runs over (slot, row block): the block is read as ``[rows · Hkv,
+D]``, every query head is scored against all of it, and the mask keeps,
+for query head ``h``, the columns of K/V head ``h // (H / Hkv)`` at rows
+``<= pos[b]``. The wasted MXU columns (all but one in ``Hkv``) cost
+nothing beside the read. Masked columns are ``NEG_INF`` before the
+float32 softmax, so a stale row has weight exactly zero, as in
+:func:`tpudml.nn.attention.decode_attention`. Operands stay in the wider
+of the cache's and the query's float type; scores, statistics and the
+accumulator are float32; ``P`` is cast to the operand type for the second
+product.
+
+**Every row block of every slot is read, whatever ``pos`` says**: no
+``pos``-bounded grid, no skipped DMA, no early exit. The benchmark's
+``serve.decode_hbm`` counts the whole dense cache a step
+(``benchmarks/counts.py``) and a step that read only live rows would read
+over 100 % of the roof. The row bound is a few lines here (clamp the
+block index at ``pos[b] // block`` so that a repeated index skips the
+DMA, and ``pl.when`` the body) once that count follows live rows:
+ROADMAP.md A1.
+
+Inference only. ``serve/cache.py:decode_kernel`` says which caches take
+this path; everything else keeps its einsum.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tpudml.nn.attention import NEG_INF
+
+# Rows of the [rows · Hkv, D] matrix a grid step reads (a slot's rows times
+# its K/V heads), so that a block's bytes and the [H, rows · Hkv] scores do
+# not grow with Hkv. On the v5e, bf16, a layer of 64 x 8192 x 1 x 128 takes
+# 1.04 / 0.65 / 0.45 / 0.39 / 0.39 ms at 256 / 512 / 1024 / 2048 / 4096 (its
+# bytes 0.33), one of 128 x 4096 x 2 x 128 0.92 / 0.75 / 0.74 / 0.75 at 1024
+# / 2048 / 4096 / 8192 (0.66): below 2048 the ~0.35 us a grid step shows
+# (PERF.md §6, PR 31).
+BLOCK_ROWS = 2048
+
+
+def kernel_interpret() -> bool | None:
+    """The kernel's ``interpret`` on this backend: False on a TPU, None
+    where there is no kernel to run (tests put True here)."""
+    return False if jax.default_backend() == "tpu" else None
+
+
+def block_rows(max_len: int, kv_heads: int) -> int:
+    """The rows of a slot a grid step reads: ``BLOCK_ROWS`` over the K/V
+    heads, or the whole (shorter) cache."""
+    return min(BLOCK_ROWS // kv_heads, max_len)
+
+
+def _rows(ref):
+    """A K or V block ``[1, rows, Hkv, D]`` (``[1, rows, D]`` with one
+    head) as the matrix ``[rows · Hkv, D]``, row ``r · Hkv + h`` from ``(r,
+    h)``, with no relayout: the chip keeps the heads of a row in
+    neighbouring sublanes, two 16-bit ones packed in a 32-bit word, which
+    is how it keeps neighbouring rows of a matrix. So the ref is viewed,
+    not the value reshaped: Mosaic relayouts a reshaped ``[rows, 2, D]``
+    value tile by tile (1.7 ms a layer at 128 x 4096 x 2 x 128, bf16,
+    against 0.74 for this and 1.05 for the einsum; PERF.md §6, PR 31)."""
+    if len(ref.shape) == 3:
+        return ref[0]
+    _, rows, kv_heads, d = ref.shape
+    pack = 4 // ref.dtype.itemsize
+    if pack > 1 and kv_heads % pack == 0:
+        words = ref.bitcast(jnp.uint32).reshape(rows * kv_heads // pack, d)
+        return pltpu.bitcast(words[:], ref.dtype)
+    return ref.reshape(rows * kv_heads, d)[:]
+
+
+def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            scale: float, block: int, kv_heads: int, group: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    ct = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+    q = q_ref[:].astype(ct)  # [H, D]
+    k = _rows(k_ref).astype(ct)
+    v = _rows(v_ref).astype(ct)
+    # Float32 operands in float32 (the MXU's default is one bf16 pass).
+    precision = jax.lax.Precision.HIGHEST if ct == jnp.float32 else None
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32) * scale  # [H, rows · Hkv]
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if kv_heads == 1:
+        keep = j * block + col <= pos_ref[b]
+    else:  # column c is row c // Hkv of K/V head c % Hkv
+        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        keep = (j * block + col // kv_heads <= pos_ref[b]) & (
+            col % kv_heads == head // group)
+    s = jnp.where(keep, s, NEG_INF)
+    m_prev = m_ref[:]  # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(ct), v, (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+    m_ref[:] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[:] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
+                block: int | None = None,
+                interpret: bool = False) -> jax.Array:
+    """:func:`tpudml.nn.attention.decode_attention_grouped` as one kernel:
+    q [B, 1, H, D] over the cache buffers k, v [B, L, Hkv, D] as stored,
+    per-slot positions ``pos`` [B] -> [B, 1, H, D] in q's type. ``L`` is a
+    multiple of ``block`` (default :func:`block_rows`)."""
+    b, _, h, d = q.shape
+    length, kv_heads = k.shape[1:3]
+    block = block or block_rows(length, kv_heads)
+    if length % block or h % kv_heads:
+        raise ValueError(
+            f"decode_attn: {length} rows in blocks of {block}, {h} query "
+            f"heads over {kv_heads}")
+    if kv_heads == 1:
+        # The chip keeps a size-1 head axis out of the tiles: [B, L, D].
+        k, v = k.reshape(b, length, d), v.reshape(b, length, d)
+        kv_spec = pl.BlockSpec((1, block, d), lambda i, j, pos: (i, j, 0))
+    else:
+        kv_spec = pl.BlockSpec((1, block, kv_heads, d),
+                               lambda i, j, pos: (i, j, 0, 0))
+    q_spec = pl.BlockSpec((None, None, h, d), lambda i, j, pos: (i, 0, 0, 0))
+    return pl.pallas_call(
+        partial(_kernel, scale=1.0 / d ** 0.5, block=block,
+                kv_heads=kv_heads, group=h // kv_heads),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, length // block),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),  # running max
+                pltpu.VMEM((h, 1), jnp.float32),  # running sum
+                pltpu.VMEM((h, d), jnp.float32),  # output accumulator
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attn",
+        interpret=interpret,
+    )(pos.astype(jnp.int32), q, k, v)

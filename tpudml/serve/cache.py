@@ -133,6 +133,30 @@ def row_scatter(head_dim: int) -> bool:
     return head_dim % 128 == 0
 
 
+def decode_kernel(kind: str, max_len: int, kv_heads: int, num_heads: int,
+                  head_dim: int) -> bool:
+    """Whether ``MultiHeadAttention.apply_decode`` reads this cache with
+    the Pallas kernel (``ops/decode_attn.py``) and not with an einsum.
+
+    The kernel makes the query heads that share a K/V head the rows of a
+    matmul, so it wants some to share (``kv_heads < num_heads``: with one
+    query head a K/V head there is no matrix); it reads the cache as the
+    chip lays it out where a row fills whole 128-lane tiles (as
+    :func:`row_scatter`; the L-minor layout of ``Dh`` 64 or 96 wants
+    another kernel, not written), stored in a float type it can put on the
+    MXU, in whole row blocks; and it needs a TPU (tests interpret it). At
+    64 slots x 8192 rows, 16 x 128 over 1, bf16, the einsum's step takes
+    120.9 ms and the kernel's 11.6 (PERF.md §6, PR 31). Everything else —
+    MHA, the int8 and ``*_sim`` kinds, a ragged ``max_len`` — keeps the
+    einsum."""
+    from tpudml.ops.decode_attn import block_rows, kernel_interpret
+
+    block = block_rows(max_len, kv_heads)
+    return (kernel_interpret() is not None and row_scatter(head_dim)
+            and kv_heads < num_heads and kind in ("bf16", "f32")
+            and block % 16 == 0 and max_len % block == 0)
+
+
 def _update_rows(buf: jax.Array, rows: jax.Array,
                  pos: jax.Array) -> jax.Array:
     """rows [B, Q, ...] into buf [B, L, ...] at per-slot rows

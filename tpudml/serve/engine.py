@@ -56,7 +56,7 @@ import numpy as np
 from tpudml.capabilities import CompositionError, reject
 from tpudml.obs.tracer import span
 from tpudml.ops.decode_head import fused_decode_head, fused_decode_head_int8
-from tpudml.serve.cache import KINDS, row_scatter
+from tpudml.serve.cache import KINDS, decode_kernel, row_scatter
 from tpudml.serve.load import Request
 from tpudml.serve.paged import PAGED_DECODE_MARKER, PagePool
 from tpudml.serve.sched import DecodeCostModel, SLOConfig
@@ -527,6 +527,14 @@ class ServingEngine:
         # ``row_scatter``): the page pool always scatters, the dense
         # cache where its layout allows (serve/cache.py:row_scatter).
         self._row_scatter = int(self._paged or row_scatter(model.head_dim))
+        # How it reads them (``decode_kernel``): the dense single-token step
+        # with the Pallas kernel where serve/cache.py:decode_kernel says;
+        # the paged, speculative and tensor-parallel steps by einsum.
+        self._decode_kernel = int(
+            not self._paged and not cfg.spec_k and mesh is None
+            and decode_kernel(cfg.cache_kind, cfg.max_len,
+                              model.num_kv_heads or model.num_heads,
+                              model.num_heads, model.head_dim))
         if mesh is not None and (self._paged or cfg.spec_k):
             # The TP decode step shards cache heads through a shard_map
             # body that knows nothing of page tables or verify windows.
@@ -1006,6 +1014,7 @@ class ServingEngine:
                 with span("dispatch", "serve", step=steps, active=n_active,
                           rows=int(pos[active].sum()),
                           row_scatter=self._row_scatter,
+                          decode_kernel=self._decode_kernel,
                           state_slots=n_active if self._stateful else 0):
                     counters = routes_np = None
                     if not self._stateful:
