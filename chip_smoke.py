@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# The r05 "large" row (bench.py bench_lm(large=True)).
+# The 12-layer, d 1024 grouped-query LM of the round-5 chip runs (2026-07-31).
 FLAGSHIP = dict(vocab_size=32768, embed_dim=1024, num_heads=8, num_layers=12,
                 num_kv_heads=2)
 SEQ_LEN, BATCH = 2048, 8
@@ -190,7 +190,8 @@ def _global_norm(tree) -> float:
 def train_lm_phase(cfg: dict = FLAGSHIP, seq_len: int = SEQ_LEN,
                    batch: int = BATCH, steps: int = 8,
                    parity_batch: int = 2) -> dict:
-    """The r05 large model and step, built as ``bench.py`` builds them."""
+    """The flagship model under the fused train step, with a fused-vs-plain
+    parity check of one step."""
     from tpudml.core.prng import seed_key
     from tpudml.optim import make_optimizer
     from tpudml.train import TrainState, make_lm_fused_train_step

@@ -39,6 +39,10 @@ else:
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# tests/test_benchmarks_*.py star-import the benchmark's own test modules;
+# registered here so their asserts are rewritten like a collected file's.
+pytest.register_assert_rewrite("benchmarks.tests")
+
 
 @pytest.fixture(scope="session")
 def rng():

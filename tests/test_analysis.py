@@ -307,12 +307,13 @@ def test_j109_ragged_transpose_backward(ragged_dw):
 
 def test_j110_cacheless_decode_fires_and_cached_is_silent():
     """J110 fires on a decode-marked program that recomputes the full
-    [T, T] attention per emitted token (make_cacheless_decode_step, the
-    serving bench's A/B baseline) and stays silent on the KV-cached step
-    whose softmax is [B, H, 1, L]."""
+    [T, T] attention per emitted token (the fixture
+    analysis_fixtures/cacheless_decode.py) and stays silent on the
+    KV-cached step whose softmax is [B, H, 1, L]."""
+    from analysis_fixtures.cacheless_decode import make_cacheless_decode_step
+
     from tpudml.models import TransformerLM
-    from tpudml.serve import (ServeConfig, ServingEngine,
-                              make_cacheless_decode_step)
+    from tpudml.serve import ServeConfig, ServingEngine
 
     lm = TransformerLM(vocab_size=32, embed_dim=16, num_heads=2,
                        num_layers=2, max_len=16, rope=True)
@@ -712,7 +713,7 @@ def test_stale_allowlist_entries_detected():
         Finding("J111", "no finiteness gate",
                 file="tpudml/optim/optimizers.py", line=40),
         Finding("A201", "python if on traced value",
-                file="tools/bench.py", line=12),
+                file="tools/report.py", line=12),
     ]
     entries = [live, live_line, stale, wrong_line]
     assert unused_entries(findings, entries) == [stale, wrong_line]

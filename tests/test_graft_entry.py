@@ -79,3 +79,13 @@ def test_dryrun_in_process_after_backend_init():
     import __graft_entry__
 
     __graft_entry__.dryrun_multichip(4, regimes=("dpzero1",))
+
+
+def test_dryrun_sharded_fused_xent_regimes_compile():
+    """The vocab-sharded fused-head regimes (task5 --parallel tp/fsdp
+    --fused_xent) compile and run on the virtual CPU mesh — keeps the
+    shard_map loss region + lse-merge collectives tracing without a
+    chip."""
+    import __graft_entry__
+
+    __graft_entry__.dryrun_multichip(4, regimes=("tpfused", "fsdpfused"))

@@ -1,6 +1,6 @@
 """tpudml.plan: the static autosharding planner's contracts.
 
-Four pinned properties:
+Three pinned properties:
 
 - **determinism** — same spec + world → byte-identical ``plan.json``
   (no timestamps, sorted keys, stable candidate ordering);
@@ -10,10 +10,11 @@ Four pinned properties:
   pass reads is the same table every engine guard raises from, checked
   in both directions (every table key is raised by some ``reject()``
   call; every ``reject()`` key exists in the table) plus live
-  constructor spot-checks that the raised message IS the table message;
-- **rank order vs reality** — ``bench.py --plan`` measures the dryrun
-  regimes through the planner's own ``build_candidate``; the planner's
-  top-1 must be within 10% of the measured best (the acceptance pin).
+  constructor spot-checks that the raised message IS the table message.
+
+The planner's rank order against measured step times is a claim for the
+chip (ROADMAP.md A7): a CPU wall-clock comparison is a count, never a
+verdict, and no test here makes one.
 """
 
 import json
@@ -231,32 +232,6 @@ def test_fresh_plan_is_j118_clean_and_stale_plan_fires(plan4):
     fired = [f for f in plan_drift_findings(stale) if f.rule == "J118"]
     assert fired
     assert "re-plan" in fired[0].message
-
-
-# ------------------------------------------------- rank order vs measured
-
-
-def test_planner_top1_within_tolerance_of_measured_best():
-    """The acceptance pin: on the world-4 CPU dryrun mesh, the planner's
-    top-1 candidate (among the measured DP/ZeRO-1/ZeRO-1-overlap/FSDP
-    regimes) costs at most 1.10x the measured-best candidate's step
-    time. Rank order of the middle of the field is NOT pinned — CPU
-    dryrun middle ranks are noise — the claim is the planner does not
-    pick a loser."""
-    sys.path.insert(0, REPO)
-    try:
-        from bench import bench_plan
-    finally:
-        sys.path.remove(REPO)
-
-    report = bench_plan(world=4)
-    assert report["within_tolerance"], report
-    assert report["top1_vs_best_ratio"] <= report["tolerance"]
-    rows = report["rows"]
-    assert set(rows) == {"dp_replicated", "dp_zero1", "dp_zero1_overlap",
-                        "fsdp"}
-    for row in rows.values():
-        assert row["sec_per_step"] > 0
 
 
 # ------------------------------------------------------------ CLI contract

@@ -18,12 +18,7 @@ any subset is fine; missing files just skip their section:
 - ``obs/mpmd.json``  — the MPMD re-mesh drill's verdict (bit-exactness
   vs the uninterrupted reference, re-mesh vs whole-world-restart MTTR)
   plus per-edge transfer-byte aggregates from the merged per-stage
-  trace (one pid track per stage group);
-- ``bench.json``     — a ``bench.py`` report dropped into the run dir:
-  the residuals section surfaces the round-20 per-residual breakdown
-  (``head_ms`` / ``junction_ms`` / ``exposed_comm_ms`` next to ``mfu``)
-  for every row that carries it, cross-checked against the measured
-  ``cat="comm"`` span total from the same run's ``trace.json``.
+  trace (one pid track per stage group).
 
 Usage::
 
@@ -282,80 +277,6 @@ def fleet_summary(run_dir: Path) -> str | None:
     return "\n\n".join(out)
 
 
-def _bench_rows(doc: dict, label: str, rows: list) -> None:
-    """Collect every bench row in ``doc`` (the top-level report plus the
-    nested ``secondary`` / ``secondary_large`` / ``parsed`` sub-rows) that
-    carries the round-20 per-residual fields."""
-    if not isinstance(doc, dict):
-        return
-    if "head_ms" in doc or "junction_ms" in doc or "exposed_comm_ms" in doc:
-        sec = doc.get("sec_per_step")
-        step_ms = sec * 1e3 if isinstance(sec, (int, float)) else None
-        resid = sum(
-            doc.get(k) or 0.0
-            for k in ("head_ms", "junction_ms", "exposed_comm_ms")
-        )
-        rows.append([
-            doc.get("metric", label),
-            f"{step_ms:.3f}" if step_ms is not None else "-",
-            f"{doc.get('head_ms', 0.0):.4f}",
-            f"{doc.get('junction_ms', 0.0):.4f}",
-            f"{doc.get('exposed_comm_ms', 0.0):.4f}",
-            f"{resid / step_ms:.1%}" if step_ms else "-",
-        ])
-    for key in ("secondary", "secondary_large", "parsed"):
-        sub = doc.get(key)
-        if isinstance(sub, dict):
-            _bench_rows(sub, key, rows)
-
-
-def residuals_summary(run_dir: Path) -> str | None:
-    """Round-20 residuals section: the per-residual breakdown bench rows
-    emit next to ``mfu`` (``head_ms`` — decode-head tail, ``junction_ms``
-    — attention/residual/LN block junctions, ``exposed_comm_ms`` — wire
-    time left on the critical path after overlap), read from a
-    ``bench.json`` dropped in the run dir, plus the measured ``cat="comm"``
-    span total from the same run's trace as the dynamic cross-check of
-    the exposed-comm column."""
-    out = []
-    for name in ("bench.json", "obs/bench.json"):
-        path = run_dir / name
-        if not path.is_file():
-            continue
-        try:
-            doc = json.loads(path.read_text())
-        except ValueError:
-            continue
-        rows: list[list] = []
-        _bench_rows(doc, "bench", rows)
-        if rows:
-            out.append(_table(
-                ["bench row", "step_ms", "head_ms", "junction_ms",
-                 "exposed_comm_ms", "residual_share"],
-                rows,
-            ))
-        break
-    # Dynamic cross-check: what the tracer actually measured on the wire.
-    tpath = run_dir / "trace.json"
-    if tpath.is_file():
-        try:
-            tdoc = json.loads(tpath.read_text())
-        except ValueError:
-            tdoc = {}
-        comm = [
-            e for e in tdoc.get("traceEvents", [])
-            if e.get("ph") == "X" and e.get("cat") == "comm"
-        ]
-        if comm:
-            total_us = sum(float(e.get("dur", 0.0)) for e in comm)
-            out.append(
-                f"measured comm spans: {len(comm)} span(s), "
-                f"{total_us / 1e3:.3f} ms total wall on the wire "
-                f"(compare against exposed_comm_ms: overlap hides the rest)"
-            )
-    return "\n\n".join(out) if out else None
-
-
 def protocol_verdict(run_dir: Path) -> str | None:
     """One-line verdict of the MPMDController's pre-launch protocol
     gate (``protocol_report.json``, written per checked round), so the
@@ -479,7 +400,6 @@ def report(run_dir: str | Path) -> str:
         ("elastic.json (reform/re-plan)", elastic_summary(run_dir)),
         ("fleet.json (serving fleet)", fleet_summary(run_dir)),
         ("mpmd.json (MPMD re-mesh)", mpmd_summary(run_dir)),
-        ("residuals (bench.json + comm spans)", residuals_summary(run_dir)),
     ]
     out = [f"== obs report: {run_dir} =="]
     found = False

@@ -325,9 +325,9 @@ def build_moe_ragged() -> list[Program]:
 def build_serve_decode() -> list[Program]:
     """The serving engine's jitted per-token decode step — the surface
     J110 guards. The cache-carrying step must trace J110-silent (its
-    softmax is [B, H, 1, L]); ``make_cacheless_decode_step`` is the
-    rule's firing fixture (covered in tests/test_analysis.py, not
-    registered as an entrypoint)."""
+    softmax is [B, H, 1, L]); the rule's firing fixture is
+    ``tests/analysis_fixtures/cacheless_decode.py`` (covered in
+    tests/test_analysis.py, not registered as an entrypoint)."""
     import jax
     from tpudml.serve import ServeConfig, ServingEngine
 

@@ -18,8 +18,7 @@ checkpoint (``tpudml/checkpoint/sharded.py``).
 ``drill.py`` is the proof: a scripted failure drill (SIGKILL-grade rank
 death mid-training → backoff → re-form → resume) whose final parameters
 must be bit-identical to an uninterrupted run. Run it as a library
-(:func:`run_drill`), via ``python -m tpudml.elastic --drill``, or as the
-MTTR benchmark row (``python bench.py --drill``).
+(:func:`run_drill`) or via ``python -m tpudml.elastic --drill``.
 """
 
 from tpudml.elastic.controller import (

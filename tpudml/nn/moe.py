@@ -14,10 +14,10 @@ scope), built here to complete the parallelism matrix. TPU-first design:
   [G, E, C] one-hot buffers, no O(G·E·C·d) einsum FLOPs). The GShard
   one-hot einsum formulation survives as ``dispatch="einsum"``, the parity
   oracle: both paths consume the identical slot assignment. Measured on a
-  v5e (tools/moe_perf.py): the einsum dispatch cost ~1.9-2.5× dense at
-  matched active FLOPs; gather removes that overhead (recording in
-  BASELINE.md round 5). Tokens past capacity are dropped (combine weight
-  0), the standard Switch trade;
+  v5e (round 5, 2026-07-31, older than this code): the einsum dispatch
+  cost ~1.9-2.5× dense at matched active FLOPs; gather removes that
+  overhead. Tokens past capacity are dropped (combine weight 0), the
+  standard Switch trade;
 - under expert parallelism (``axis_name`` set, run inside shard_map),
   tokens AND experts are sharded over the same mesh axis: each shard
   routes its local tokens, one ``all_to_all`` ships the [E, C, d] dispatch

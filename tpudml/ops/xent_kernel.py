@@ -495,8 +495,9 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # O(N) lean mode beyond. 2 GiB covers the flagship (8k×32k = 1 GiB) and
 # the chip-filling config (16k×32k = 2 GiB) with room for the model;
 # 131k-token long-context regimes (16 GiB of scores) auto-drop to lean —
-# exactly the regime the O(N) contract exists for. The speed win is
-# measured at kernel granularity by tools/xent_micro.py.
+# exactly the regime the O(N) contract exists for. The speed win was
+# measured at kernel granularity in round 5 (2026-07-31, older than this
+# code).
 SAVE_S_AUTO_MAX_BYTES = 2 * 1024**3
 
 
@@ -580,7 +581,8 @@ def linear_cross_entropy(
     out beforehand. ``save_s=True`` is the SPEED mode: it keeps the
     [N_pad, V_pad] f32 scores as a backward residual (2 fewer backward
     matmuls — 8.21 → 5.97 ms at [8192,32k] at kernel granularity,
-    tools/xent_micro.py; 21.54 → 19.29 ms/step in-situ); the
+    21.54 → 19.29 ms/step in-situ: round 5, 2026-07-31, older than this
+    code); the
     default ``save_s=None`` resolves it AUTOMATICALLY: speed mode while
     the score residual fits ``SAVE_S_AUTO_MAX_BYTES``, the O(N) lean
     mode beyond (the long-context regimes the memory contract exists

@@ -33,9 +33,9 @@ measured constants folded in as a :class:`~tpudml.plan.score.Calibration`
 — both land in the plan's v2 ``replan`` / ``calibration`` blocks.
 
 CLI: ``python -m tpudml.plan`` (``--format text|json|github``,
-``--check`` for the world-4/8 smoke).  Validation the other way:
-``python bench.py --plan`` measures the dryrun regimes and pins the
-planner's top-1 within tolerance of the measured best.
+``--check`` for the world-4/8 smoke).  Validation the other way — the
+planner's top-1 against the measured best — waits for a four-chip cell
+of the benchmark (ROADMAP.md A7): not measured.
 """
 
 from tpudml.plan.emit import (

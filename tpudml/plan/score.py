@@ -26,8 +26,8 @@ hide.  Ranking metric is per-token time so candidates with different
 meshes stay comparable.
 
 Nominal TPU-v4-ish constants; absolute seconds are not the contract —
-*rank order* is, and it is pinned against ``bench.py --plan`` dryrun
-measurements.
+*rank order* is, and no chip measurement has checked it yet (the
+four-chip cell of ROADMAP.md A7 is where it will be).
 """
 
 from __future__ import annotations

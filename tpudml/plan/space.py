@@ -60,9 +60,9 @@ class ModelSpec:
 
 
 def flagship_lm() -> ModelSpec:
-    """The CPU-dryrun flagship spec — the same shape ``bench.py``'s
-    dryrun rows train, so planner ranks and measured step times talk
-    about the identical workload."""
+    """The CPU-dryrun flagship spec — small enough that ``--check`` and
+    the tier-1 tests build and verify every surviving candidate on the
+    virtual CPU mesh."""
     return ModelSpec(
         vocab_size=256,
         embed_dim=64,

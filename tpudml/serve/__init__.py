@@ -22,7 +22,6 @@ from tpudml.serve.engine import (
     ServeConfig,
     ServeReport,
     ServingEngine,
-    make_cacheless_decode_step,
     make_decode_step,
     make_paged_decode_step,
 )
@@ -82,7 +81,6 @@ __all__ = [
     "draft_from_trunk",
     "init_cache",
     "init_pool",
-    "make_cacheless_decode_step",
     "make_decode_step",
     "make_paged_decode_step",
     "make_spec_decode_step",

@@ -159,24 +159,6 @@ def make_fused_decode_step(model, head_q=None, head_scale=None):
     return jax.jit(step, donate_argnums=(1,))
 
 
-def make_cacheless_decode_step(model):
-    """The decode strategy the KV cache exists to kill: re-run the full
-    forward over the whole history and keep the last logits row. Kept as
-    the A/B baseline for ``bench.py --serve`` (the ≥5× acceptance
-    criterion) and as the living firing fixture for analysis rule J110 —
-    it carries the decode marker, and the [T, T] softmax inside it is
-    precisely what the rule reports. One compile per history length, too
-    (tokens [B, T] is shape-polymorphic in T) — recompile churn the
-    slot engine never pays."""
-
-    def _serve_decode_step(params, tokens):
-        logits, _ = model.apply(params, {}, tokens)
-        return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-
-    inner = jax.jit(_serve_decode_step)
-    return jax.jit(lambda params, tokens: inner(params, tokens))
-
-
 def make_paged_decode_step(model):
     """The paged twin of :func:`make_decode_step`: (params, pools,
     table [B, max_pages], tokens [B], pos [B]) → (next tokens [B],

@@ -271,8 +271,7 @@ def make_train_step_body(
 ) -> Callable:
     """Un-jitted (ts, images, labels) -> (new_ts, metrics) step body —
     the traceable core of :func:`make_train_step`, composable under
-    ``lax.fori_loop``/``lax.scan`` (bench.py times K of these inside one
-    dispatch)."""
+    ``lax.fori_loop``/``lax.scan`` (K steps inside one dispatch)."""
     loss_fn = make_loss_fn(model, loss, resolve_aux_loss_weight(model, aux_loss_weight))
 
     def step(ts: TrainState, images, labels):
@@ -437,8 +436,8 @@ def make_lm_fused_train_step_body(
 ) -> Callable:
     """Un-jitted (ts, tokens, labels) -> (new_ts, metrics) body of
     :func:`make_lm_fused_train_step` — composable under ``lax.fori_loop``
-    (bench.py times K of these inside one dispatch, like
-    :func:`make_train_step_body` for the standard step)."""
+    (K steps inside one dispatch, like :func:`make_train_step_body` for
+    the standard step)."""
     loss_fn = make_lm_fused_loss_fn(model, save_scores)
 
     def step(ts: TrainState, tokens, labels):
