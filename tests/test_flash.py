@@ -2,9 +2,13 @@
 
 Load-bearing property: the kernels are the same function as the reference
 ``dot_product_attention`` — forward (all block sizes, causal on/off,
-bfloat16) and gradients via BOTH backward paths: the blocked dQ/dK/dV
-kernels (default) and the custom_vjp reference-recompute fallback.
+bfloat16) and gradients via every backward path: the one-pass kernel (a
+head resident in VMEM), the dQ and dK/dV kernels that stream tiles (the
+rule between them is ``_backward_plan``), and the custom_vjp
+reference-recompute fallback.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +17,7 @@ import pytest
 
 from tpudml.models import TransformerLM
 from tpudml.nn.attention import dot_product_attention
-from tpudml.ops import flash_attention
+from tpudml.ops import attention_kernel, flash_attention, flash_block_grads
 
 B, T, H, D = 2, 32, 4, 8
 
@@ -93,33 +97,154 @@ def test_odd_lengths_pad_and_mask(qkv, t, block_q, block_k, causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize(
-    "t,block_q,block_k,causal",
-    [(32, 8, 8, False), (32, 8, 8, True), (30, 16, 8, True), (27, 8, 4, False)],
-)
-def test_blocked_backward_matches_reference(qkv, t, block_q, block_k, causal):
-    """The flash backward kernels (dQ, dK/dV with tile streaming) must
-    reproduce reference gradients across multi-tile grids, odd lengths,
-    and causal skipping."""
-    q, k, v = (a[:, :t] for a in qkv)
+def _flash_and_reference_grads(q, k, v, causal, **blocks):
     w = jnp.asarray(
         np.random.default_rng(7).normal(size=q.shape).astype(np.float32)
     )
     got = jax.grad(
         lambda q, k, v: jnp.sum(
-            flash_attention(
-                q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                interpret=True,
-            ) * w
+            flash_attention(q, k, v, causal=causal, interpret=True, **blocks)
+            .astype(jnp.float32) * w
         ),
         argnums=(0, 1, 2),
     )(q, k, v)
     want = jax.grad(
-        lambda q, k, v: jnp.sum(dot_product_attention(q, k, v, causal=causal) * w),
+        lambda q, k, v: jnp.sum(dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), causal=causal) * w),
         argnums=(0, 1, 2),
     )(q, k, v)
+    return got, want
+
+
+def _takes_one_pass(t, d, dtype, block_q, block_k) -> bool:
+    calls = attention_kernel._backward_plan(t, d, dtype, block_q, block_k)[0]
+    assert calls in (attention_kernel._backward_one_pass,
+                     attention_kernel._backward_calls)
+    return calls is attention_kernel._backward_one_pass
+
+
+# (t, block_q, block_k, causal, one_pass): multi-tile grids, odd lengths (K
+# padding masked at global positions), tiles off the square, causal
+# skipping; 4 x 7 tiles are more pairs than the one-pass kernel unrolls.
+BACKWARD_TILINGS = [
+    (32, 8, 8, False, True), (32, 8, 8, True, True), (30, 16, 8, True, True),
+    (27, 8, 4, False, False), (32, 16, 8, True, True), (32, 8, 16, True, True),
+    (30, 8, 16, False, True), (27, 16, 16, True, True),
+]
+
+
+@pytest.mark.parametrize("streamed", [False, True], ids=["by_rule", "two_kernels"])
+@pytest.mark.parametrize("t,block_q,block_k,causal,one_pass", BACKWARD_TILINGS)
+def test_blocked_backward_matches_reference(qkv, monkeypatch, t, block_q, block_k,
+                                            causal, one_pass, streamed):
+    """The flash backward must reproduce reference gradients across
+    multi-tile loops, odd lengths, and causal skipping: in the form the rule
+    gives the shape (the one-pass kernel for all but one), and held to the
+    dQ and dK/dV kernels, which long sequences keep."""
+    if streamed:
+        monkeypatch.setattr(attention_kernel, "_one_pass_fits", lambda *a: False)
+    q, k, v = (a[:, :t] for a in qkv)
+    assert _takes_one_pass(t, D, q.dtype, block_q, block_k) == (
+        one_pass and not streamed)
+    got, want = _flash_and_reference_grads(
+        q, k, v, causal, block_q=block_q, block_k=block_k)
     for g, r in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=5e-4, atol=1e-5)
+
+
+def test_one_pass_backward_takes_lse_at_its_own_padding(qkv):
+    """A (forward, backward) pair of Q tiles that pad T differently: the
+    forward's lse has 24 rows, the backward's resident block 32."""
+    q, k, v = (a[:, :24] for a in qkv)
+    assert _takes_one_pass(24, D, q.dtype, 16, 8)
+    got, want = _flash_and_reference_grads(q, k, v, True, block_q=(8, 16), block_k=8)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=5e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-4), (jnp.bfloat16, 0.03)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t,causal", [(256, True), (200, True), (200, False)])
+def test_one_pass_backward_at_head_widths(t, causal, d, dtype, tol):
+    """Head 64 and 128 at the default tiles, T a multiple of the tile and
+    not (``t_valid`` masking), bf16 and float32: relative error of each
+    gradient against the float32 reference."""
+    rng = np.random.default_rng(d + t)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, t, 2, d)), dtype) for _ in range(3))
+    assert _takes_one_pass(t, d, dtype, None, None)
+    got, want = _flash_and_reference_grads(q, k, v, causal)
+    for g, r in zip(got, want):
+        assert g.dtype == dtype
+        err = np.linalg.norm(np.asarray(g, np.float32) - np.asarray(r))
+        assert err <= tol * np.linalg.norm(np.asarray(r))
+
+
+@pytest.mark.parametrize(
+    "t,d,dtype,block_q,block_k,one_pass",
+    [
+        (1024, 64, jnp.bfloat16, None, None, True),    # gpt2-medium.pretrain-1k
+        (512, 64, jnp.bfloat16, None, None, True),
+        (2048, 64, jnp.bfloat16, None, None, True),
+        (2048, 128, jnp.bfloat16, None, None, True),   # the chip smoke's row
+        (1024, 128, jnp.float32, None, None, True),
+        (2048, 128, jnp.float32, None, None, False),   # float32 doubles the head
+        (4096, 128, jnp.bfloat16, None, None, False),
+        (8192, 128, jnp.bfloat16, None, None, False),  # starcoderbase-1b
+        (1024, 64, jnp.bfloat16, 128, 128, False),     # 64 tile pairs to unroll
+        (32, 8, jnp.float32, 8, 8, True),
+    ],
+)
+def test_backward_form_follows_the_shape(t, d, dtype, block_q, block_k, one_pass):
+    """The rule itself: which form a (T, head dim, dtype, tiles) takes, and
+    that a tile left open takes that form's default."""
+    _, bq, bk, t_pad_q, t_pad_k = attention_kernel._backward_plan(
+        t, d, dtype, block_q, block_k)
+    assert _takes_one_pass(t, d, dtype, block_q, block_k) == one_pass
+    assert t_pad_q % bq == 0 and t_pad_k % bk == 0 and t_pad_q >= t <= t_pad_k
+    if block_q is None:
+        two_kernel_bq = attention_kernel._default_blocks(d)[0][1]
+        assert bq == (attention_kernel._ONE_PASS_TILE if one_pass else two_kernel_bq)
+    else:
+        assert (bq, bk) == (block_q, block_k)
+
+
+def test_block_grads_keep_the_two_kernels(qkv):
+    """Ring context parallelism's per-block entry point (external lse and Δ,
+    ``k_shift``) stays on the dQ and dK/dV kernels at a shape whose full
+    backward is one pass, and the blocks still sum to the full gradient."""
+    q, k, v = qkv
+    w = jnp.asarray(np.random.default_rng(7).normal(size=q.shape).astype(np.float32))
+    o = dot_product_attention(q, k, v, causal=False)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    delta = jnp.sum(w * o, axis=-1).transpose(0, 2, 1)
+    half = T // 2
+    halves = (slice(0, half), slice(half, T))
+
+    def block(i, j):
+        """Q block i against K/V block j, as the ring runs them."""
+        return flash_block_grads(
+            q[:, halves[i]], k[:, halves[j]], v[:, halves[j]], w[:, halves[i]],
+            lse[:, :, halves[i]], delta[:, :, halves[i]],
+            block_q=8, block_k=8, interpret=True)
+
+    assert _takes_one_pass(half, D, q.dtype, 8, 8)
+    assert set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(
+        lambda: block(0, 1))()))) == {"flash_bwd_dq", "flash_bwd_dkv"}
+    g = [[block(i, j) for j in (0, 1)] for i in (0, 1)]
+    got = (
+        jnp.concatenate([g[i][0][0] + g[i][1][0] for i in (0, 1)], 1),
+        jnp.concatenate([g[0][j][1] + g[1][j][1] for j in (0, 1)], 1),
+        jnp.concatenate([g[0][j][2] + g[1][j][2] for j in (0, 1)], 1),
+    )
+    want = jax.grad(
+        lambda q, k, v: jnp.sum(dot_product_attention(q, k, v, causal=False) * w),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=5e-4, atol=1e-5)
 
 
 @pytest.mark.skipif(jax.default_backend() != "cpu", reason="CPU dispatch path")

@@ -273,6 +273,15 @@ def _flash(q, k, v):
     return flash_attention(q, k, v, causal=True, interpret=True)
 
 
+def _flash_streamed(q, k, v):
+    """More tile pairs than the one-pass backward unrolls: the shape takes
+    the dQ and dK/dV kernels, as a head too long for VMEM does."""
+    from tpudml.ops.attention_kernel import flash_attention
+
+    return flash_attention(q, k, v, causal=True, interpret=True, block_q=2,
+                           block_k=2)
+
+
 def _ln(x, g, b):
     from tpudml.ops.layernorm_kernel import fused_layernorm
 
@@ -300,7 +309,8 @@ _HEAD = [jnp.ones((16, 32), jnp.float32), jnp.ones((32, 256), jnp.float32),
 
 KERNELS = [
     ("flash-forward", _flash, _QKV, None, ["flash_fwd"]),
-    ("flash-gradient", _flash, _QKV, (0, 1, 2),
+    ("flash-gradient", _flash, _QKV, (0, 1, 2), ["flash_fwd", "flash_bwd"]),
+    ("flash-gradient-streamed", _flash_streamed, _QKV, (0, 1, 2),
      ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     ("ln-forward", _ln, _ROWS, None, ["ln_fwd"]),
     ("ln-gradient", _ln, _ROWS, (0, 1, 2), ["ln_fwd", "ln_bwd"]),

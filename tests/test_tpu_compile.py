@@ -14,6 +14,8 @@ the worker that is handed this file may load it. All of these tests stay in
 this one file for the same reason.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,6 +88,22 @@ def test_flash_attention_fwd_bwd_bf16(chip):
     qkv = ((B, T, H, DH), bf16)
     chip(_grad_sum(lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=False), (0, 1, 2)), qkv, qkv, qkv)
+
+
+# The backward's two forms, each at a shape that takes it: the training
+# cell's (gpt2-medium.pretrain-1k, a head resident in VMEM: one kernel) and
+# starcoderbase-1b's context (too long to sit there: the dQ and dK/dV kernels).
+@pytest.mark.parametrize("shape,kernels", [
+    ((8, 1024, 16, 64), {"flash_fwd", "flash_bwd"}),
+    ((1, 8192, 16, 128), {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+], ids=["gpt2-medium-one_pass", "long_head128-two_kernels"])
+def test_flash_backward_form_on_the_chip(chip, shape, kernels):
+    from tpudml.ops.attention_kernel import flash_attention
+
+    qkv = (shape, bf16)
+    text = chip(_grad_sum(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False), (0, 1, 2)), qkv, qkv, qkv)
+    assert set(re.findall(r"flash_(?:fwd|bwd_dq|bwd_dkv|bwd)\b", text)) == kernels
 
 
 def test_fused_add_layernorm_fwd_bwd(chip):

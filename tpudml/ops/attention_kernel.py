@@ -8,21 +8,21 @@ f32 output accumulator, rescaled by exp(m_prev − m_new) per tile), and the
 row log-sum-exp is emitted as a residual. Per-step VMEM is
 O(block_q·D + block_k·D), independent of T.
 
-Backward: the flash recipe — no O(T²) transient. With the forward's
-output O and lse, and Δ = rowsum(dO ⊙ O):
-
-- dQ kernel (K innermost): recompute the tile's scores, p = exp(s − lse),
-  dp = dO·Vᵀ, ds = p ⊙ (dp − Δ); accumulate dQ += scale · ds·K in scratch.
-- dK/dV kernel (Q innermost): same recompute per tile; dV += pᵀ·dO,
-  dK += scale · dsᵀ·Q.
-
-Causal runs skip tiles entirely off the diagonal in all three kernels
-(~2× fewer FLOPs). Q and K pad independently to their own block
-multiples; masking uses global positions so any T works. Grid reads are
-hoisted out of skip branches (program_id can't lower inside a cond in
-interpret mode). ``blocked_backward=False`` falls back to
-recompute-through-the-reference-math under vjp (debugging aid).
-
+Backward: the flash recipe — no O(T²) transient. With the forward's lse
+and Δ = rowsum(dO ⊙ O), each visible score tile is recomputed: p =
+exp(s − lse), dp = dO·Vᵀ, ds = p ⊙ (dp − Δ); dV += pᵀ·dO, dK += scale ·
+dsᵀ·Q, dQ += scale · ds·K, float32 accumulators. One algorithm at two
+tilings, chosen from T, head dim and dtype alone (``_backward_plan``):
+- one pass (``flash_bwd``): a head's Q, K, V, dO, lse, Δ are one VMEM
+  block, the tile loop runs inside the kernel and every tile is recomputed
+  once for all three products (GPT-2's T 1024 × D 64: 4.1× the two below);
+- two kernels (``flash_bwd_dq``, K innermost; ``flash_bwd_dkv``, Q
+  innermost) stream tiles and recompute each twice: heads too long to sit
+  in VMEM, and ring attention's per-block ``flash_block_grads``.
+Causal runs skip the tiles above the diagonal everywhere (~2× fewer
+FLOPs); Q and K pad independently to their tile multiples and masks use
+global positions, so any T works. ``blocked_backward=False`` recomputes
+through the reference math under vjp (debugging aid).
 Validated against the reference math on a real v5e chip; on non-TPU
 platforms ``flash_attention`` dispatches to the reference math unless
 ``interpret=True`` forces the Pallas interpreter (tests).
@@ -263,17 +263,17 @@ def _dkdv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_backward(q, k, v, o, lse, g, causal, block_q, block_k, interpret):
     b, t, h, d = q.shape
-    block_q, block_k, t_pad_q, t_pad_k = _plan(t, block_q, block_k)
+    calls, block_q, block_k, t_pad_q, t_pad_k = _backward_plan(
+        t, d, q.dtype, block_q, block_k)
     qf, dof, of = _fold_pad((q, g, o), b, h, t, d, t_pad_q)
     kf, vf = _fold_pad((k, v), b, h, t, d, t_pad_k)
     # Δ = rowsum(dO ⊙ O): cheap elementwise, computed once outside.
     delta = jnp.sum(
         dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1, keepdims=True
     )  # [B·H, t_pad_q, 1]
-    dqf, dkf, dvf = _backward_calls(
-        qf, kf, vf, dof, lse, delta, b, h, t, d, causal, block_q, block_k,
-        t_pad_q, t_pad_k, interpret,
-    )
+    # One pass over a head resident in VMEM, or the dQ and dK/dV kernels.
+    dqf, dkf, dvf = calls(qf, kf, vf, dof, lse, delta, b, h, t, d, causal,
+                          block_q, block_k, t_pad_q, t_pad_k, interpret)
     return tuple(_unfold(x, b, h, t, d) for x in (dqf, dkf, dvf))
 
 
@@ -422,18 +422,112 @@ def flash_block_grads(
     return tuple(_unfold(x, b, h, t, d) for x in (dqf, dkf, dvf))
 
 
+# ------------------------------------------ backward, a head resident in VMEM
+#
+# Below the entry points the serving programs call, so that their source
+# lines, which a compiled program's fingerprint holds, stay where they were.
+
+
+def _one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, qt_ref, dot_ref, *,
+                     scale, causal, block_q, block_k, t_valid):
+    """One (batch, head) resident in VMEM: the loops over the visible
+    (K tile, Q tile) pairs run here, unrolled (their bounds are static, so
+    the pairs above the causal diagonal do not exist), and each score tile
+    is recomputed once and feeds dV, dK and dQ.
+
+    dV and dK accumulate transposed, [D, block_k] += dOᵀ·P and Qᵀ·dS, so P
+    and dS enter all three products as the [block_q, block_k] tiles they
+    are: Q and dO turn once a head and dK, dV once a K tile, where Pᵀ and
+    dSᵀ would turn once a pair."""
+    nq = q_ref.shape[1] // block_q
+    nk = k_ref.shape[1] // block_k
+    d = q_ref.shape[2]
+    nn = (((1,), (0,)), ((), ()))
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    qt_ref[:] = q_ref[0].T
+    dot_ref[:] = do_ref[0].T
+    for kj in range(nk):
+        ks = slice(kj * block_k, (kj + 1) * block_k)
+        k = k_ref[0, ks, :]
+        v = v_ref[0, ks, :]
+        dkt = dvt = jnp.zeros((d, block_k), jnp.float32)
+        for qi in range(kj * block_k // block_q if causal else 0, nq):
+            qs = slice(qi * block_q, (qi + 1) * block_q)
+            s = _scores(
+                q_ref[0, qs, :], k, qi, kj, scale=scale,
+                # a tile wholly below the diagonal needs no causal mask
+                causal=causal and qi * block_q < (kj + 1) * block_k - 1,
+                block_q=block_q, block_k=block_k, t_valid=t_valid, nk=nk,
+            )
+            p = jnp.exp(s - lse_ref[0, qs, :])
+            dp = jax.lax.dot_general(
+                do_ref[0, qs, :], v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = (p * (dp - delta_ref[0, qs, :])).astype(k.dtype)
+            dvt += jax.lax.dot_general(
+                dot_ref[:, qs], p.astype(k.dtype), nn,
+                preferred_element_type=jnp.float32)
+            dkt += jax.lax.dot_general(
+                qt_ref[:, qs], ds, nn, preferred_element_type=jnp.float32)
+            dq_acc[qs, :] += jax.lax.dot_general(
+                ds, k, nn, preferred_element_type=jnp.float32)
+        dk_ref[0, ks, :] = (dkt.T * scale).astype(dk_ref.dtype)
+        dv_ref[0, ks, :] = dvt.T.astype(dv_ref.dtype)
+    dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+def _backward_one_pass(qf, kf, vf, dof, lse, delta, b, h, t, d, causal,
+                       block_q, block_k, t_pad_q, t_pad_k, interpret):
+    """The backward as one pallas_call, a (batch, head) a program."""
+    if lse.shape[1] != t_pad_q:
+        # The forward padded T to its own Q tile; the resident block is
+        # the backward's.
+        lse = _fold_rows(lse[:, :t, 0].reshape(b, h, t), t_pad_q)
+    head_q = pl.BlockSpec((1, t_pad_q, d), lambda i: (i, 0, 0))
+    head_k = pl.BlockSpec((1, t_pad_k, d), lambda i: (i, 0, 0))
+    rows = pl.BlockSpec((1, t_pad_q, 1), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        partial(
+            _one_pass_kernel, scale=1.0 / (d ** 0.5), causal=causal,
+            block_q=block_q, block_k=block_k, t_valid=t,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
+        ],
+        grid=(b * h,),
+        in_specs=[head_q, head_k, head_k, head_q, rows, rows],
+        out_specs=[head_q, head_k, head_k],
+        scratch_shapes=[
+            pltpu.VMEM((t_pad_q, d), jnp.float32),  # dQ accumulator
+            pltpu.VMEM((d, t_pad_q), qf.dtype),  # Qᵀ
+            pltpu.VMEM((d, t_pad_q), qf.dtype),  # dOᵀ
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ONE_PASS_VMEM_LIMIT),
+        name="flash_bwd",
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, delta)
+
+
 # ------------------------------------------------------------- dispatch
 
 
-# Measured-best default tiles by head dim (v5e, T=1024 sweeps):
+# Measured-best default tiles by head dim (v5e, T=1024 sweeps) for the
+# kernels that stream tiles — the forward and the two-kernel backward:
 # - forward wants the largest Q tile that fits VMEM (fewer grid
 #   programs, bigger MXU ops: 0.43 vs 0.71 ms/layer at dh=64 for
 #   (512,512) vs (128,512));
-# - the backward's dQ/dKdV kernels carry more scratch/live values per
-#   program and prefer smaller Q tiles;
+# - the dQ and dK/dV kernels carry more scratch/live values per program
+#   and prefer smaller Q tiles (8,192 tokens a layer: 3.31 ms at dh=64,
+#   T=1024; 4.61 ms at dh=128, T=8192);
 # - at dh>=128 (full-lane tiles) larger K blocks win in BOTH directions
 #   (fwd 0.067 ms at bk=1024 vs 0.131 at 512; bwd (256,1024) 0.56 ms vs
 #   (128,512) 0.90 ms per layer).
+# The one-pass backward has its own tile (``_ONE_PASS_TILE``).
 def _default_blocks(d: int) -> tuple[tuple[int, int], int]:
     """((fwd_block_q, bwd_block_q), block_k) by head dim."""
     if d >= 128:
@@ -441,14 +535,61 @@ def _default_blocks(d: int) -> tuple[tuple[int, int], int]:
     return (512, 128), 512
 
 
+# The one-pass backward (a head resident in VMEM) against the dQ and dK/dV
+# kernels that stream tiles: one algorithm at two tilings, chosen from the
+# shapes alone. The head must fit — what the pipeline double-buffers (Q, K, V,
+# dO, lse, Δ in; dQ, dK, dV out) plus the kernel's scratch, minor dims padded
+# to 128 lanes as VMEM holds them: 6.3 MB at T 1024 x D 64 bf16, 13 MB at
+# T 2048 x D 128 — and the unrolled tile loop must stay short (T 4096 at 512:
+# 36 visible pairs, 30 s to compile). Measured on both sides (v5e, 8,192
+# tokens a layer, causal, bf16, ms: two kernels / one pass): D 64 at T 512
+# 1.83 / 0.67, T 1024 3.31 / 0.81, T 2048 5.58 / 1.16; D 128 at T 1024
+# 0.91 / 0.39, T 2048 1.51 / 0.61. Tiles of 256 read within 10 % of 512 at
+# four times the pairs; a rolled `fori_loop` over the pairs costs 1.03 for
+# 0.84, Pᵀ and dSᵀ turned per pair 0.97, and nothing of the masks or the
+# `exp` shows in the time: the five matmuls bound it.
+_ONE_PASS_TILE = 512
+_ONE_PASS_MAX_PAIRS = 16
+_ONE_PASS_VMEM_BUDGET = 16 * 2 ** 20
+# The budget plus the score tiles' room: under the default 16 MiB the chip's
+# compiler refuses the non-causal T 2048 x D 128 head (16 pairs).
+_ONE_PASS_VMEM_LIMIT = 32 * 2 ** 20
+
+
+def _one_pass_fits(block_q: int, block_k: int, t_pad_q: int, t_pad_k: int,
+                   d: int, dtype) -> bool:
+    itemsize = jnp.dtype(dtype).itemsize
+    lanes = _round_up(d, 128)
+    stats = 2 * 128 * 4  # lse, Δ: [T, 1] float32 columns
+    pipelined = 2 * (t_pad_q * (3 * lanes * itemsize + stats)
+                     + t_pad_k * 4 * lanes * itemsize)
+    scratch = t_pad_q * (lanes * 4 + 2 * d * itemsize)  # dQ f32; Qᵀ, dOᵀ
+    pairs = (t_pad_q // block_q) * (t_pad_k // block_k)
+    return (pairs <= _ONE_PASS_MAX_PAIRS
+            and pipelined + scratch <= _ONE_PASS_VMEM_BUDGET)
+
+
+def _backward_plan(t: int, d: int, dtype, block_q: int | None,
+                   block_k: int | None):
+    """(calls, block_q, block_k, t_pad_q, t_pad_k) of the backward: which
+    form runs (``_backward_one_pass`` or ``_backward_calls``) at which
+    tiles; a tile the caller left open takes that form's measured best."""
+    plan = _plan(t, block_q or _ONE_PASS_TILE, block_k or _ONE_PASS_TILE)
+    if _one_pass_fits(*plan, d, dtype):
+        return (_backward_one_pass, *plan)
+    (_, default_bq), default_bk = _default_blocks(d)
+    return (_backward_calls,
+            *_plan(t, block_q or default_bq, block_k or default_bk))
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, block_q, block_k, interpret, blocked_backward):
-    out, _ = _flash_forward(q, k, v, causal, block_q[0], block_k, interpret)
+    out, _ = _flash_forward(q, k, v, causal, block_q[0], block_k[0], interpret)
     return out
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, blocked_backward):
-    out, lse = _flash_forward(q, k, v, causal, block_q[0], block_k, interpret)
+    out, lse = _flash_forward(q, k, v, causal, block_q[0], block_k[0], interpret)
     res = (q, k, v, out, lse) if blocked_backward else (q, k, v)
     return out, res
 
@@ -457,7 +598,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, blocked_backward, res, g):
     if blocked_backward:
         q, k, v, o, lse = res
         return _flash_backward(
-            q, k, v, o, lse, g, causal, block_q[1], block_k, interpret
+            q, k, v, o, lse, g, causal, block_q[1], block_k[1], interpret
         )
     q, k, v = res
     # Fallback: exact gradients by recomputing the reference math under
@@ -489,9 +630,10 @@ def flash_attention(
 
     ``block_q``: one int for both directions, or a (forward, backward)
     pair; ``block_q``/``block_k`` default (None) to the measured-best
-    tiles for the head dim (``_default_blocks``: the forward prefers
-    large Q tiles, the backward small; dh>=128 takes bigger K blocks).
-    ``_plan`` still caps every block at the padded T.
+    tiles: the forward's by head dim (``_default_blocks``), the
+    backward's by the form its shape takes (``_backward_plan``: one pass
+    over a head resident in VMEM, or the dQ and dK/dV kernels). ``_plan``
+    still caps every block at the padded T.
 
     Under a GSPMD engine (an active ``parallel.sharding.KernelLayout``)
     the call runs per shard of batch and heads: the SPMD partitioner
@@ -512,13 +654,14 @@ def _flash_attention_local(q, k, v, causal, block_q, block_k, interpret,
         if jax.default_backend() != "tpu":
             return dot_product_attention(q, k, v, causal=causal)
         interpret = False
-    default_bq, default_bk = _default_blocks(q.shape[-1])
+    # (forward, backward) tiles; a backward tile left None is chosen with
+    # the backward's form (``_backward_plan``).
+    (fwd_bq, _), fwd_bk = _default_blocks(q.shape[-1])
     if block_q is None:
-        bq = default_bq
+        bq = (fwd_bq, None)
     elif isinstance(block_q, int):
         bq = (block_q, block_q)
     else:
         bq = tuple(block_q)
-    if block_k is None:
-        block_k = default_bk
-    return _flash(q, k, v, causal, bq, block_k, interpret, blocked_backward)
+    bk = (fwd_bk, None) if block_k is None else (block_k, block_k)
+    return _flash(q, k, v, causal, bq, bk, interpret, blocked_backward)
