@@ -308,6 +308,65 @@ def test_pattern_model_decode_step_copies_no_weights_and_no_state(
         assert not re.search(rf"= f32\[{dims}\]\{{[^}}]*\}} copy\(", text)
 
 
+def _window_full_model():
+    """One full, one window and one expert layer at MiMo-V2.5's published
+    widths (16 of 256 experts held), 128 slots x 8192 rows."""
+    from tpudml.models import HybridLM
+
+    return HybridLM(
+        vocab_size=1024, pattern="FWE", embed_dim=4096, num_heads=64, head_dim=192,
+        v_head_dim=128, rotary_dim=64, value_scale=0.707, full_kv_heads=4,
+        window=128, window_kv_heads=8, num_experts=256, top_k=8, expert_dim=2048,
+        shared_dim=0, gated_experts=True, held=(0, 16), dtype=bf16)
+
+
+def _described(tree, one):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+
+
+def test_window_and_full_layers_decode_step_takes_both_fast_paths(topo, on_chip_kernel):
+    """A 192-wide key stored in 256 lanes beside a 128-wide value: the step
+    writes by scatters, reads each cache with the kernel under its own name,
+    and copies neither a cache nor a weight — a 192-wide head made the chip's
+    compiler transpose the 100 MB q kernel every step until `_project`'s
+    barrier (PERF.md §6, PR 37)."""
+    from tpudml.serve.engine import make_stateful_decode_step
+
+    model, slots, rows = _window_full_model(), 128, 8192
+    assert model.cache_forms(rows, "bf16") == (True, True)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(lambda: model.init_decode_cache(slots, rows, "bf16"))
+    assert caches[0].k.shape == (128, 8192, 4, 256) and caches[1].v.shape == (128, 128, 8, 128)
+    state = jax.ShapeDtypeStruct((3, slots), i32, sharding=one)
+    text = make_stateful_decode_step(model).lower(
+        _described(params, one), _described(caches, one), state).compile().as_text()
+    assert " scatter(" in text and " while(" not in text
+    calls = re.findall(r" custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert sum("decode_attn_window" in c for c in calls) == 1 and len(calls) == 2
+    q_kernel = params["layer0"]["mixer"]["q"]["kernel"]
+    assert max(_copied_bytes(text)) < q_kernel.size * q_kernel.dtype.itemsize / 4
+
+
+def test_window_and_full_layers_prefill_chunk_leaves_the_cache_in_place(topo):
+    """The last chunk of a 4096-token prompt: the full layer's scores contract
+    over the key's stored 256 lanes, so nothing as large as the ring, let alone
+    the 2 GB cache, is re-laid (slicing 192 of the 256 lanes made the compiler
+    transpose the whole cache there and back; PERF.md §6, PR 37)."""
+    model, slots, rows = _window_full_model(), 128, 8192
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(lambda: model.init_decode_cache(slots, rows, "bf16"))
+    chunk = jax.ShapeDtypeStruct((1, 512), i32, sharding=one)
+    scalar = jax.ShapeDtypeStruct((), i32, sharding=one)
+    text = jax.jit(
+        lambda p, c, ch, slot, n: model.apply_prefill(p, c, ch, slot, 3584, n),
+        donate_argnums=(1,)).lower(_described(params, one), _described(caches, one),
+                                   chunk, scalar, scalar).compile().as_text()
+    ring = caches[1].k
+    assert max(_copied_bytes(text)) < ring.size * ring.dtype.itemsize
+
+
 # ------------------------------------------------- across the four chips
 # What exists only on a mesh: the SPMD partitioner refuses a bare Mosaic
 # kernel, so under the GSPMD engines the kernels run per shard; and the

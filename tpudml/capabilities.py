@@ -374,6 +374,17 @@ _ENTRIES = (
         and bool(_g(c, "serve_handoff")),
     ),
     Capability(
+        key="serve_pattern_ring_int8",
+        owner="tpudml.models.hybrid",
+        message=(
+            "a pattern model with window layers (`W`) does not store its "
+            "cache int8: a ring's prefill write picks rows by position and "
+            "carries no per-row scales yet"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern_window"))
+        and str(_g(c, "serve_cache_kind", "f32")).startswith("int8"),
+    ),
+    Capability(
         key="mpmd_moe_aux_loss",
         owner="tpudml.mpmd.spec",
         message=(

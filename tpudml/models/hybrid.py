@@ -1,18 +1,31 @@
 """A decoder-only language model whose layers are listed by a pattern.
 
 ``pattern`` is one character a layer: ``M`` a Mamba-2 mixer
-(`tpudml.nn.mamba.Mamba2`), ``E`` a sigmoid-routed mixture of experts with
-a shared expert (`tpudml.nn.moe.SigmoidMoE`), ``*`` causal grouped-query
-attention with an explicit head size, no bias and no positional encoding.
-Every layer is ``h <- h + mixer(RMSNorm(h))``; after the last,
+(`tpudml.nn.mamba.Mamba2`), ``E`` a sigmoid-routed mixture of experts
+(`tpudml.nn.moe.SigmoidMoE`: relu^2 or, ``gated_experts``, SwiGLU experts;
+a shared expert where ``shared_dim`` is not 0), ``D`` a dense gated
+feed-forward (`tpudml.nn.layers.GatedMLP`), and three kinds of causal
+grouped-query attention with an explicit head size and no bias
+(`tpudml.nn.attention.MultiHeadAttention`): ``*`` without positional
+encoding, ``F`` full and ``W`` windowed, both with RoPE on the head's first
+``rotary_dim``, a value head ``v_head_dim`` wide scaled by ``value_scale``,
+and each with its own K/V head count, RoPE base and sink flag (a ``W``
+query sees the last ``window`` positions, its own among them, and by
+default a learned sink a head joins its softmax's denominator).
+Every layer is ``h <- h + mixer(RMSNorm(h))``, so a published
+attention-then-feed-forward layer is two pattern entries; after the last,
 ``logits = RMSNorm_f(h) @ W_head`` (untied, no bias). There is no position
-table: the state-space layers carry order.
+table: the state-space layers or RoPE carry order.
 
 The model serves through the unmodified ``ServingEngine`` entry point with
-the dense cache layout. Its per-layer cache tuple holds two kinds of
-per-slot state (`tpudml.serve.cache`): a ``KVCache`` for ``*``, a
-``RecurrentState`` for ``M``, and ``None`` for ``E``. Because a recurrent
-state has no mask to hide stale or padded tokens behind, the model is
+the dense cache layout. Its per-layer cache tuple holds each layer's own
+per-slot state (`tpudml.serve.cache`): for ``*`` and ``F`` a ``KVCache`` of
+``max_len`` rows, for ``W`` a ring of ``window`` rows, with the layer's K/V
+head count and K and V at their stored widths (``stored_width``: a 192-wide
+key in 256 lanes); a ``RecurrentState`` for ``M``; ``None`` for ``E`` and
+``D``. ``cache_forms``, ``cache_bytes`` and ``live_rows`` tell the engine
+what its ``serve/dispatch`` span says of them. Because a recurrent
+state or a ring has no mask to hide stale or padded tokens behind, the model is
 ``stateful`` and the engine then (1) zeroes a slot's state when a request
 takes the slot (``reset_slot``), (2) tells prefill how many tokens of a
 padded chunk are real, and (3) tells decode which slots are active — the
@@ -35,13 +48,16 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from tpudml.capabilities import reject
 from tpudml.nn.attention import MultiHeadAttention
-from tpudml.nn.layers import Module, RMSNorm
+from tpudml.nn.layers import GatedMLP, Module, RMSNorm
 from tpudml.nn.mamba import Mamba2
 from tpudml.nn.moe import SigmoidMoE
 
-KINDS = "ME*"
+KINDS = "ME*FWD"
+ATTENTION = "*FW"
 
 
 @dataclass(frozen=True)
@@ -53,7 +69,20 @@ class HybridLM(Module):
     num_heads: int = 4
     num_kv_heads: int = 2
     head_dim: int = 16
-    impl: str = "full"  # "flash": the Pallas kernel in `apply` and prefill on TPU
+    impl: str = "full"  # "flash": the Pallas kernel in `apply` and prefill on TPU (`*`)
+    # attention with positions (`F` full, `W` window); heads and head_dim as `*`
+    v_head_dim: int | None = None  # None: head_dim
+    rotary_dim: int | None = None  # None: the whole head
+    value_scale: float = 1.0
+    full_kv_heads: int = 2
+    full_rope_base: float = 1e7
+    full_sink: bool = False
+    window: int = 128
+    window_kv_heads: int = 2
+    window_rope_base: float = 1e4
+    window_sink: bool = True
+    # dense gated feed-forward (`D`)
+    dense_dim: int = 128
     # Mamba-2 (`M`)
     mamba_heads: int = 8
     mamba_head_dim: int = 16
@@ -65,7 +94,8 @@ class HybridLM(Module):
     num_experts: int = 8
     top_k: int = 2
     expert_dim: int = 32
-    shared_dim: int = 64
+    shared_dim: int = 64  # 0: no shared expert
+    gated_experts: bool = False  # SwiGLU experts (three matrices), not relu^2 (two)
     routed_scale: float = 1.0
     norm_topk: bool = True
     held: tuple[int, int] | None = None
@@ -105,11 +135,23 @@ class HybridLM(Module):
         if kind == "E":
             return SigmoidMoE(self.embed_dim, self.num_experts, self.top_k,
                               self.expert_dim, self.shared_dim, self.routed_scale,
-                              self.norm_topk, self.held, self.dtype)
+                              self.norm_topk, self.held, self.dtype,
+                              self.gated_experts)
+        if kind == "D":
+            return GatedMLP(self.embed_dim, self.dense_dim, self.dtype)
+        attention = dict(causal=True, impl=self.impl, head_dim=self.head_dim,
+                         use_bias=False, dtype=self.dtype)
+        if kind == "*":
+            return MultiHeadAttention(self.embed_dim, self.num_heads,
+                                      num_kv_heads=self.num_kv_heads, **attention)
+        kv_heads, base, sink, window = {
+            "F": (self.full_kv_heads, self.full_rope_base, self.full_sink, None),
+            "W": (self.window_kv_heads, self.window_rope_base, self.window_sink,
+                  self.window)}[kind]
         return MultiHeadAttention(
-            self.embed_dim, self.num_heads, causal=True, impl=self.impl,
-            num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-            use_bias=False, dtype=self.dtype)
+            self.embed_dim, self.num_heads, num_kv_heads=kv_heads, rope=True,
+            rope_base=base, v_head_dim=self.v_head_dim, rotary_dim=self.rotary_dim,
+            value_scale=self.value_scale, window=window, sink=sink, **attention)
 
     def init(self, key):
         keys = jax.random.split(key, self.num_layers + 2)
@@ -152,15 +194,62 @@ class HybridLM(Module):
         convolution window the stream's dtype."""
         from tpudml.serve.cache import init_cache, init_recurrent_state
 
-        m = self._mixer("M")
-        made = {
-            "M": lambda: init_recurrent_state(
-                batch, self.conv_kernel - 1, m.conv_dim, self.mamba_heads,
-                self.mamba_head_dim, self.state_size, self.dtype, self.state_dtype),
-            "E": lambda: None,
-            "*": lambda: init_cache(batch, max_len, self.num_kv_heads, self.head_dim, kind),
-        }
-        return tuple(made[k]() for k in self.pattern)
+        if kind.startswith("int8") and "W" in self.pattern:
+            reject("serve_pattern_ring_int8")
+
+        def make(layer: str):
+            if layer in ATTENTION:
+                rows, kv_heads, k_dim, v_dim = self._cache_shape(layer, max_len)
+                return init_cache(batch, rows, kv_heads, k_dim, kind, v_dim)
+            if layer == "M":
+                m = self._mixer("M")
+                return init_recurrent_state(
+                    batch, self.conv_kernel - 1, m.conv_dim, self.mamba_heads,
+                    self.mamba_head_dim, self.state_size, self.dtype, self.state_dtype)
+            return None
+
+        return tuple(make(k) for k in self.pattern)
+
+    def _cache_shape(self, layer: str, max_len: int) -> tuple[int, int, int, int]:
+        """(rows, K/V heads, K width, V width as stored) of one attention
+        layer's cache: a ``W`` layer keeps a ring of its window."""
+        from tpudml.serve.cache import stored_width
+
+        if layer == "*":
+            return max_len, self.num_kv_heads, self.head_dim, self.head_dim
+        widths = stored_width(self.head_dim), stored_width(self.v_head_dim or self.head_dim)
+        if layer == "F":
+            return max_len, self.full_kv_heads, *widths
+        return min(self.window, max_len), self.window_kv_heads, *widths
+
+    def cache_forms(self, max_len: int, kind: str) -> tuple[bool, bool]:
+        """(row_scatter, decode_kernel): whether EVERY attention layer's
+        decode step writes its rows by one scatter, and reads them with the
+        kernel (`tpudml.serve.cache`); what ``serve/dispatch`` reports."""
+        from tpudml.serve.cache import decode_kernel, row_scatter
+
+        shapes = [self._cache_shape(k, max_len) for k in self.pattern if k in ATTENTION]
+        return (all(row_scatter(k) and row_scatter(v) for _, _, k, v in shapes),
+                all(decode_kernel(kind, rows, kv_heads, self.num_heads, k, v)
+                    for rows, kv_heads, k, v in shapes))
+
+    def cache_bytes(self, caches) -> dict:
+        """Allocated bytes of the ``max_len``-row K/V caches and of the rings."""
+        from tpudml.serve.cache import cache_bytes
+
+        by = {"cache_bytes_full": 0, "cache_bytes_window": 0}
+        for layer, c in zip(self.pattern, caches):
+            if layer in ATTENTION:
+                by["cache_bytes_window" if layer == "W" else "cache_bytes_full"] += cache_bytes(c)
+        return by
+
+    def live_rows(self, pos, max_len: int) -> dict:
+        """Cache rows that hold a token this step, over the active slots at
+        positions ``pos`` (numpy) and over the layers: ``rows_full`` of the
+        ``max_len``-row caches, ``rows_window`` of the rings."""
+        ring = min(self.window, max_len)
+        return {"rows_full": int((pos + 1).sum()) * sum(k in "*F" for k in self.pattern),
+                "rows_window": int(np.minimum(pos + 1, ring).sum()) * self.pattern.count("W")}
 
     def reset_slot(self, caches, slot):
         from tpudml.serve.cache import reset_slot_state
@@ -181,8 +270,10 @@ class HybridLM(Module):
         def mix(i, kind, mixer, p, u):
             if kind == "M":
                 out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, n_real)
-            elif kind == "*":
-                out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, start)
+            elif kind in ATTENTION:
+                out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, start, n_real)
+            elif kind == "D":
+                out = mixer.apply(p, {}, u)[0]
             else:
                 out, counts = mixer.forward(p, u, real)
                 routes.append(counts["choices"])
@@ -206,8 +297,10 @@ class HybridLM(Module):
         def mix(i, kind, mixer, p, u):
             if kind == "M":
                 out, new[i] = mixer.apply_decode(p, caches[i], u, active)
-            elif kind == "*":
+            elif kind in ATTENTION:
                 out, new[i] = mixer.apply_decode(p, caches[i], u, pos)
+            elif kind == "D":
+                out = mixer.apply(p, {}, u)[0]
             else:
                 out, counts = mixer.forward(p, u, active[:, None])
                 seen.append(counts)

@@ -7,6 +7,7 @@ from tpudml.nn.layers import (
     Dropout,
     Flatten,
     GatedGroupRMSNorm,
+    GatedMLP,
     LayerNorm,
     MaxPool,
     Module,
@@ -30,6 +31,7 @@ __all__ = [
     "LayerNorm",
     "RMSNorm",
     "GatedGroupRMSNorm",
+    "GatedMLP",
     "Sequential",
     "MultiHeadAttention",
     "dot_product_attention",

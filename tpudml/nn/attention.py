@@ -141,6 +141,49 @@ def decode_attention_grouped(
     return jnp.einsum("bkgl,blkd->bkgd", p, v).reshape(b, 1, h, d)
 
 
+def attention_by_position(
+    q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
+    k_pos: jax.Array, *, window: int | None = None,
+    sink: jax.Array | None = None, scale: float | None = None,
+) -> jax.Array:
+    """Grouped-query attention whose mask is written in GLOBAL positions:
+    q [B, Tq, H, Dk] at ``q_pos`` ([Tq] or [B, Tq]) over k [B, Tk, Hkv, Dk]
+    and v [B, Tk, Hkv, Dv] at ``k_pos`` ([Tk] or [B, Tk]) -> [B, Tq, H, Dv].
+    Query head h reads K/V head h // (H / Hkv), with no repeat of K or V.
+
+    A key is seen where ``0 <= k_pos <= q_pos`` and, with ``window`` W,
+    ``k_pos > q_pos - W``: the window counts the query's own position, so a
+    query sees itself and the W - 1 keys before it. A negative ``k_pos``
+    marks a row that holds nothing (a ring's unwritten rows). ``sink`` [H]
+    float32 is a learned logit a head that joins the softmax's denominator
+    and gives no value: ``P_ij = exp(S_ij) / (exp(s_h) + sum_j' exp(S_ij'))``.
+
+    One text for every path that is not a kernel: the whole sequence
+    (``apply``), a prefill chunk over a ring's rows and its own, and the
+    decode step's einsum read. K and V may differ in width; ``scale``
+    defaults to ``Dk ** -0.5`` (a caller whose K is stored in more lanes
+    than the head has, the rest zero, gives the head's)."""
+    b, tq, h, dk = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, tq, hkv, h // hkv, dk)
+    s = jnp.einsum(
+        "bqkgd,blkd->bkgql", qg, k, preferred_element_type=jnp.float32
+    ) * jnp.asarray(scale or dk ** -0.5, jnp.float32)
+    qp = jnp.broadcast_to(q_pos, (b, tq))[:, :, None]
+    kp = jnp.broadcast_to(k_pos, (b, k.shape[1]))[:, None, :]
+    mask = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        mask &= kp > qp - window
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    if sink is not None:
+        s = jnp.concatenate([s, jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, hkv, h // hkv, 1, 1),
+            (*s.shape[:-1], 1))], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :k.shape[1]].astype(v.dtype)
+    o = jnp.einsum("bkgql,blkd->bqkgd", p, v)
+    return o.reshape(b, tq, h, v.shape[-1])
+
+
 def decode_attention_window(
     q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array
 ) -> jax.Array:
@@ -237,6 +280,15 @@ class MultiHeadAttention(Module):
     # not equal embed_dim.
     head_dim: int | None = None
     use_bias: bool = True
+    # What a layer of a window / full mixture brings (models/hybrid.py).
+    # Any of ``v_head_dim``, ``window`` and ``sink`` makes the layer
+    # ``_positional``: its attention is `attention_by_position` (or, in the
+    # decode step, the kernel), never the flash or the ring form.
+    v_head_dim: int | None = None  # a value head's width; None: head_dim
+    rotary_dim: int | None = None  # RoPE turns the head's first rotary_dim; None: all
+    value_scale: float = 1.0  # v = value_scale * (x @ Wv)
+    window: int | None = None  # query i sees keys i - window + 1 .. i
+    sink: bool = False  # a learned logit a head in the softmax's denominator
 
     def __post_init__(self):
         if self.head_dim is None and self.embed_dim % self.num_heads:
@@ -258,12 +310,21 @@ class MultiHeadAttention(Module):
             raise ValueError(
                 f"seq_layout='striped' requires impl='ring', got {self.impl!r}"
             )
-        if self.rope and self._head_dim % 2:
-            # RoPE rotates feature PAIRS; an odd head_dim would silently
+        if self.rope and self._rotary_dim % 2:
+            # RoPE rotates feature PAIRS; an odd width would silently
             # broadcast to the wrong width instead of erroring later.
             raise ValueError(
-                f"rope requires an even head_dim, got {self._head_dim}"
+                f"rope requires an even rotary width, got {self._rotary_dim}"
             )
+        if not 0 < self._rotary_dim <= self._head_dim:
+            raise ValueError(
+                f"rotary_dim {self.rotary_dim} outside (0, {self._head_dim}]")
+        if self.window is not None and (self.window < 1 or not self.causal):
+            raise ValueError("a window needs window >= 1 and causal=True")
+        if self._positional and self.impl not in ("full", "flash"):
+            raise ValueError(
+                f"impl {self.impl!r} has no window, sink or value-head width "
+                "of its own; such a layer takes 'full' or 'flash'")
 
     @property
     def _kv_heads(self) -> int:
@@ -274,9 +335,41 @@ class MultiHeadAttention(Module):
         return self.head_dim or self.embed_dim // self.num_heads
 
     @property
+    def _v_dim(self) -> int:
+        return self.v_head_dim or self._head_dim
+
+    @property
+    def _rotary_dim(self) -> int:
+        return self.rotary_dim or self._head_dim
+
+    @property
+    def _positional(self) -> bool:
+        return (self.window is not None or self.sink
+                or self._v_dim != self._head_dim)
+
+    @property
     def _inner(self) -> int:
-        """Width of the concatenated heads (embed_dim unless head_dim is set)."""
+        """Width of the concatenated query heads (embed_dim unless head_dim
+        is set)."""
         return self.num_heads * self._head_dim
+
+    @property
+    def _inner_v(self) -> int:
+        """Width of the concatenated value heads: what the out projection
+        takes."""
+        return self.num_heads * self._v_dim
+
+    def _sink(self, params):
+        return params["sink"] if self.sink else None
+
+    def _rope(self, x, positions):
+        """RoPE on the head's first ``rotary_dim``; the rest passes through."""
+        r = self._rotary_dim
+        if r == x.shape[-1]:
+            return rotary_embedding(x, positions, self.rope_base)
+        return jnp.concatenate(
+            [rotary_embedding(x[..., :r], positions, self.rope_base),
+             x[..., r:]], axis=-1)
 
     @staticmethod
     def _dense(p, x):
@@ -299,33 +392,41 @@ class MultiHeadAttention(Module):
         proj = Dense(self.embed_dim, self._inner, bias, dtype=self.dtype)
         kv_proj = Dense(self.embed_dim, self._kv_heads * self._head_dim, bias,
                         dtype=self.dtype)
-        out = Dense(self._inner, self.embed_dim, bias, dtype=self.dtype)
-        return {
+        v_proj = Dense(self.embed_dim, self._kv_heads * self._v_dim, bias,
+                       dtype=self.dtype)
+        out = Dense(self._inner_v, self.embed_dim, bias, dtype=self.dtype)
+        params = {
             "q": proj.init(kq)[0],
             "k": kv_proj.init(kk)[0],
-            "v": kv_proj.init(kv)[0],
+            "v": v_proj.init(kv)[0],
             "out": out.init(ko)[0],
-        }, {}
+        }
+        if self.sink:
+            params["sink"] = jnp.zeros((self.num_heads,), jnp.float32)
+        return params, {}
 
     def _heads(self, x, n_heads):
         b, t, _ = x.shape
-        return x.reshape(b, t, n_heads, self._head_dim)
+        return x.reshape(b, t, n_heads, x.shape[-1] // n_heads)
 
     def apply(self, params, state, x, *, train=False, rng=None):
         b, t, _ = x.shape
-        q = self._heads(self._dense(params["q"], x), self.num_heads)
-        k, v = (
-            self._heads(self._dense(params[n], x), self._kv_heads)
-            for n in ("k", "v")
-        )
+        q, k, v = self._project(params, x)
         if self.rope:
             # Before the GQA repeat: rotating the kv_heads-wide tensor does
             # group× less work and repeating rotated heads is identical.
             positions = sharded_positions(
                 self.axis_name, t, self.seq_sharded, self.seq_layout
             )
-            q = rotary_embedding(q, positions, self.rope_base)
-            k = rotary_embedding(k, positions, self.rope_base)
+            q = self._rope(q, positions)
+            k = self._rope(k, positions)
+        if self._positional:
+            # No flash form takes a window, a sink or a narrower value head:
+            # the whole sequence by the plain math, whatever ``impl`` says.
+            at = jnp.arange(t)
+            o = attention_by_position(q, k, v, at, at, window=self.window,
+                                      sink=self._sink(params))
+            return self._dense(params["out"], o.reshape(b, t, -1)), state
         if self._kv_heads != self.num_heads:
             # Broadcast each KV group across its query heads; the attention
             # ops then see ordinary per-head tensors (GQA's savings are in
@@ -371,17 +472,32 @@ class MultiHeadAttention(Module):
         if self.seq_sharded:
             raise ValueError("serve decode requires seq_sharded=False")
 
+    def _dense_cache_only(self, what: str):
+        if self._positional:
+            raise ValueError(
+                f"a layer with a window, a sink or its own value-head width "
+                f"serves through the dense cache only, not {what}")
+
     def _project(self, params, x, n_local_heads=None, n_local_kv=None):
         """(q, k, v) head tensors for x [B, T, d]. Local head counts are
         overridable so the TP decode step can run the same code on a
         head-sharded parameter shard."""
-        q = self._heads(
-            self._dense(params["q"], x), n_local_heads or self.num_heads
-        )
-        k, v = (
-            self._heads(self._dense(params[n], x), n_local_kv or self._kv_heads)
-            for n in ("k", "v")
-        )
+        def heads(name, n):
+            y = self._dense(params[name], x)
+            if y.shape[-1] // n > 128 and (y.shape[-1] // n) % 128:
+                # A head of 192 does not split along whole 128-lane tiles,
+                # and the chip's compiler then prefers the projection's
+                # output with the tokens in the lanes: it transposes the
+                # WEIGHT to get it, 100 MB of q kernel copied a layer a
+                # step at 4096 x 64 x 192. Behind the barrier the 3 MB of
+                # activations are re-laid instead (PERF.md §6, PR 37).
+                y = jax.lax.optimization_barrier(y)
+            return self._heads(y, n)
+
+        q = heads("q", n_local_heads or self.num_heads)
+        k, v = (heads(n, n_local_kv or self._kv_heads) for n in ("k", "v"))
+        if self.value_scale != 1.0:
+            v = v * jnp.asarray(self.value_scale, v.dtype)
         return q, k, v
 
     def _gqa_repeat(self, k, v, n_heads):
@@ -394,30 +510,53 @@ class MultiHeadAttention(Module):
         """One decode step: x [B, 1, d] (the current token's features),
         ``pos`` [B] its per-slot position. Writes this token's K/V into
         the cache at ``pos``, attends q over the cached prefix, returns
-        (out [B, 1, d], updated cache)."""
-        from tpudml.serve.cache import decode_kernel, read_all, write_token
+        (out [B, 1, d], updated cache).
+
+        A window layer treats its cache of L rows as a ring: position p
+        lies in row ``p % L`` (`tpudml.serve.cache.ring_positions`). With L
+        the window itself, the rows ``<= pos`` are exactly the keys the
+        query sees and the kernel's mask is the full layer's; a longer
+        cache (``max_len`` rows: nothing wraps) is read by the einsum under
+        the window mask, row for row the same values."""
+        from tpudml.serve.cache import (decode_kernel, fit_width, read_all,
+                                        ring_positions, write_token)
 
         self._serve_guard()
         b = x.shape[0]
         q, k_new, v_new = self._project(params, x)
         if self.rope:
-            q = rotary_embedding(q, pos[:, None], self.rope_base)
-            k_new = rotary_embedding(k_new, pos[:, None], self.rope_base)
-        cache = write_token(cache, k_new, v_new, pos)
-        if decode_kernel(cache.kind, *cache.k.shape[1:3], *q.shape[2:]):
+            q = self._rope(q, pos[:, None])
+            k_new = self._rope(k_new, pos[:, None])
+        length = cache.max_len
+        ring = self.window is not None
+        # K may be stored wider than the head (zero lanes: serve/cache.py).
+        q, k_new = (fit_width(a, cache.k.shape[-1]) for a in (q, k_new))
+        cache = write_token(cache, k_new, v_new, pos % length if ring else pos)
+        scale = 1.0 / self._head_dim ** 0.5
+        if (decode_kernel(cache.kind, *cache.k.shape[1:3], self.num_heads,
+                          cache.k.shape[-1], cache.v.shape[-1])
+                and (not ring or length == self.window)):
             # Shared K/V heads on a TPU: the cache is read where it lies.
             from tpudml.ops.decode_attn import decode_attn, kernel_interpret
 
-            o = decode_attn(q, cache.k, cache.v, pos,
+            o = decode_attn(q, cache.k, cache.v, pos, scale=scale,
+                            sink=self._sink(params),
+                            name="decode_attn_window" if ring else "decode_attn",
                             interpret=kernel_interpret())
         else:
             k, v = read_all(cache, x.dtype)
-            if 1 < k.shape[2] < q.shape[2]:
+            if self._positional:
+                k_pos = (ring_positions(pos, length) if ring
+                         else jnp.arange(length))
+                o = attention_by_position(
+                    q, k, v, pos[:, None], k_pos, window=self.window,
+                    sink=self._sink(params), scale=scale)
+            elif 1 < k.shape[2] < q.shape[2]:
                 o = decode_attention_grouped(q, k, v, pos)
             else:  # MHA as it is; one K/V head broadcasts
                 k, v = self._gqa_repeat(k, v, self.num_heads)
                 o = decode_attention(q, k, v, pos)
-        o = o.reshape(b, 1, self._inner)
+        o = o.reshape(b, 1, self._inner_v)
         return self._dense(params["out"], o), cache
 
     def apply_decode_window(self, params, cache, x, pos):
@@ -431,12 +570,13 @@ class MultiHeadAttention(Module):
         from tpudml.serve.cache import read_all, write_token
 
         self._serve_guard()
+        self._dense_cache_only("the speculative window")
         b, qlen = x.shape[:2]
         q, k_new, v_new = self._project(params, x)
         if self.rope:
             positions = pos[:, None] + jnp.arange(qlen)[None, :]  # [B, Q]
-            q = rotary_embedding(q, positions, self.rope_base)
-            k_new = rotary_embedding(k_new, positions, self.rope_base)
+            q = self._rope(q, positions)
+            k_new = self._rope(k_new, positions)
         cache = write_token(cache, k_new, v_new, pos)
         k, v = read_all(cache, x.dtype)
         k, v = self._gqa_repeat(k, v, self.num_heads)
@@ -455,12 +595,13 @@ class MultiHeadAttention(Module):
         from tpudml.serve.paged import read_table, write_tokens
 
         self._serve_guard()
+        self._dense_cache_only("the paged pool")
         b, qlen = x.shape[:2]
         q, k_new, v_new = self._project(params, x)
         if self.rope:
             positions = pos[:, None] + jnp.arange(qlen)[None, :]
-            q = rotary_embedding(q, positions, self.rope_base)
-            k_new = rotary_embedding(k_new, positions, self.rope_base)
+            q = self._rope(q, positions)
+            k_new = self._rope(k_new, positions)
         pool = write_tokens(pool, k_new, v_new, table, pos)
         k, v = read_table(pool, table, x.dtype)
         k, v = self._gqa_repeat(k, v, self.num_heads)
@@ -475,12 +616,13 @@ class MultiHeadAttention(Module):
         from tpudml.serve.paged import read_row_prefix, write_chunk
 
         self._serve_guard()
+        self._dense_cache_only("the paged pool")
         c = x.shape[1]
         q, k_new, v_new = self._project(params, x)
         if self.rope:
             positions = start + jnp.arange(c)
-            q = rotary_embedding(q, positions, self.rope_base)
-            k_new = rotary_embedding(k_new, positions, self.rope_base)
+            q = self._rope(q, positions)
+            k_new = self._rope(k_new, positions)
         pool = write_chunk(pool, k_new, v_new, table_row, start)
         k, v = read_row_prefix(pool, table_row, start + c, x.dtype)
         k, v = self._gqa_repeat(k, v, self.num_heads)
@@ -491,7 +633,7 @@ class MultiHeadAttention(Module):
         o = o.reshape(1, c, self._inner)
         return self._dense(params["out"], o), pool
 
-    def apply_prefill(self, params, cache, x, slot, start: int):
+    def apply_prefill(self, params, cache, x, slot, start: int, n_real=None):
         """Prefill one chunk of one slot: x [1, C, d] are features of
         prompt tokens at global positions [start, start+C). Writes their
         K/V, attends the chunk over the slot's [0, start+C) window with
@@ -499,18 +641,47 @@ class MultiHeadAttention(Module):
         cache). ``start`` is STATIC — one compiled program per chunk
         index, shared across slots/requests. On TPU the window attention
         reuses the flash kernel (``k_shift`` moves the causal diagonal
-        to the chunk's global offset)."""
-        from tpudml.serve.cache import read_slot_prefix, write_chunk
+        to the chunk's global offset).
+
+        A window layer (its cache a ring) sees, besides the chunk's own
+        keys, the ``window`` positions before ``start`` as the ring holds
+        them, and then keeps the last rows of the chunk's ``n_real`` real
+        tokens (traced; default all): a padded tail written into a ring
+        would lie over rows that still count."""
+        from tpudml.serve.cache import (fit_width, read_ring_slot,
+                                        read_slot_prefix, write_chunk,
+                                        write_ring_chunk)
 
         self._serve_guard()
         c = x.shape[1]
         q, k_new, v_new = self._project(params, x)
         if self.rope:
             positions = start + jnp.arange(c)
-            q = rotary_embedding(q, positions, self.rope_base)
-            k_new = rotary_embedding(k_new, positions, self.rope_base)
+            q = self._rope(q, positions)
+            k_new = self._rope(k_new, positions)
+        # K may be stored wider than the head (zero lanes: serve/cache.py);
+        # q takes the same lanes, and the scores the head's own scale.
+        q, k_new = (fit_width(a, cache.k.shape[-1]) for a in (q, k_new))
+        scale = 1.0 / self._head_dim ** 0.5
+        if self.window is not None:
+            length = cache.max_len
+            k_old, v_old = read_ring_slot(cache, slot, start, x.dtype)
+            o = attention_by_position(
+                q, jnp.concatenate([k_old, k_new], axis=1),
+                jnp.concatenate([v_old, v_new], axis=1),
+                start + jnp.arange(c),
+                start - length + jnp.arange(length + c), window=self.window,
+                sink=self._sink(params), scale=scale)
+            cache = write_ring_chunk(cache, k_new, v_new, slot, start,
+                                     c if n_real is None else n_real)
+            return self._dense(params["out"], o.reshape(1, c, -1)), cache
         cache = write_chunk(cache, k_new, v_new, slot, start)
         k, v = read_slot_prefix(cache, slot, start + c, x.dtype)
+        if self._positional:
+            o = attention_by_position(
+                q, k, v, start + jnp.arange(c), jnp.arange(start + c),
+                sink=self._sink(params), scale=scale)
+            return self._dense(params["out"], o.reshape(1, c, -1)), cache
         k, v = self._gqa_repeat(k, v, self.num_heads)
         if jax.default_backend() == "tpu":
             o = _chunk_flash_window(q, k, v, start)

@@ -336,6 +336,27 @@ class GatedGroupRMSNorm(Module):
 
 
 @dataclass(frozen=True)
+class GatedMLP(Module):
+    """A gated feed-forward (SwiGLU): ``(silu(x @ gate) * (x @ up)) @ down``,
+    no bias."""
+
+    embed_dim: int
+    hidden_dim: int
+    dtype: Any = jnp.float32
+
+    def init(self, key):
+        d, h = self.embed_dim, self.hidden_dim
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"gate": _uniform_fan_in(kg, (d, h), d, self.dtype),
+                "up": _uniform_fan_in(ku, (d, h), d, self.dtype),
+                "down": _uniform_fan_in(kd, (h, d), h, self.dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        y = jax.nn.silu(x @ params["gate"]) * (x @ params["up"])
+        return y @ params["down"], state
+
+
+@dataclass(frozen=True)
 class Sequential(Module):
     """Chain of modules; params/state are dicts keyed ``layer{i}``."""
 

@@ -394,8 +394,9 @@ class SigmoidMoE(Module):
     ``num_experts``; a token's experts are the top-k of ``s + bias`` (the
     bias only chooses); their weights are ``routed_scale * s_e / (sum of
     the k chosen s + 1e-20)`` (``norm_topk``). An expert is two matrices,
-    ``relu(u @ up)^2 @ down``; the shared expert, of width ``shared_dim``,
-    sees every token.
+    ``relu(u @ up)^2 @ down``, or with ``gated`` three, ``(silu(u @ gate) *
+    (u @ up)) @ down`` (SwiGLU); the shared expert, of width ``shared_dim``
+    and of the same form, sees every token (``shared_dim`` 0: there is none).
 
     ``held = (first, count)``: the contiguous experts whose weights live
     here (default: all of them). The layer routes over all ``num_experts``
@@ -426,6 +427,7 @@ class SigmoidMoE(Module):
     norm_topk: bool = True
     held: tuple[int, int] | None = None
     dtype: Any = jnp.float32
+    gated: bool = False
 
     def __post_init__(self):
         first, count = self._held
@@ -444,15 +446,31 @@ class SigmoidMoE(Module):
         d, h, hs = self.embed_dim, self.expert_dim, self.shared_dim
         n = self._held[1]
         kr, ku, kd, ksu, ksd = jax.random.split(key, 5)
-        return {
+        params = {
             "router": {"kernel": _uniform_fan_in(kr, (d, self.num_experts), d,
                                                  jnp.float32),
                        "bias": jnp.zeros((self.num_experts,), jnp.float32)},
             "experts": {"up": _uniform_fan_in(ku, (n, d, h), d, self.dtype),
                         "down": _uniform_fan_in(kd, (n, h, d), h, self.dtype)},
-            "shared": {"up": _uniform_fan_in(ksu, (d, hs), d, self.dtype),
-                       "down": _uniform_fan_in(ksd, (hs, d), hs, self.dtype)},
-        }, {}
+        }
+        if hs:
+            params["shared"] = {
+                "up": _uniform_fan_in(ksu, (d, hs), d, self.dtype),
+                "down": _uniform_fan_in(ksd, (hs, d), hs, self.dtype)}
+        if self.gated:
+            kg, ksg = jax.random.split(jax.random.fold_in(key, 1))
+            params["experts"]["gate"] = _uniform_fan_in(kg, (n, d, h), d, self.dtype)
+            if hs:
+                params["shared"]["gate"] = _uniform_fan_in(ksg, (d, hs), d, self.dtype)
+        return params, {}
+
+    def _hidden(self, p, tokens, spec: str):
+        """An expert's hidden activations: ``tokens`` times ``p``'s ``up``
+        (and ``gate``) by the einsum ``spec``."""
+        up = jnp.einsum(spec, tokens, p["up"])
+        if self.gated:
+            return jax.nn.silu(jnp.einsum(spec, tokens, p["gate"])) * up
+        return jnp.square(jax.nn.relu(up))
 
     def scores(self, params, tokens):
         """s [G, num_experts] float32 of tokens [G, d], computed in float32."""
@@ -495,11 +513,12 @@ class SigmoidMoE(Module):
         comb = jnp.einsum("gk,gke->ge", w, onehot)  # weights, zero elsewhere
         load = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)  # tokens an expert
         ex = params["experts"]
-        hidden = jnp.square(jax.nn.relu(jnp.einsum("gd,edh->geh", tokens, ex["up"])))
+        hidden = self._hidden(ex, tokens, "gd,edh->geh")
         hidden = (hidden.astype(jnp.float32) * comb[..., None]).astype(tokens.dtype)
         y = jnp.einsum("geh,ehd->gd", hidden, ex["down"])
-        sh = params["shared"]
-        y = y + jnp.square(jax.nn.relu(tokens @ sh["up"])) @ sh["down"]
+        if self.shared_dim:
+            sh = params["shared"]
+            y = y + self._hidden(sh, tokens, "gd,dh->gh") @ sh["down"]
         counts = {"routed": jnp.sum(live).astype(jnp.int32) * k,
                   "held": jnp.sum(load), "touched": jnp.sum(load > 0).astype(jnp.int32),
                   "load_max": jnp.max(load), "choices": topi}

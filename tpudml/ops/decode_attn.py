@@ -35,6 +35,14 @@ block index at ``pos[b] // block`` so that a repeated index skips the
 DMA, and ``pl.when`` the body) once that count follows live rows:
 ROADMAP.md A1.
 
+K and V blocks have their own widths (a 192-wide key stored in 256 lanes
+beside a 128-wide value: ``scale`` is then the caller's, of the head and
+not of the lanes), and a ``sink`` [H] starts the online softmax at
+``m = sink, l = 1`` with an empty accumulator: one more column of weight
+``exp(sink)`` and no value, at no cost a block. A window layer's ring of W
+rows needs no bound of its own: the rows ``<= pos`` are the keys the query
+sees, all W of them once the ring has wrapped.
+
 Inference only. ``serve/cache.py:decode_kernel`` says which caches take
 this path; everything else keeps its einsum.
 """
@@ -91,14 +99,20 @@ def _rows(ref):
     return ref.reshape(rows * kv_heads, d)[:]
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, block: int, kv_heads: int, group: int):
+def _kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float, block: int,
+            kv_heads: int, group: int, sink: bool):
+    sink_ref = rest[0] if sink else None
+    o_ref, m_ref, l_ref, acc_ref = rest[sink:]
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if sink:  # a column of weight exp(sink) that carries no value
+            m_ref[:] = sink_ref[:]
+            l_ref[:] = jnp.ones_like(l_ref)
+        else:
+            m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     ct = jnp.promote_types(q_ref.dtype, k_ref.dtype)
@@ -134,14 +148,19 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
-                block: int | None = None,
+                scale: float | None = None, sink: jax.Array | None = None,
+                block: int | None = None, name: str = "decode_attn",
                 interpret: bool = False) -> jax.Array:
     """:func:`tpudml.nn.attention.decode_attention_grouped` as one kernel:
-    q [B, 1, H, D] over the cache buffers k, v [B, L, Hkv, D] as stored,
-    per-slot positions ``pos`` [B] -> [B, 1, H, D] in q's type. ``L`` is a
-    multiple of ``block`` (default :func:`block_rows`)."""
+    q [B, 1, H, D] over the cache buffers k [B, L, Hkv, D] and v [B, L, Hkv,
+    Dv] as stored, per-slot positions ``pos`` [B] -> [B, 1, H, Dv] in q's
+    type. ``L`` is a multiple of ``block`` (default :func:`block_rows`);
+    ``scale`` defaults to ``D ** -0.5``; ``sink`` [H] float32 joins every
+    head's denominator (`tpudml.nn.attention.attention_by_position`);
+    ``name`` is the kernel's in a device trace (a window layer's ring reads
+    as ``decode_attn_window``)."""
     b, _, h, d = q.shape
-    length, kv_heads = k.shape[1:3]
+    length, kv_heads, dv = v.shape[1:]
     block = block or block_rows(length, kv_heads)
     if length % block or h % kv_heads:
         raise ValueError(
@@ -149,29 +168,38 @@ def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
             f"heads over {kv_heads}")
     if kv_heads == 1:
         # The chip keeps a size-1 head axis out of the tiles: [B, L, D].
-        k, v = k.reshape(b, length, d), v.reshape(b, length, d)
-        kv_spec = pl.BlockSpec((1, block, d), lambda i, j, pos: (i, j, 0))
+        k, v = k.reshape(b, length, d), v.reshape(b, length, dv)
+        k_spec, v_spec = (pl.BlockSpec((1, block, w), lambda i, j, pos: (i, j, 0))
+                          for w in (d, dv))
     else:
-        kv_spec = pl.BlockSpec((1, block, kv_heads, d),
-                               lambda i, j, pos: (i, j, 0, 0))
-    q_spec = pl.BlockSpec((None, None, h, d), lambda i, j, pos: (i, 0, 0, 0))
+        k_spec, v_spec = (pl.BlockSpec((1, block, kv_heads, w),
+                                       lambda i, j, pos: (i, j, 0, 0))
+                          for w in (d, dv))
+    q_spec, o_spec = (pl.BlockSpec((None, None, h, w),
+                                   lambda i, j, pos: (i, 0, 0, 0))
+                      for w in (d, dv))
+    operands, specs = [q, k, v], [q_spec, k_spec, v_spec]
+    if sink is not None:
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
+        specs.append(pl.BlockSpec((h, 1), lambda i, j, pos: (0, 0)))
     return pl.pallas_call(
-        partial(_kernel, scale=1.0 / d ** 0.5, block=block,
-                kv_heads=kv_heads, group=h // kv_heads),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        partial(_kernel, scale=scale or 1.0 / d ** 0.5, block=block,
+                kv_heads=kv_heads, group=h // kv_heads,
+                sink=sink is not None),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, length // block),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            in_specs=specs,
+            out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),  # running max
                 pltpu.VMEM((h, 1), jnp.float32),  # running sum
-                pltpu.VMEM((h, d), jnp.float32),  # output accumulator
+                pltpu.VMEM((h, dv), jnp.float32),  # output accumulator
             ],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name="decode_attn",
+        name=name,
         interpret=interpret,
-    )(pos.astype(jnp.int32), q, k, v)
+    )(pos.astype(jnp.int32), *operands)

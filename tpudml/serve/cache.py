@@ -29,6 +29,15 @@ beyond a sequence's current token only ever hold zeros-or-stale values
 that the causal mask (``k_pos <= pos``) excludes; no masking state is
 stored in the cache itself.
 
+K and V need not be as wide as each other (``init_cache``'s ``v_dim``),
+and K may be stored wider than the head (``stored_width``: a 192-wide key
+in 256 lanes, the rest zero), so every cache this file makes has rows of
+whole 128-lane tiles or of at most one. A layer whose queries see only
+the last W positions keeps a RING of W rows: position p lies in row
+``p % W`` (``ring_positions``, ``read_ring_slot``, ``write_ring_chunk``;
+the decode step writes through ``write_token`` at ``pos % W``). A model
+gives each of its layers the shape it needs (``models/hybrid.py``).
+
 A second kind of per-slot state lives beside the K/V cache: the
 ``RecurrentState`` of a state-space layer (end of this file). A model's
 per-layer cache tuple may hold both kinds, and ``None`` for a layer that
@@ -43,6 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpudml.ops.tiling import round_up
+
 KINDS = ("f32", "bf16", "int8", "bf16_sim", "int8_sim")
 
 # Floor on the per-(token, head) scale: an all-zero row (unwritten cache
@@ -55,8 +66,8 @@ _SCALE_EPS = 1e-8
 class KVCache:
     """One layer's cache: K/V plus (int8 only) per-(token, head) scales."""
 
-    k: jax.Array  # [B, L, Hkv, Dh] storage dtype
-    v: jax.Array
+    k: jax.Array  # [B, L, Hkv, Dk] storage dtype
+    v: jax.Array  # [B, L, Hkv, Dv]
     k_scale: jax.Array  # [B, L, Hkv] f32; zeros-shaped [0] when unused
     v_scale: jax.Array
     kind: str = field(metadata=dict(static=True))
@@ -73,18 +84,41 @@ def _store_dtype(kind: str):
     }[kind]
 
 
+def stored_width(head_dim: int) -> int:
+    """The lanes a K or V row of ``head_dim`` is stored in: itself up to
+    one 128-lane tile, whole tiles beyond. A 192-wide key is stored 256
+    wide, the last 64 lanes zero (a zero lane adds nothing to ``q . k``),
+    so that both fast paths of the decode step (:func:`row_scatter`,
+    :func:`decode_kernel`) hold for it. Left 192 wide the chip keeps the
+    cache L-minor: a row scatter then transposes all of it (12.3 ms a
+    layer at 128 x 8192 x 4, against 0.13) and the kernel re-lays every
+    block (10.9 ms against 4.3); the price is a third more K (PERF.md §6,
+    PR 37: both sides)."""
+    return head_dim if head_dim <= 128 else round_up(head_dim, 128)
+
+
+def fit_width(x: jax.Array, width: int) -> jax.Array:
+    """x [..., D] with zero lanes up to ``width`` (itself where D is)."""
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def init_cache(batch: int, max_len: int, kv_heads: int, head_dim: int,
-               kind: str = "f32") -> KVCache:
+               kind: str = "f32", v_dim: int | None = None) -> KVCache:
+    """``max_len`` rows a slot, K ``head_dim`` wide and V ``v_dim``
+    (default: as K). A ring is a cache of its window's rows."""
     if kind not in KINDS:
         raise ValueError(f"unknown cache kind {kind!r}; one of {KINDS}")
     shape = (batch, max_len, kv_heads, head_dim)
+    vshape = (batch, max_len, kv_heads, v_dim or head_dim)
     sshape = (batch, max_len, kv_heads) if kind == "int8" else (0,)
     # k/v (and the scales) must be DISTINCT buffers: the engine donates
     # the cache pytree every step, and XLA rejects donating one buffer
     # twice — so no `z = zeros(...); KVCache(k=z, v=z, ...)` aliasing.
     return KVCache(
         k=jnp.zeros(shape, _store_dtype(kind)),
-        v=jnp.zeros(shape, _store_dtype(kind)),
+        v=jnp.zeros(vshape, _store_dtype(kind)),
         k_scale=jnp.zeros(sshape, jnp.float32),
         v_scale=jnp.zeros(sshape, jnp.float32),
         kind=kind,
@@ -134,7 +168,7 @@ def row_scatter(head_dim: int) -> bool:
 
 
 def decode_kernel(kind: str, max_len: int, kv_heads: int, num_heads: int,
-                  head_dim: int) -> bool:
+                  head_dim: int, v_dim: int | None = None) -> bool:
     """Whether ``MultiHeadAttention.apply_decode`` reads this cache with
     the Pallas kernel (``ops/decode_attn.py``) and not with an einsum.
 
@@ -148,11 +182,12 @@ def decode_kernel(kind: str, max_len: int, kv_heads: int, num_heads: int,
     64 slots x 8192 rows, 16 x 128 over 1, bf16, the einsum's step takes
     120.9 ms and the kernel's 11.6 (PERF.md §6, PR 31). Everything else —
     MHA, the int8 and ``*_sim`` kinds, a ragged ``max_len`` — keeps the
-    einsum."""
+    einsum. ``head_dim`` and ``v_dim`` are the widths as stored."""
     from tpudml.ops.decode_attn import block_rows, kernel_interpret
 
     block = block_rows(max_len, kv_heads)
     return (kernel_interpret() is not None and row_scatter(head_dim)
+            and row_scatter(v_dim or head_dim)
             and kv_heads < num_heads and kind in ("bf16", "f32")
             and block % 16 == 0 and max_len % block == 0)
 
@@ -218,6 +253,52 @@ def write_chunk(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
     return KVCache(k=k, v=v, k_scale=k_sc, v_scale=v_sc, kind=cache.kind)
 
 
+def write_ring_chunk(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
+                     slot: jax.Array, start: int, n_real) -> KVCache:
+    """Prefill write into a ring of L rows: of k_new/v_new [1, C, Hkv, D]
+    at positions [start, start + C), of which the first ``n_real`` (traced)
+    are real, row ``r`` takes the LAST real position that lies in it
+    (``p % L == r``) and keeps what it held where the chunk has none. C may
+    pass L (the chunk's early rows are then never stored) and the padded
+    tail never lands: in a ring it would lie over rows that still count."""
+    if cache.kind == "int8":
+        raise ValueError("a ring cache is not stored int8")
+    length = cache.max_len
+    last = jnp.asarray(n_real, jnp.int32) - 1  # index of the last real token
+    idx = last - (last + start - jnp.arange(length)) % length  # [L], < 0: none
+    take = (idx >= 0)[None, :, None, None]
+    at = jnp.maximum(idx, 0)
+    out = []
+    for buf, new in ((cache.k, k_new), (cache.v, v_new)):
+        rows, _ = _encode(new[:, at], cache.kind)
+        old = lax.dynamic_slice_in_dim(buf, slot, 1, axis=0)
+        out.append(lax.dynamic_update_slice_in_dim(
+            buf, jnp.where(take, rows.astype(buf.dtype), old), slot, axis=0))
+    return KVCache(k=out[0], v=out[1], k_scale=cache.k_scale,
+                   v_scale=cache.v_scale, kind=cache.kind)
+
+
+def ring_positions(pos: jax.Array, length: int) -> jax.Array:
+    """[B, L]: the position each row of a ring of ``length`` rows holds
+    once the token at ``pos`` [B] is written — the latest ``p <= pos`` with
+    ``p % length == row``; negative where the row holds nothing yet."""
+    row = jnp.arange(length)[None, :]
+    return pos[:, None] - (pos[:, None] - row) % length
+
+
+def read_ring_slot(cache: KVCache, slot: jax.Array, start: int,
+                   dtype) -> tuple[jax.Array, jax.Array]:
+    """One slot's ring in order of position before a chunk at ``start``
+    (static): [1, L, Hkv, D], row j the position ``start - L + j`` (a
+    negative one holds nothing the mask lets through)."""
+    length = cache.max_len
+    k = lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=0)
+    v = lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=0)
+    if start % length:
+        k, v = (jnp.roll(a, -(start % length), axis=1) for a in (k, v))
+    return k.astype(dtype), v.astype(dtype)
+
+
 def read_all(cache: KVCache, dtype) -> tuple[jax.Array, jax.Array]:
     """Full-cache read for the decode step: [B, L, Hkv, Dh] in the
     compute dtype, dequantized in the int8 case (this IS the "dequant in
@@ -234,10 +315,10 @@ def read_slot_prefix(cache: KVCache, slot: jax.Array, length: int,
                      dtype) -> tuple[jax.Array, jax.Array]:
     """One slot's first ``length`` rows (static) for a prefill chunk's
     attention window: [1, length, Hkv, Dh]."""
-    b, _, h, d = cache.k.shape
+    _, _, h, d = cache.k.shape
     at = (slot, 0, 0, 0)
     k = lax.dynamic_slice(cache.k, at, (1, length, h, d))
-    v = lax.dynamic_slice(cache.v, at, (1, length, h, d))
+    v = lax.dynamic_slice(cache.v, at, (1, length, h, cache.v.shape[-1]))
     if cache.kind == "int8":
         k = _dequant(k, lax.dynamic_slice(cache.k_scale, (slot, 0, 0),
                                           (1, length, h)))

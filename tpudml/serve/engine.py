@@ -508,15 +508,22 @@ class ServingEngine:
         # How the decode step writes its K/V rows (``serve/dispatch``'s
         # ``row_scatter``): the page pool always scatters, the dense
         # cache where its layout allows (serve/cache.py:row_scatter).
-        self._row_scatter = int(self._paged or row_scatter(model.head_dim))
         # How it reads them (``decode_kernel``): the dense single-token step
         # with the Pallas kernel where serve/cache.py:decode_kernel says;
-        # the paged, speculative and tensor-parallel steps by einsum.
+        # the paged, speculative and tensor-parallel steps by einsum. A
+        # model whose layers keep caches of different shapes answers for
+        # all of them (``cache_forms``: models/hybrid.py).
+        forms = getattr(model, "cache_forms", None)
+        if forms is not None and not self._paged:
+            scatter, kernel = forms(cfg.max_len, cfg.cache_kind)
+        else:
+            scatter = self._paged or row_scatter(model.head_dim)
+            kernel = decode_kernel(cfg.cache_kind, cfg.max_len,
+                                   model.num_kv_heads or model.num_heads,
+                                   model.num_heads, model.head_dim)
+        self._row_scatter = int(scatter)
         self._decode_kernel = int(
-            not self._paged and not cfg.spec_k and mesh is None
-            and decode_kernel(cfg.cache_kind, cfg.max_len,
-                              model.num_kv_heads or model.num_heads,
-                              model.num_heads, model.head_dim))
+            not self._paged and not cfg.spec_k and mesh is None and kernel)
         if mesh is not None and (self._paged or cfg.spec_k):
             # The TP decode step shards cache heads through a shard_map
             # body that knows nothing of page tables or verify windows.
@@ -593,6 +600,8 @@ class ServingEngine:
                     self._reset_slot = jax.jit(model.reset_slot,
                                                donate_argnums=(0,))
                     self._route_backlog: list = []
+                    # Allocated once; ``serve/dispatch`` carries them.
+                    self._cache_bytes = model.cache_bytes(self.caches)
                 else:
                     self._decode = make_decode_step(model)
                 self._prefill_builder = self._build_prefill
@@ -993,11 +1002,17 @@ class ServingEngine:
                 # slots x max_len the dense step reads.
                 # ``state_slots``: slots whose recurrent state the step
                 # reads and writes back (a stateful model's active slots).
+                # A stateful model adds its caches' own counters: live
+                # rows by layer kind (``rows_full``, ``rows_window``) and
+                # the bytes allocated to each (``cache_bytes_*``).
                 with span("dispatch", "serve", step=steps, active=n_active,
                           rows=int(pos[active].sum()),
                           row_scatter=self._row_scatter,
                           decode_kernel=self._decode_kernel,
-                          state_slots=n_active if self._stateful else 0):
+                          state_slots=n_active if self._stateful else 0,
+                          **({**self.model.live_rows(pos[active], cfg.max_len),
+                              **self._cache_bytes}
+                             if self._stateful else {})):
                     counters = routes_np = None
                     if not self._stateful:
                         last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
