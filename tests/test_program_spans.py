@@ -117,8 +117,11 @@ def test_serve_children_lie_inside_their_pass(traced_serve):
         for child in _spans(tracer, f"serve/{name}"):
             holders = [p for p in passes if _inside(child, p)]
             assert len(holders) == 1, (name, child)
+            # A pass's `step` is the step it dispatches; with a step kept in
+            # flight (a dense engine) it fetches and commits the one before.
             if "step" in (child.args or {}):
-                assert child.args["step"] == holders[0].args["step"]
+                assert child.args["step"] == holders[0].args["step"] - (
+                    name in ("fetch", "commit"))
     assert all({"step", "queue", "active"} <= set(p.args) for p in passes)
 
 

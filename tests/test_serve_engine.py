@@ -381,3 +381,168 @@ def test_overload_config_validation():
         ServeConfig(deadline_s=0.0)
     with pytest.raises(ValueError, match="step_time_s"):
         ServeConfig(step_time_s=-1.0)
+
+
+# ------------------------------------------- one decode step kept in flight
+
+
+def _fetch_then_dispatch(eng, reqs):
+    """The order the loop had before it kept a step in flight, as the
+    reference: admit into free slots, run one step, fetch it, commit it,
+    and only then go round. Answers end by count; everybody is there at
+    the start. -> ({rid: tokens}, the admit / evict events)."""
+    b = eng.cfg.slots
+    queue = sorted(reqs, key=lambda r: (r.arrival_time, r.rid))
+    last, pos = np.zeros(b, np.int32), np.zeros(b, np.int32)
+    owed, rid = np.zeros(b, int), np.full(b, -1)
+    tokens, events, step = {r.rid: [] for r in reqs}, [], 0
+    while queue or (rid >= 0).any():
+        for i in range(b):
+            if rid[i] < 0 and queue:
+                req = queue.pop(0)
+                pos[i], last[i] = eng._admit(i, req)
+                owed[i], rid[i] = req.max_new_tokens, req.rid
+                events.append(("admit", req.rid, i, step))
+        state = ((np.stack([last, pos, rid >= 0]).astype(np.int32),)
+                 if eng._stateful else (last.copy(), pos.copy()))
+        out, _, eng.caches = eng._decode(eng.params, eng.caches, *state)
+        step += 1
+        for i, tok in enumerate(np.asarray(out)[:b].tolist()):
+            if rid[i] < 0:
+                continue
+            tokens[rid[i]].append(tok)
+            pos[i], last[i], owed[i] = pos[i] + 1, tok, owed[i] - 1
+            if not owed[i]:
+                events.append(("evict", int(rid[i]), i, step))
+                rid[i] = -1
+    return tokens, events, step
+
+
+@pytest.mark.parametrize("build", [_model, _pattern_128],
+                         ids=["stateless", "stateful"])
+def test_step_in_flight_serves_the_fetch_then_dispatch_schedule(build):
+    """With step N + 1 dispatched before step N is fetched, answers that
+    end by count get the tokens, the slots, the steps and the event order
+    of the loop that fetched first: an ending by count is known when its
+    last step is dispatched, so its slot is refilled for the very next."""
+    model = build()
+    params, _ = model.init(jax.random.key(0))
+    cfg = ServeConfig(slots=3, max_len=64, prefill_chunk=8)
+    reqs, _ = poisson_workload(10, math.inf, 11, vocab_size=V,
+                               prompt_len=(1, 20), new_tokens=(1, 8))
+    want, events, steps = _fetch_then_dispatch(
+        ServingEngine(model, params, cfg), reqs)
+    eng = ServingEngine(model, params, cfg)
+    assert eng._lookahead == 1
+    rep = eng.run(reqs)
+    assert {rid: st.tokens for rid, st in rep.requests.items()} == want
+    assert rep.events == events
+    assert rep.decode_steps == steps
+    assert rep.busy_slot_steps == rep.generated_tokens  # no step discarded
+    assert set(_terminal_states(rep).values()) == {"finished"}
+
+
+def test_eos_ending_costs_one_discarded_step(setup):
+    """An ending only the token tells is seen with the next step already
+    in flight: that step's token for the slot is dropped (never in the
+    ledger), the slot's next tenant comes one step later, and each request
+    still ends exactly once."""
+    model, params = setup
+    reqs, _ = poisson_workload(8, math.inf, 5, vocab_size=V,
+                               prompt_len=(2, 12), new_tokens=(4, 8))
+    cfg = dict(slots=2, max_len=64, prefill_chunk=8)
+    plain = ServingEngine(model, params, ServeConfig(**cfg)).run(reqs)
+    eos = plain.requests[0].tokens[1]
+    rep = ServingEngine(model, params, ServeConfig(eos_token=eos, **cfg)).run(reqs)
+
+    assert set(_terminal_states(rep).values()) == {"finished"}
+    early = []
+    for rid, st in rep.requests.items():
+        full = plain.requests[rid].tokens
+        cut = full.index(eos) + 1 if eos in full else len(full)
+        assert st.tokens == full[:cut]
+        if cut < len(full):
+            early.append(rid)
+    assert 0 in early
+    # one slot-step each, and no more, went to a request that had ended
+    assert rep.busy_slot_steps - rep.generated_tokens == len(early)
+    evicted = {e[1]: e for e in rep.events if e[0] == "evict"}
+    for kind, rid, slot, step in rep.events:
+        if kind != "admit" or step == 0:
+            continue
+        before = [e for e in evicted.values() if e[2] == slot and e[3] <= step]
+        _, gone, _, freed = max(before, key=lambda e: e[3])
+        assert step >= freed + (gone in early)
+
+
+def test_midflight_deadline_costs_one_discarded_step(setup):
+    """So does an ending only the clock tells: the expiry is seen at the
+    commit of step N with N + 1 in flight, and N + 1's token is dropped."""
+    model, params = setup
+    cfg = ServeConfig(slots=1, max_len=64, prefill_chunk=8,
+                      deadline_s=0.2, step_time_s=0.01)
+    rep = ServingEngine(model, params, cfg).run([_req(0, 4, 50), _req(1, 4, 4)])
+    assert _terminal_states(rep) == {0: "expired", 1: "expired"}
+    r0 = rep.requests[0]
+    # step k commits at (k + 1) x 0.01: the 21st token is the first past 0.2
+    assert len(r0.tokens) == 21 and r0.expired == pytest.approx(0.21)
+    assert rep.busy_slot_steps - rep.generated_tokens == 1
+    assert rep.decode_steps == 22
+    assert [e for e in rep.events if e[0] == "expire" and e[1] == 0] == [
+        ("expire", 0, 0, 21)]
+
+
+@pytest.mark.parametrize("kw,ahead", [({}, 1), (dict(spec_k=2), 0),
+                                      (dict(cache_layout="paged", page_size=8), 0)],
+                         ids=["dense", "spec", "paged"])
+def test_dispatch_opens_before_the_fetch_of_the_step_before(setup, kw, ahead):
+    """``serve/dispatch`` of step N + 1 opens before ``serve/fetch`` of step
+    N and says ``ahead`` 1; the first step after an idle engine has nothing
+    in flight before it and says 0, as does every step of an engine whose
+    next inputs are data (speculative) or the commit's bookkeeping (paged)."""
+    from tpudml.obs import Tracer, use_tracer
+
+    model, params = setup
+    cfg = ServeConfig(slots=2, max_len=64, prefill_chunk=8, step_time_s=0.01, **kw)
+    tracer = Tracer()
+    with use_tracer(tracer):  # the engine drains between the two arrivals
+        rep = ServingEngine(model, params, cfg).run(
+            [_req(0, 6, 5), _req(1, 9, 5, t=1.0)])
+    assert set(_terminal_states(rep).values()) == {"finished"}
+    spans = {name: {s.args["step"]: s for s in tracer.events
+                    if (s.cat, s.name) == ("serve", name)}
+             for name in ("dispatch", "fetch", "commit")}
+    steps = sorted(spans["dispatch"])
+    assert steps == sorted(spans["fetch"]) == sorted(spans["commit"])
+    assert steps == list(range(rep.decode_steps))
+    idle_before = {0, min(e[3] for e in rep.events if e[:2] == ("admit", 1))}
+    for n in steps:
+        d = spans["dispatch"][n]
+        assert d.args["ahead"] == (ahead and n not in idle_before)
+        assert spans["fetch"][n].ts_us <= spans["commit"][n].ts_us
+        if n + 1 in spans["dispatch"]:
+            nxt = spans["dispatch"][n + 1]
+            assert nxt.args["ahead"] == (nxt.ts_us < spans["fetch"][n].ts_us)
+    if ahead:
+        assert sum(s.args["ahead"] for s in spans["dispatch"].values()) == len(steps) - 2
+
+
+@pytest.mark.parametrize("build", [_model, _pattern_128],
+                         ids=["stateless", "stateful"])
+def test_a_stream_is_what_its_request_gets_alone(build):
+    """Arrivals spread over the wall clock, so that passes find the engine
+    idle, drained, mid-answer and refilling in turn: whatever the step in
+    flight overlaps, every request is served the tokens it gets alone."""
+    model = build()
+    params, _ = model.init(jax.random.key(0))
+    eng = ServingEngine(model, params,
+                        ServeConfig(slots=3, max_len=64, prefill_chunk=8))
+    reqs, _ = poisson_workload(24, 150.0, 5, vocab_size=V,
+                               prompt_len=(1, 20), new_tokens=(1, 12))
+    eng.run(reqs)  # compiles, so that the second run's passes are short
+    rep = eng.run(reqs)
+    assert rep.busy_slot_steps == rep.generated_tokens
+    for r in reqs:
+        alone = eng.run([Request(rid=r.rid, prompt=r.prompt,
+                                 max_new_tokens=r.max_new_tokens)])
+        assert rep.requests[r.rid].tokens == alone.requests[r.rid].tokens

@@ -199,12 +199,13 @@ def _entry_ops(text: str) -> int:
     return sum(" = " in line for line in entry.splitlines())
 
 
-def _serve_code_step(topo, kind, layers):
+def _serve_code_step(topo, kind, layers, in_flight=False):
     """The engine's decode step at the serving cell's cache (64 slots x
     8192 rows, multi-query 16 x 128, donated), compiled: (program text,
-    one layer's K)."""
+    one layer's K). ``in_flight``: as the run loop calls it, the tokens
+    taken from the step before on the device (``_with_device_tokens``)."""
     from tpudml.models import TransformerLM
-    from tpudml.serve.engine import make_decode_step
+    from tpudml.serve.engine import _with_device_tokens, make_decode_step
 
     slots, rows = 64, 8192
     model = TransformerLM(vocab_size=1024, embed_dim=2048, num_heads=16,
@@ -221,8 +222,12 @@ def _serve_code_step(topo, kind, layers):
     caches = jax.eval_shape(
         lambda: model.init_decode_cache(slots, rows, kind))
     ints = jax.ShapeDtypeStruct((slots,), i32, sharding=one)
-    text = make_decode_step(model).lower(
-        described(params), described(caches), ints, ints).compile().as_text()
+    step, state = make_decode_step(model), (ints, ints)
+    if in_flight:
+        step = _with_device_tokens(step, slots, stateful=False)
+        state = (ints, jax.ShapeDtypeStruct((4, slots), i32, sharding=one))
+    text = step.lower(
+        described(params), described(caches), *state).compile().as_text()
     return text, caches[0].k
 
 
@@ -236,6 +241,21 @@ def test_serve_decode_step_writes_rows_in_place(topo, kind, on_chip_kernel):
     assert " scatter(" in text and " while(" not in text
     assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
     assert ("decode_attn" in text) == (kind == "bf16")
+
+
+def test_serve_step_kept_in_flight_is_the_same_step_behind_a_select(
+        topo, on_chip_kernel):
+    """What the run loop dispatches (the previous step's tokens still on
+    the device, the host's word in one [4, B] array) is the maker's step
+    plus the select: the kernel a layer, the cache written in place
+    (donated through the wrapper), a handful of operations more."""
+    plain, k = _serve_code_step(topo, "bf16", layers=2)
+    text, _ = _serve_code_step(topo, "bf16", layers=2, in_flight=True)
+    assert text.count("decode_attn") == plain.count("decode_attn") > 0
+    assert " scatter(" in text and " while(" not in text
+    assert max(_copied_bytes(text)) < k.size * k.dtype.itemsize
+    assert "input_output_alias" in text
+    assert _entry_ops(text) <= _entry_ops(plain) + 4
 
 
 def test_serve_decode_step_reads_the_cache_once_in_place(topo, monkeypatch):

@@ -19,6 +19,12 @@ request churn never triggers a recompile.
   (the slot id is a traced scalar), so a max_len-M cache needs at most
   M/C prefill programs ever. The prompt's last token is NOT prefilled —
   it feeds the first decode step, which emits the first generated token.
+- **The loop** (``ServingEngine.run``) keeps one decode step in flight:
+  a pass dispatches step N + 1 and only then fetches and commits step N,
+  the next token staying on the device (``_with_device_tokens``), so the
+  device always has its next program queued and the host's work runs
+  under a running step. Where the next step's inputs are data
+  (speculative) or the commit's bookkeeping (paged) it fetches first.
 - **Scheduling** is FIFO by arrival time with slot-index tie-breaking:
   deterministic under a fixed workload seed (the scheduler unit tests
   pin eviction/refill order), and starvation-free — an admitted request
@@ -48,6 +54,7 @@ import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -180,6 +187,29 @@ def make_paged_decode_step(model):
 
     def step(params, caches, table, tokens, pos):
         return inner(params, caches, table, tokens, pos)
+
+    return jax.jit(step, donate_argnums=(1,))
+
+
+def _with_device_tokens(decode, slots: int, stateful: bool):
+    """What the run loop calls where it keeps a step in flight: ``decode``
+    (whatever :func:`make_decode_step`, :func:`make_stateful_decode_step`,
+    :func:`make_fused_decode_step` or the tensor-parallel engine returned,
+    signature untouched) behind a select, in one donated jit that the
+    device trace still calls ``jit_step``: (params, caches, prev, ctl int32
+    [4, B] = the host's token, position, active 0/1, fresh 0/1) -> what
+    ``decode`` returns. ``prev`` is the previous step's first output, still
+    on the device (its first ``slots`` entries are the tokens): a slot takes
+    its token from there, so step N + 1 can be queued before the host has
+    seen step N, unless it is ``fresh`` (admitted since that step, or there
+    was none), when the host's token stands. ``ctl`` is one NumPy array: one
+    transfer, no program of its own between two steps."""
+
+    def step(params, caches, prev, ctl):
+        tokens = jnp.where(ctl[3] != 0, ctl[0], prev[:slots])
+        if stateful:
+            return decode(params, caches, jnp.stack([tokens, ctl[1], ctl[2]]))
+        return decode(params, caches, tokens, ctl[1])
 
     return jax.jit(step, donate_argnums=(1,))
 
@@ -354,6 +384,16 @@ class RequestStats:
         return (self.token_times[-1] - self.token_times[0]) / (
             len(self.token_times) - 1
         )
+
+
+class _InFlight(NamedTuple):
+    """A decode step dispatched and not yet fetched (``ServingEngine.run``)."""
+
+    step: int
+    out: object  # its outputs, on the device: the tokens (spec: window, counts)
+    rids: np.ndarray  # the request it ran in each slot, -1 where none
+    owed: np.ndarray  # tokens each slot was still owed when it was dispatched
+    backlog: list | None  # prefill routes launched before it (stateful)
 
 
 @dataclass
@@ -637,6 +677,21 @@ class ServingEngine:
             self._spec = make_spec_decode_step(
                 model, draft_model, cfg.spec_k, paged=self._paged
             )
+        # How many decode steps stay in flight while the host fetches and
+        # commits the one before (``run``): one where the next step's
+        # inputs are the host's own arithmetic (a plain step advances each
+        # active slot by one token, and that token can stay on the device);
+        # none where they are data the host must see first (a speculative
+        # step advances a slot by its accepted length) or bookkeeping the
+        # commit does (the page pool).
+        self._lookahead = int(self._spec is None and not self._paged)
+        if self._lookahead:
+            self._step = _with_device_tokens(self._decode, cfg.slots,
+                                             self._stateful)
+            width = cfg.slots
+            if self._stateful:  # counters and routes ride behind the tokens
+                width += len(model.counter_names) + cfg.slots * model.route_width
+            self._no_prev = jnp.zeros(width, jnp.int32)
         # SLO admission pricing (deterministic, host-side).
         self._cost = None
         if cfg.slo is not None:
@@ -748,7 +803,8 @@ class ServingEngine:
                 chunk[0, :n] = prompt[s0:s0 + n]
                 if self._stateful:
                     # The chunk's routes stay on the device until the
-                    # next decode step's fetch (``_collect_routes``).
+                    # fetch of the next decode step dispatched
+                    # (``_collect_routes``).
                     self.caches, routes = self._prefill_at(s0)(
                         self.params, self.caches, jnp.asarray(chunk), slot_j,
                         np.int32(n))
@@ -761,13 +817,14 @@ class ServingEngine:
             self._prefill_draft(slot, prompt)
         return p, int(prompt[-1])
 
-    def _collect_routes(self, stats: dict) -> None:
-        """Move the prefill chunks' routes to their requests. Called
-        behind a decode step's fetch: the chunks ran before that step, so
-        each is a plain copy, not a wait."""
-        for rid, routes, n in self._route_backlog:
+    @staticmethod
+    def _collect_routes(stats: dict, backlog: list) -> None:
+        """Move prefill chunks' routes to their requests. ``backlog`` is
+        what admission had launched when a decode step was dispatched, and
+        this is called behind that step's fetch: the chunks ran before the
+        step, so each is a plain copy, not a wait."""
+        for rid, routes, n in backlog:
             stats[rid].routes.append(np.asarray(routes)[:n])
-        self._route_backlog.clear()
 
     def _admit_span(self, req: Request, slot: int, prompt: np.ndarray,
                     chunks: int, shared_pages: int):
@@ -863,6 +920,22 @@ class ServingEngine:
         state: finished, rejected (bounded queue full at arrival), or
         expired (deadline passed while queued or in flight) — the
         ledger-accounting invariant the overload tests audit.
+
+        A pass stages, admits, dispatches step N + 1 and only then fetches
+        and commits step N (``self._lookahead`` 1: the device always has
+        its next program queued, and the host's work runs under a running
+        step). The slot state below (``pos``, ``remaining``, ``active``) is
+        therefore kept as of the last DISPATCH: a plain step advances each
+        active slot by one token, so an answer that ends by count is known
+        to end when its last step is dispatched, its slot takes a new
+        tenant in the next pass (whose prefill chunks queue on the device
+        behind that step), and the schedule in steps is the one of a loop
+        that fetched before it dispatched. An ending only the token or the
+        clock tells (``eos_token``, ``deadline_s`` mid-flight) is seen at
+        commit N with N + 1 already in flight: that slot's token of N + 1
+        is dropped, and the slot frees one step later. With a lookahead of
+        0 (speculative, paged) a pass fetches and commits the step it
+        dispatched.
         """
         cfg = self.cfg
         b = cfg.slots
@@ -882,24 +955,31 @@ class ServingEngine:
         pos = np.zeros(b, np.int32)
         remaining = np.zeros(b, np.int64)
         slot_rid = np.full(b, -1, np.int64)
-        slot_deadline = np.full(b, np.inf)
         active = np.zeros(b, bool)
+        deadline_s = np.inf if cfg.deadline_s is None else cfg.deadline_s
+        in_flight: deque[_InFlight] = deque()  # oldest first
         events: list = []
-        steps = 0
+        steps = 0  # decode steps dispatched
         peak_queue = 0
         busy_slot_steps = 0
         deferred_logged: set[int] = set()  # one "defer" event per rid
         # Clock: wall time by default; virtual (decode-step-derived) when
-        # cfg.step_time_s is set — see ServeConfig.
+        # cfg.step_time_s is set — see ServeConfig. ``now(n)`` is the clock
+        # behind ``n`` steps, for a commit that runs with one more in flight.
         t0 = time.perf_counter()
         v_extra = 0.0  # virtual-clock idle skips (accumulated)
         if cfg.step_time_s is not None:
-            now = lambda: steps * cfg.step_time_s + v_extra  # noqa: E731
+            now = lambda n=None: (  # noqa: E731
+                (steps if n is None else n) * cfg.step_time_s + v_extra)
         else:
-            now = lambda: time.perf_counter() - t0  # noqa: E731
+            now = lambda n=None: time.perf_counter() - t0  # noqa: E731
 
-        while arrivals or queue or active.any():
+        while arrivals or queue or active.any() or in_flight:
             t = now()
+            # The events of a step already in flight go in ahead of this
+            # pass's own (staging, admission): the log reads in the order
+            # of the steps, an eviction before the refill of its slot.
+            mark = len(events) if in_flight else None
             with span("iter", "serve", step=steps,
                       active=int(active.sum())) as this_pass:
                 # Stage arrivals into the waiting queue; a full bounded
@@ -969,17 +1049,12 @@ class ServingEngine:
                     pos[i], last[i] = admitted
                     remaining[i] = req.max_new_tokens
                     slot_rid[i] = req.rid
-                    slot_deadline[i] = (
-                        req.arrival_time + cfg.deadline_s
-                        if cfg.deadline_s is not None
-                        else np.inf
-                    )
                     active[i] = True
                     st.admitted = now()
                     st.slot = i
                     events.append(("admit", req.rid, i, steps))
                 n_active = int(active.sum())
-                if not n_active:
+                if not n_active and not in_flight:
                     if not arrivals:
                         continue  # queue drained by expiry; loop re-checks
                     # Idle: nothing in flight, queue head hasn't arrived yet.
@@ -990,135 +1065,163 @@ class ServingEngine:
                         with span("idle", "serve"):
                             time.sleep(min(gap, 0.05))
                     continue
-                # One decode step for ALL slots. Inactive slots run garbage
-                # tokens at stale positions — harmless by the mask argument
-                # in the module docstring (paged: their zero table rows
-                # point every write at the garbage page) — so the compiled
-                # shape never changes with occupancy. Spec steps return a
-                # K+1-wide window + per-slot commit counts; plain steps
-                # reduce to the same contract at width 1.
-                busy_slot_steps += n_active
-                # ``rows``: cache rows that hold a token, of the
-                # slots x max_len the dense step reads.
-                # ``state_slots``: slots whose recurrent state the step
-                # reads and writes back (a stateful model's active slots).
-                # A stateful model adds its caches' own counters: live
-                # rows by layer kind (``rows_full``, ``rows_window``) and
-                # the bytes allocated to each (``cache_bytes_*``).
-                with span("dispatch", "serve", step=steps, active=n_active,
-                          rows=int(pos[active].sum()),
-                          row_scatter=self._row_scatter,
-                          decode_kernel=self._decode_kernel,
-                          state_slots=n_active if self._stateful else 0,
-                          **({**self.model.live_rows(pos[active], cfg.max_len),
-                              **self._cache_bytes}
-                             if self._stateful else {})):
-                    counters = routes_np = None
-                    if not self._stateful:
-                        last_j, pos_j = jnp.asarray(last), jnp.asarray(pos)
-                    if self._spec is not None:
-                        if self._paged:
-                            emitted, n_emit, _, self.caches, self._dcaches = (
-                                self._spec(self.params, self._dparams,
-                                           self.caches, self._dcaches,
-                                           jnp.asarray(self._table),
-                                           last_j, pos_j)
-                            )
+                if n_active:
+                    # One decode step for ALL slots. Inactive slots run garbage
+                    # tokens at stale positions — harmless by the mask argument
+                    # in the module docstring (paged: their zero table rows
+                    # point every write at the garbage page) — so the compiled
+                    # shape never changes with occupancy. Spec steps return a
+                    # K+1-wide window + per-slot commit counts; plain steps
+                    # reduce to the same contract at width 1.
+                    busy_slot_steps += n_active
+                    # ``ahead``: 1 when the step before is still unfetched
+                    # (this one takes that one's tokens on the device).
+                    # ``rows``: cache rows that hold a token, of the
+                    # slots x max_len the dense step reads.
+                    # ``state_slots``: slots whose recurrent state the step
+                    # reads and writes back (a stateful model's active slots).
+                    # A stateful model adds its caches' own counters: live
+                    # rows by layer kind (``rows_full``, ``rows_window``) and
+                    # the bytes allocated to each (``cache_bytes_*``).
+                    with span("dispatch", "serve", step=steps, active=n_active,
+                              ahead=int(bool(in_flight)),
+                              rows=int(pos[active].sum()),
+                              row_scatter=self._row_scatter,
+                              decode_kernel=self._decode_kernel,
+                              state_slots=n_active if self._stateful else 0,
+                              **({**self.model.live_rows(pos[active], cfg.max_len),
+                                  **self._cache_bytes}
+                                 if self._stateful else {})):
+                        rids = np.where(active, slot_rid, -1)
+                        backlog = None
+                        if self._lookahead:
+                            # A slot is fresh unless the step before ran it
+                            # for the same request: then its token is there.
+                            prev, fresh = self._no_prev, np.ones(b, bool)
+                            if in_flight:
+                                prev = in_flight[-1].out
+                                fresh = rids != in_flight[-1].rids
+                            if self._stateful:
+                                backlog, self._route_backlog = self._route_backlog, []
+                            out, _, self.caches = self._step(
+                                self.params, self.caches, prev,
+                                np.array([last, pos, active, fresh], np.int32))
                         else:
-                            emitted, n_emit, _, self.caches, self._dcaches = (
-                                self._spec(self.params, self._dparams,
-                                           self.caches, self._dcaches,
-                                           last_j, pos_j)
-                            )
-                    elif self._paged:
-                        next_t, _, self.caches = self._decode(
-                            self.params, self.caches,
-                            jnp.asarray(self._table), last_j, pos_j,
-                        )
-                    elif self._stateful:
-                        next_t, _, self.caches = self._decode(
-                            self.params, self.caches,
-                            np.stack([last, pos, active]).astype(np.int32),
-                        )
-                    else:
-                        next_t, _, self.caches = self._decode(
-                            self.params, self.caches, last_j, pos_j
-                        )
-                # Where the host waits for the device.
-                with span("fetch", "serve", step=steps):
-                    if self._spec is not None:
-                        emitted_np = np.asarray(jax.device_get(emitted))
-                        n_emit_np = np.asarray(jax.device_get(n_emit))
-                    else:
-                        next_np = np.asarray(jax.device_get(next_t))
-                        if self._stateful:  # counters, then routes, ride behind the tokens
-                            names = self.model.counter_names
-                            counters = dict(zip(
-                                names, next_np[b:b + len(names)].tolist()))
-                            routes_np = next_np[b + len(names):].reshape(b, -1)
-                            if self._route_backlog:
-                                self._collect_routes(stats)
-                        emitted_np = next_np[:b, None]
-                        n_emit_np = np.ones(b, np.int64)
-                steps += 1
-                t_step = now()
-                with span("commit", "serve", step=steps - 1) as commit:
-                    n_tokens = n_finished = n_expired = 0
-                    for i in range(b):
-                        if not active[i]:
-                            continue
-                        st = stats[slot_rid[i]]
-                        if routes_np is not None and routes_np.shape[1]:
-                            st.routes.append(routes_np[i:i + 1])
-                        done = False
-                        committed = 0
-                        for tok in emitted_np[i, : int(n_emit_np[i])]:
-                            tok = int(tok)
-                            st.tokens.append(tok)
-                            st.token_times.append(t_step)
-                            committed += 1
-                            if st.first_token is None:
-                                st.first_token = t_step
-                            pos[i] += 1
-                            last[i] = tok
-                            remaining[i] -= 1
-                            if remaining[i] <= 0 or (
-                                cfg.eos_token is not None
-                                and tok == cfg.eos_token
-                            ):
-                                done = True
-                                break
-                        n_tokens += committed
+                            # Copies of its own: the CPU backend may read a
+                            # NumPy buffer late, and ``pos`` moves on below.
+                            state = (last.copy(), pos.copy())
+                            table = (jnp.asarray(self._table),) if self._paged else ()
+                            if self._spec is not None:
+                                emitted, n_emit, _, self.caches, self._dcaches = (
+                                    self._spec(self.params, self._dparams,
+                                               self.caches, self._dcaches,
+                                               *table, *state))
+                                out = (emitted, n_emit)
+                            else:
+                                out, _, self.caches = self._decode(
+                                    self.params, self.caches, *table, *state)
+                    in_flight.append(_InFlight(steps, out, rids, remaining.copy(),
+                                               backlog))
+                    steps += 1
+                    # A step emits a token a slot (a speculative one at
+                    # least; its commit adds the rest), so who is still
+                    # active after it is known now.
+                    pos[active] += 1
+                    remaining[active] -= 1
+                    active &= remaining > 0
+                while len(in_flight) > (self._lookahead if n_active else 0):
+                    step, out, rids, owed, backlog = in_flight.popleft()
+                    counters = routes_np = None
+                    # Where the host waits for the device.
+                    with span("fetch", "serve", step=step):
                         if self._spec is not None:
-                            # accepted_len counts draft tokens actually
-                            # COMMITTED (committed - 1: the last commit is
-                            # the target's bonus/correction token) — a
-                            # window truncated by EOS or the max_new_tokens
-                            # budget logs only what landed in the ledger,
-                            # so mean_accepted_len stays an exact
-                            # tokens-per-target-step accounting.
-                            events.append(("spec", int(slot_rid[i]), i, steps,
-                                           committed - 1))
-                        if done:
-                            st.finished = t_step
-                            active[i] = False
-                            events.append(("evict", int(slot_rid[i]), i, steps))
-                            slot_rid[i] = -1
+                            emitted_np = np.asarray(jax.device_get(out[0]))
+                            n_emit_np = np.asarray(jax.device_get(out[1]))
+                        else:
+                            next_np = np.asarray(jax.device_get(out))
+                            if self._stateful:  # counters, then routes, ride behind the tokens
+                                names = self.model.counter_names
+                                counters = dict(zip(
+                                    names, next_np[b:b + len(names)].tolist()))
+                                routes_np = next_np[b + len(names):].reshape(b, -1)
+                                if backlog:
+                                    self._collect_routes(stats, backlog)
+                            emitted_np = next_np[:b, None]
+                            n_emit_np = np.ones(b, np.int64)
+                    t_step = now(step + 1)
+                    logged: list = []
+                    with span("commit", "serve", step=step) as commit:
+                        n_tokens = n_finished = n_expired = 0
+                        for i in range(b):
+                            if rids[i] < 0:
+                                continue
+                            st = stats[rids[i]]
+                            if st.finished is not None or st.expired is not None:
+                                # Ended at the commit before this one, which
+                                # this step was already in flight behind:
+                                # its token for the slot is dropped.
+                                continue
+                            if routes_np is not None and routes_np.shape[1]:
+                                st.routes.append(routes_np[i:i + 1])
+                            done = False
+                            committed = 0
+                            for tok in emitted_np[i, : int(n_emit_np[i])]:
+                                tok = int(tok)
+                                st.tokens.append(tok)
+                                st.token_times.append(t_step)
+                                committed += 1
+                                if st.first_token is None:
+                                    st.first_token = t_step
+                                if committed >= owed[i] or (
+                                    cfg.eos_token is not None
+                                    and tok == cfg.eos_token
+                                ):
+                                    done = True
+                                    break
+                            n_tokens += committed
+                            # The slot's state is this request's to move
+                            # until admission hands the slot on (it may
+                            # have: an ending by count frees it a pass early).
+                            mine = slot_rid[i] == rids[i]
+                            if mine:
+                                last[i] = tok
+                                pos[i] += committed - 1
+                                remaining[i] -= committed - 1
+                            if self._spec is not None:
+                                # accepted_len counts draft tokens actually
+                                # COMMITTED (committed - 1: the last commit is
+                                # the target's bonus/correction token) — a
+                                # window truncated by EOS or the max_new_tokens
+                                # budget logs only what landed in the ledger,
+                                # so mean_accepted_len stays an exact
+                                # tokens-per-target-step accounting.
+                                logged.append(("spec", int(rids[i]), i, step + 1,
+                                               committed - 1))
+                            if done:
+                                st.finished = t_step
+                                logged.append(("evict", int(rids[i]), i, step + 1))
+                                n_finished += 1
+                            elif t_step > st.arrival + deadline_s:
+                                # Mid-flight deadline eviction at the step
+                                # boundary: the slot frees for the queue head,
+                                # the partial tokens stay in the ledger,
+                                # finished stays None.
+                                st.expired = t_step
+                                logged.append(("expire", int(rids[i]), i, step + 1))
+                                n_expired += 1
+                            else:
+                                continue
                             self._release_slot(i)
-                            n_finished += 1
-                        elif t_step > slot_deadline[i]:
-                            # Mid-flight deadline eviction at the step
-                            # boundary: the slot frees for the queue head,
-                            # the partial tokens stay in the ledger,
-                            # finished stays None.
-                            st.expired = t_step
-                            active[i] = False
-                            events.append(("expire", int(slot_rid[i]), i, steps))
-                            slot_rid[i] = -1
-                            self._release_slot(i)
-                            n_expired += 1
-                    commit.set_metadata(tokens=n_tokens, finished=n_finished,
-                                        expired=n_expired, **(counters or {}))
+                            if mine:
+                                active[i] = False
+                                slot_rid[i] = -1
+                        commit.set_metadata(tokens=n_tokens, finished=n_finished,
+                                            expired=n_expired, **(counters or {}))
+                    if mark is None:
+                        events.extend(logged)
+                    else:
+                        events[mark:mark] = logged
+                        mark += len(logged)
         pool_stats = None
         if self._pool is not None:
             pool_stats = {
