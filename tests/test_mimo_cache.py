@@ -266,5 +266,6 @@ def test_the_forms_follow_the_stored_widths(monkeypatch):
                                       "sliding_window": 16}, {})
     assert roomy.cache_forms(64, "bf16") == (True, True)
     assert roomy.cache_forms(64, "int8_sim") == (True, False)
-    assert roomy.live_rows(np.array([3, 40]), 64) == {"rows_full": 2 * (4 + 41),
-                                                      "rows_window": 5 * (4 + 16)}
+    assert roomy.live_rows(np.array([3, 40]), 64) == {
+        "rows_full": 2 * (4 + 41), "rows_window": 5 * (4 + 16),
+        "rows_read_full": 2 * (4 + 41), "state_bytes": 0}  # each cache has one reader, no state

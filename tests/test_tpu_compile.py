@@ -387,6 +387,41 @@ def test_window_and_full_layers_prefill_chunk_leaves_the_cache_in_place(topo):
     assert max(_copied_bytes(text)) < ring.size * ring.dtype.itemsize
 
 
+def test_shared_cache_model_decode_step_copies_neither_the_table_nor_a_cache(
+        topo, on_chip_kernel):
+    """Phi-4-mini-flash-reasoning's widths, one layer of each kind, all
+    200,064 vocabulary rows, 64 slots x 4096 rows: the tied head contracts the
+    [200064, 2560] table where it lies (no transposed 1 GB copy a step), and
+    the pair-row caches, stored flat, are written by scatters and read by the
+    kernel under its three names with nothing as large as a ring re-laid —
+    stored [B, L, 10, 128] the chip keeps them L-minor and the kernel's
+    layout costs two copies of every cache a step (PERF.md §6, PR 39)."""
+    from tpudml.models import HybridLM
+    from tpudml.serve.engine import make_stateful_decode_step
+
+    slots, rows = 64, 4096
+    model = HybridLM(
+        vocab_size=200064, pattern="SDWDSDFDGDXD", embed_dim=2560, num_heads=40, head_dim=64,
+        attn_bias=True, differential=True, window=512, dense_dim=10240, ssm_inner=5120,
+        dt_rank=160, state_size=16, conv_kernel=4, norm="layer", tied=True, dtype=bf16)
+    assert model.cache_forms(rows, "bf16") == (True, True) and model.prefill_entries == 7
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(lambda: model.init_decode_cache(slots, rows, "bf16"))
+    assert caches[6].k.shape == (64, 4096 * 10, 1, 128) and caches[10] is None
+    assert caches[2].v.shape == (64, 512 * 10, 1, 128) and caches[0].ssm.shape == (64, 1, 16, 5120)
+    state = jax.ShapeDtypeStruct((3, slots), i32, sharding=one)
+    text = make_stateful_decode_step(model).lower(
+        _described(params, one), _described(caches, one), state).compile().as_text()
+    assert " scatter(" in text and " while(" not in text
+    calls = re.findall(r" custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    names = [re.search(r"/(decode_attn\w*)/pallas_call", c).group(1) for c in calls]
+    assert sorted(names) == ["decode_attn", "decode_attn_shared", "decode_attn_window"]
+    ring = caches[2].k
+    assert max(_copied_bytes(text)) < ring.size * ring.dtype.itemsize / 8
+    assert not re.search(r"\[(200064,2560|2560,200064)\]\{[^}]*\} (copy|transpose)\(", text)
+
+
 # ------------------------------------------------- across the four chips
 # What exists only on a mesh: the SPMD partitioner refuses a bare Mosaic
 # kernel, so under the GSPMD engines the kernels run per shard; and the

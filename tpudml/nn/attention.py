@@ -689,3 +689,228 @@ class MultiHeadAttention(Module):
             o = dot_product_attention(q, k, v, causal=True, q_offset=start)
         o = o.reshape(1, c, self._inner)
         return self._dense(params["out"], o), cache
+
+
+# ------------------------------------------------- differential attention
+
+
+def differential_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
+    k_pos: jax.Array, *, scale: float, window: int | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The two softmaxes of differential attention (Ye et al. 2024), by the
+    plain math and with the mask of :func:`attention_by_position`: q
+    [B, Tq, H, D] at ``q_pos`` over k, v [B, Tk, H/2, D] at ``k_pos`` ([Tk] or
+    [B, Tk]), or over the same rows stored a pair a row, in any shape of that
+    order ([B, Tk, H/4, 2D], or flat [B, Tk * H/4, 1, 2D]: K heads 2p and
+    2p + 1 side by side are one row, V heads likewise).
+
+    Differential head j of H/2 pairs query heads 2j (q1) and 2j + 1 (q2) and
+    reads K/V pair p = j // 2: q1 scores K head 2p, q2 K head 2p + 1, and
+    both weigh the pair's two V heads side by side (2D wide). Returns (A1,
+    A2), each [B, Tq, H/2, 2D]: ``softmax(q_i k_i^T * scale + mask) V_p``."""
+    b, tq, h, d = q.shape
+    tk, pairs = k_pos.shape[-1], h // 4
+    qg = q.reshape(b, tq, pairs, 2, 2, d)  # (pair, head of the pair, q1 | q2)
+    kg = k.reshape(b, tk, pairs, 2, d)  # (pair, k1 | k2)
+    vg = v.reshape(b, tk, pairs, 2 * d)
+    s = jnp.einsum("bqpjid,blpid->bpjiql", qg, kg,
+                   preferred_element_type=jnp.float32) * jnp.asarray(scale, jnp.float32)
+    qp = jnp.broadcast_to(q_pos, (b, tq))[:, :, None]
+    kp = jnp.broadcast_to(k_pos, (b, tk))[:, None, :]
+    mask = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        mask &= kp > qp - window
+    s = jnp.where(mask[:, None, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    o = jnp.einsum("bpjiql,blpe->bqpjie", p, vg)  # [B, Tq, pair, head, q1 | q2, 2D]
+    return (o[..., 0, :].reshape(b, tq, h // 2, 2 * d),
+            o[..., 1, :].reshape(b, tq, h // 2, 2 * d))
+
+
+@dataclass(frozen=True)
+class DifferentialAttention(Module):
+    """Causal differential attention without positions, with its serving
+    paths over a `tpudml.serve.cache.KVCache`:
+
+        o_j = RMSNorm_2D(A1_j - lambda * A2_j) * (1 - lambda_init)
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+        out = concat_j(o_j) @ W_o + b_o
+
+    with A1, A2 as :func:`differential_attention` gives them, ``num_heads``
+    query heads, half as many K/V heads, four learned D-vectors and the
+    norm's gain a layer. ``window`` W: a query sees the last W positions, its
+    own among them, and the cache is a ring of W rows. ``cross``: the layer
+    has a query projection only and reads the rows another layer cached
+    (its call is handed that layer's cache and writes nothing).
+
+    The cache decides how a K/V row is stored, and every path follows the
+    cache it is handed: [B, L, H/2, D], a head a row, or a pair a row, FLAT:
+    [B, L * H/4, 1, 2D], a token's H/4 pair-rows one after the other (every
+    cache function then takes positions times H/4: `_per`). At D = 64 a pair is
+    a 128-lane row, both fast paths of ``serve/cache.py`` hold, and the decode
+    step reads the cache with ``ops/decode_attn.py`` as it is: its H query
+    rows are ``[q1 | 0]`` and ``[0 | q2]`` (a zero lane adds nothing to
+    ``q . k``), four to a K/V row, each weighing the whole 2D-wide V row; the
+    subtraction, the norm and the scale follow the kernel. Flat, because ten
+    pairs are no whole sublane tile: [B, L, 10, 128] the chip stores L-minor
+    or pads to sixteen, and the kernel's layout then costs four copies of the
+    cache a step (PERF.md §6, PR 39). The kernel's name in a trace says whose
+    read it is: ``decode_attn`` (a full layer's own cache),
+    ``decode_attn_window`` (a ring), ``decode_attn_shared`` (a cross layer's
+    read of another's)."""
+
+    embed_dim: int
+    num_heads: int
+    head_dim: int
+    lambda_init: float
+    window: int | None = None
+    cross: bool = False
+    use_bias: bool = True
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.num_heads % 4:
+            raise ValueError(
+                f"num_heads {self.num_heads}: differential heads read K/V in "
+                "pairs, so the query heads come in fours")
+        if self.cross and self.window is not None:
+            raise ValueError("a cross layer has no ring of its own")
+
+    @property
+    def _scale(self) -> float:
+        return self.head_dim ** -0.5
+
+    def init(self, key):
+        kq, kk, kv, ko, kl = jax.random.split(key, 5)
+        d, inner = self.embed_dim, self.num_heads * self.head_dim
+        params = {"q": Dense(d, inner, self.use_bias, dtype=self.dtype).init(kq)[0],
+                  "out": Dense(inner, d, self.use_bias, dtype=self.dtype).init(ko)[0]}
+        if not self.cross:
+            kv_proj = Dense(d, inner // 2, self.use_bias, dtype=self.dtype)
+            params.update(k=kv_proj.init(kk)[0], v=kv_proj.init(kv)[0])
+        lam = 0.1 * jax.random.normal(kl, (4, self.head_dim), jnp.float32)
+        params.update(lambda_q1=lam[0], lambda_k1=lam[1], lambda_q2=lam[2],
+                      lambda_k2=lam[3],
+                      subln={"scale": jnp.ones((2 * self.head_dim,), self.dtype)})
+        return params, {}
+
+    # ------------------------------------------------------------ pieces
+
+    _dense = staticmethod(MultiHeadAttention._dense)
+
+    def _queries(self, params, x):
+        b, t, _ = x.shape
+        return self._dense(params["q"], x).reshape(b, t, self.num_heads, self.head_dim)
+
+    def _subln(self, params, x):
+        y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
+        return y * params["subln"]["scale"].astype(jnp.float32)
+
+    def _finish(self, params, a1, a2, dtype):
+        """A1, A2 [B, T, H/2, 2D] -> out [B, T, d]; float32 between."""
+        lam = (jnp.exp(jnp.sum(params["lambda_q1"] * params["lambda_k1"]))
+               - jnp.exp(jnp.sum(params["lambda_q2"] * params["lambda_k2"]))
+               + self.lambda_init)
+        o = self._subln(params, a1.astype(jnp.float32) - lam * a2.astype(jnp.float32))
+        o = (o * (1.0 - self.lambda_init)).astype(dtype)
+        return self._dense(params["out"], o.reshape(*o.shape[:2], -1))
+
+    def _attend(self, q, k, v, q_pos, k_pos):
+        return differential_attention(q, k, v, q_pos, k_pos, scale=self._scale,
+                                      window=self.window)
+
+    # ------------------------------------------------------------- paths
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        if self.cross:
+            raise ValueError("a cross layer reads another layer's rows: `forward`")
+        return self.forward(params, x), state
+
+    def forward(self, params, x, kv=None):
+        """The whole sequences x [B, T, d], no cache, over ``kv`` (default:
+        the layer's own `kv_rows`; a cross layer is given those it reads)."""
+        k, v = kv if kv is not None else self.kv_rows(params, x)
+        at = jnp.arange(x.shape[1])
+        return self._finish(params, *self._attend(self._queries(params, x), k, v, at, at),
+                            x.dtype)
+
+    def kv_rows(self, params, x, like=None):
+        """(k, v) of x [B, T, d], a head a row ([B, T, H/2, D]) or as the
+        cache buffer ``like`` stores rows (`_per`)."""
+        shape = (x.shape[1], self.num_heads // 2, self.head_dim) if like is None else (
+            x.shape[1] * self._per(like), *like.shape[2:])
+        return tuple(self._dense(params[n], x).reshape(x.shape[0], *shape) for n in "kv")
+
+    def _per(self, buffer) -> int:
+        """Cache rows a token: H/4 where a row is a pair (flat), else 1."""
+        return self.num_heads // 4 if buffer.shape[-1] == 2 * self.head_dim else 1
+
+    def apply_decode(self, params, cache, x, pos):
+        """One decode step: x [B, 1, d] at per-slot positions ``pos`` [B].
+        Writes the token's K/V rows (a ring: at ``pos % L``; a cross layer:
+        nothing), reads the cache, returns (out [B, 1, d], the cache)."""
+        from tpudml.serve.cache import (kernel_block, read_all, ring_positions,
+                                        write_token)
+
+        b = x.shape[0]
+        per = self._per(cache.k)
+        length = cache.max_len // per
+        ring = self.window is not None
+        q = self._queries(params, x)
+        if not self.cross:
+            k_new, v_new = self.kv_rows(params, x, cache.k)
+            cache = write_token(cache, k_new, v_new, (pos % length if ring else pos) * per)
+        block = per > 1 and (not ring or length == self.window) and kernel_block(
+            cache.kind, length, per, self.num_heads, cache.k.shape[-1], cache.v.shape[-1])
+        if block:
+            from tpudml.ops.decode_attn import decode_attn, kernel_interpret
+
+            zero = jnp.zeros_like(q[..., 0::2, :])
+            rows = jnp.stack([jnp.concatenate([q[..., 0::2, :], zero], axis=-1),
+                              jnp.concatenate([zero, q[..., 1::2, :]], axis=-1)],
+                             axis=3)  # [B, 1, H/2, q1 | q2, 2D]
+            name = ("decode_attn_shared" if self.cross
+                    else "decode_attn_window" if ring else "decode_attn")
+            o = decode_attn(rows.reshape(b, 1, self.num_heads, 2 * self.head_dim),
+                            cache.k, cache.v, pos, scale=self._scale, block=block,
+                            name=name, interpret=kernel_interpret(), kv_heads=per)
+            o = o.reshape(b, 1, self.num_heads // 2, 2, 2 * self.head_dim)
+            a1, a2 = o[..., 0, :], o[..., 1, :]
+        else:
+            k, v = read_all(cache, x.dtype)
+            k_pos = ring_positions(pos, length) if ring else jnp.arange(length)
+            a1, a2 = self._attend(q, k, v, pos[:, None], k_pos)
+        return self._finish(params, a1, a2, x.dtype), cache
+
+    def apply_prefill(self, params, cache, x, slot, start: int, n_real=None):
+        """Prefill one chunk of one slot: x [1, C, d] at positions
+        [start, start + C) (``start`` static), of which the first ``n_real``
+        are real (what a ring must know: `MultiHeadAttention.apply_prefill`).
+        Writes the chunk's K/V rows and attends over the slot's rows before
+        them and its own; a cross layer reads rows [0, start + C) of the
+        cache it is handed. Returns (out [1, C, d], the cache)."""
+        from tpudml.serve.cache import (read_ring_slot, read_slot_prefix,
+                                        write_chunk, write_ring_chunk)
+
+        c = x.shape[1]
+        per = self._per(cache.k)  # cache rows a token: positions times it
+        q = self._queries(params, x)
+        q_pos = start + jnp.arange(c)
+        if self.window is not None:
+            length = cache.max_len // per
+            k_new, v_new = self.kv_rows(params, x, cache.k)
+            k_old, v_old = read_ring_slot(cache, slot, start * per, x.dtype)
+            a1, a2 = self._attend(
+                q, jnp.concatenate([k_old, k_new], axis=1),
+                jnp.concatenate([v_old, v_new], axis=1), q_pos,
+                start - length + jnp.arange(length + c))
+            cache = write_ring_chunk(cache, k_new, v_new, slot, start * per,
+                                     (c if n_real is None else n_real) * per)
+            return self._finish(params, a1, a2, x.dtype), cache
+        if not self.cross:
+            cache = write_chunk(cache, *self.kv_rows(params, x, cache.k), slot, start * per)
+        k, v = read_slot_prefix(cache, slot, (start + c) * per, x.dtype)
+        a1, a2 = self._attend(q, k, v, q_pos, jnp.arange(start + c))
+        return self._finish(params, a1, a2, x.dtype), cache

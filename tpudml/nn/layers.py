@@ -357,6 +357,28 @@ class GatedMLP(Module):
 
 
 @dataclass(frozen=True)
+class GatedMemoryUnit(Module):
+    """A gated memory unit: ``(m * silu(x @ W_1)) @ W_2``, no bias, where
+    ``m`` [..., memory_dim] is what an earlier layer of the model made for the
+    same token (a state-space layer's scan output; `tpudml.nn.mamba.Mamba1`).
+    The layer keeps nothing between tokens."""
+
+    embed_dim: int
+    memory_dim: int
+    dtype: Any = jnp.float32
+
+    def init(self, key):
+        d, e = self.embed_dim, self.memory_dim
+        k1, k2 = jax.random.split(key)
+        return {"in_proj": {"kernel": _uniform_fan_in(k1, (d, e), d, self.dtype)},
+                "out_proj": {"kernel": _uniform_fan_in(k2, (e, d), e, self.dtype)}}, {}
+
+    def forward(self, params, x, m):
+        gate = jax.nn.silu(x @ params["in_proj"]["kernel"])
+        return (m.astype(x.dtype) * gate) @ params["out_proj"]["kernel"]
+
+
+@dataclass(frozen=True)
 class Sequential(Module):
     """Chain of modules; params/state are dicts keyed ``layer{i}``."""
 

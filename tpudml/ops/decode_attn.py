@@ -150,7 +150,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float, block: int,
 def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
                 scale: float | None = None, sink: jax.Array | None = None,
                 block: int | None = None, name: str = "decode_attn",
-                interpret: bool = False) -> jax.Array:
+                interpret: bool = False, kv_heads: int | None = None) -> jax.Array:
     """:func:`tpudml.nn.attention.decode_attention_grouped` as one kernel:
     q [B, 1, H, D] over the cache buffers k [B, L, Hkv, D] and v [B, L, Hkv,
     Dv] as stored, per-slot positions ``pos`` [B] -> [B, 1, H, Dv] in q's
@@ -158,18 +158,25 @@ def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
     ``scale`` defaults to ``D ** -0.5``; ``sink`` [H] float32 joins every
     head's denominator (`tpudml.nn.attention.attention_by_position`);
     ``name`` is the kernel's in a device trace (a window layer's ring reads
-    as ``decode_attn_window``)."""
+    as ``decode_attn_window``). With ``kv_heads`` given, k and v are the same
+    rows stored FLAT, [B, L * Hkv, 1, D] (row ``r * Hkv + h``): the matrix the
+    kernel reads, for a head count the chip would pad to its sublane tile
+    (ten heads to sixteen) or, to avoid that, store L-minor."""
     b, _, h, d = q.shape
-    length, kv_heads, dv = v.shape[1:]
+    flat = kv_heads is not None
+    if flat:
+        length, dv = v.shape[1] // kv_heads, v.shape[-1]
+    else:
+        length, kv_heads, dv = v.shape[1:]
     block = block or block_rows(length, kv_heads)
     if length % block or h % kv_heads:
         raise ValueError(
             f"decode_attn: {length} rows in blocks of {block}, {h} query "
             f"heads over {kv_heads}")
-    if kv_heads == 1:
+    if kv_heads == 1 or flat:
         # The chip keeps a size-1 head axis out of the tiles: [B, L, D].
-        k, v = k.reshape(b, length, d), v.reshape(b, length, dv)
-        k_spec, v_spec = (pl.BlockSpec((1, block, w), lambda i, j, pos: (i, j, 0))
+        k, v = k.reshape(b, length * kv_heads, d), v.reshape(b, length * kv_heads, dv)
+        k_spec, v_spec = (pl.BlockSpec((1, block * kv_heads, w), lambda i, j, pos: (i, j, 0))
                           for w in (d, dv))
     else:
         k_spec, v_spec = (pl.BlockSpec((1, block, kv_heads, w),

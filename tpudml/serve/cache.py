@@ -39,9 +39,18 @@ the decode step writes through ``write_token`` at ``pos % W``). A model
 gives each of its layers the shape it needs (``models/hybrid.py``).
 
 A second kind of per-slot state lives beside the K/V cache: the
-``RecurrentState`` of a state-space layer (end of this file). A model's
-per-layer cache tuple may hold both kinds, and ``None`` for a layer that
-keeps nothing between tokens.
+``RecurrentState`` of a state-space layer (end of this file), Mamba-2's
+``[B, H, P, N]`` or Mamba-1's ``[B, 1, N, E]`` in the same dataclass. A
+model's per-layer cache tuple may hold both kinds, and ``None`` for a layer
+that keeps nothing between tokens — also one that reads ANOTHER layer's
+cache (a cross layer: the tuple's entry of the layer that owns the cache is
+the only one, and only its owner writes it).
+
+A K/V head count that is no whole sublane tile (ten pair-rows) is stored
+FLAT, ``[B, L * Hkv, 1, D]``: every function here then takes rows, not
+tokens, and the caller multiplies its positions by ``Hkv``
+(`tpudml.nn.attention.DifferentialAttention`); ``kernel_block`` says how the
+kernel reads it.
 """
 
 from __future__ import annotations
@@ -183,13 +192,37 @@ def decode_kernel(kind: str, max_len: int, kv_heads: int, num_heads: int,
     120.9 ms and the kernel's 11.6 (PERF.md §6, PR 31). Everything else —
     MHA, the int8 and ``*_sim`` kinds, a ragged ``max_len`` — keeps the
     einsum. ``head_dim`` and ``v_dim`` are the widths as stored."""
-    from tpudml.ops.decode_attn import block_rows, kernel_interpret
+    from tpudml.ops.decode_attn import block_rows
 
     block = block_rows(max_len, kv_heads)
+    return (_kernel_reads(kind, kv_heads, num_heads, head_dim, v_dim)
+            and block % 16 == 0 and max_len % block == 0)
+
+
+def _kernel_reads(kind: str, kv_heads: int, num_heads: int, head_dim: int,
+                  v_dim: int | None) -> bool:
+    """:func:`decode_kernel` but for the row blocks."""
+    from tpudml.ops.decode_attn import kernel_interpret
+
     return (kernel_interpret() is not None and row_scatter(head_dim)
             and row_scatter(v_dim or head_dim)
-            and kv_heads < num_heads and kind in ("bf16", "f32")
-            and block % 16 == 0 and max_len % block == 0)
+            and kv_heads < num_heads and kind in ("bf16", "f32"))
+
+
+def kernel_block(kind: str, max_len: int, kv_heads: int, num_heads: int,
+                 head_dim: int, v_dim: int | None = None) -> int | None:
+    """:func:`decode_kernel` for a K/V head count that ``BLOCK_ROWS`` need
+    not be a multiple of (ten pair-rows: `DifferentialAttention`): the rows
+    of a slot a grid step of the kernel reads, or None where the cache keeps
+    the einsum. The fewest whole 16-row tiles that divide ``max_len`` and
+    with their heads fill ``BLOCK_ROWS`` (256 x 10 of 4096 or of a ring of
+    512), or the whole of a shorter cache."""
+    from tpudml.ops.decode_attn import BLOCK_ROWS
+
+    if not _kernel_reads(kind, kv_heads, num_heads, head_dim, v_dim):
+        return None
+    return next((rows for rows in range(16, max_len + 1, 16) if max_len % rows == 0
+                 and (rows * kv_heads >= BLOCK_ROWS or rows == max_len)), None)
 
 
 def _update_rows(buf: jax.Array, rows: jax.Array,
@@ -348,7 +381,10 @@ class RecurrentState:
     """One state-space layer's per-slot state."""
 
     conv: jax.Array  # [B, K-1, conv_dim]: the convolution's last K-1 inputs
-    ssm: jax.Array  # [B, H, P, N]: the recurrence's state
+    # The recurrence's state: [B, H, P, N] (Mamba-2: heads, head size, state
+    # size) or [B, 1, N, E] (Mamba-1: the E channels in the lanes, or the chip
+    # pads a 16-wide last axis to 128, eight times the bytes).
+    ssm: jax.Array
 
 
 def init_recurrent_state(batch: int, window: int, conv_dim: int, heads: int,

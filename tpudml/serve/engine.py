@@ -830,13 +830,18 @@ class ServingEngine:
                     chunks: int, shared_pages: int):
         """``serve/admit``: the host side of one request's prefill, opened
         once admission is certain. ``chunks`` counts the prefill programs
-        it launches (the draft's too under speculative decoding)."""
+        it launches (the draft's too under speculative decoding), and
+        ``trunk_prefilled`` the layers each of them runs: all of them,
+        unless the model's last layers keep nothing of a prompt token
+        (``prefill_entries``: models/hybrid.py)."""
         if self._spec is not None:
             chunks += len(range(0, prompt.size - 1, self.cfg.prefill_chunk))
         return span("admit", "serve", rid=req.rid, slot=slot,
                     prompt_len=int(prompt.size), chunks=chunks,
                     shared_pages=shared_pages,
-                    state_reset=int(self._stateful))
+                    state_reset=int(self._stateful),
+                    trunk_prefilled=getattr(self.model, "prefill_entries",
+                                            self.model.num_layers))
 
     def _admit_paged(self, slot: int, req: Request,
                      stats: RequestStats) -> tuple[int, int] | None:
@@ -1081,8 +1086,10 @@ class ServingEngine:
                     # ``state_slots``: slots whose recurrent state the step
                     # reads and writes back (a stateful model's active slots).
                     # A stateful model adds its caches' own counters: live
-                    # rows by layer kind (``rows_full``, ``rows_window``) and
-                    # the bytes allocated to each (``cache_bytes_*``).
+                    # rows by layer kind (``rows_full``, ``rows_window``;
+                    # ``rows_read_full``: times the layers that read them),
+                    # the recurrent ``state_bytes`` of the active slots, and
+                    # the bytes allocated to each kind (``cache_bytes_*``).
                     with span("dispatch", "serve", step=steps, active=n_active,
                               ahead=int(bool(in_flight)),
                               rows=int(pos[active].sum()),
