@@ -2,9 +2,10 @@
 
 Load-bearing property: the kernels are the same function as the reference
 ``dot_product_attention`` — forward (all block sizes, causal on/off,
-bfloat16) and gradients via every backward path: the one-pass kernel (a
-head resident in VMEM), the dQ and dK/dV kernels that stream tiles (the
-rule between them is ``_backward_plan``), and the custom_vjp
+bfloat16) in both its forms, a head resident in VMEM and K/V tiles streamed
+(the rule between them is ``_forward_plan``), and gradients via every
+backward path: the one-pass kernel (a head resident in VMEM), the dQ and
+dK/dV kernels that stream tiles (``_backward_plan``), and the custom_vjp
 reference-recompute fallback.
 """
 
@@ -17,7 +18,12 @@ import pytest
 
 from tpudml.models import TransformerLM
 from tpudml.nn.attention import dot_product_attention
-from tpudml.ops import attention_kernel, flash_attention, flash_block_grads
+from tpudml.ops import (
+    attention_kernel,
+    flash_attention,
+    flash_block_grads,
+    flash_forward_lse,
+)
 
 B, T, H, D = 2, 32, 4, 8
 
@@ -31,9 +37,31 @@ def qkv():
     )
 
 
+def _takes_resident(t, d, dtype, block_q, block_k, k_shift=0) -> bool:
+    forward = attention_kernel._forward_plan(t, d, dtype, block_q, block_k, k_shift)[0]
+    assert forward in (attention_kernel._forward_resident,
+                       attention_kernel._flash_forward)
+    return forward is attention_kernel._forward_resident
+
+
+FORWARD_FORMS = pytest.mark.parametrize(
+    "streamed", [False, True], ids=["by_rule", "streaming"])
+
+
+def _hold_forward(monkeypatch, streamed):
+    """``streamed``: hold the forward to the kernel that streams K/V tiles,
+    which long heads keep, at a shape whose rule is the resident one."""
+    if streamed:
+        monkeypatch.setattr(attention_kernel, "_resident_fits", lambda *a: False)
+
+
+@FORWARD_FORMS
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (32, 16), (8, 32)])
-def test_kernel_matches_reference(qkv, causal, block_q, block_k):
+def test_kernel_matches_reference(qkv, monkeypatch, causal, block_q, block_k,
+                                  streamed):
+    _hold_forward(monkeypatch, streamed)
+    assert _takes_resident(T, D, jnp.float32, block_q, block_k) != streamed
     q, k, v = qkv
     got = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k, interpret=True)
     want = dot_product_attention(q, k, v, causal=causal)
@@ -73,22 +101,28 @@ def test_gradients_match_reference(qkv):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-6)
 
 
+@FORWARD_FORMS
 @pytest.mark.parametrize(
     "t,block_q,block_k,causal",
     [
         (30, 16, 512, False),
         (30, 16, 512, True),
+        # 35 padded Q rows: the last Q tile's diagonal lies past the K tile
         (32, 5, 512, True),
         # Multiple K tiles WITH K padding: the padded-tail mask must apply
         # at global k positions across tiles (kj > 0).
         (30, 16, 8, False),
         (30, 16, 8, True),
-        (27, 8, 4, True),
+        (27, 8, 4, True),  # 4 x 7 tiles: more pairs than the resident form unrolls
     ],
 )
-def test_odd_lengths_pad_and_mask(qkv, t, block_q, block_k, causal):
+def test_odd_lengths_pad_and_mask(qkv, monkeypatch, t, block_q, block_k, causal,
+                                  streamed):
     """Any T works via pad-and-mask (never by shrinking the MXU block):
     padded keys get no attention mass, padded queries are sliced off."""
+    _hold_forward(monkeypatch, streamed)
+    assert _takes_resident(t, D, jnp.float32, block_q, block_k) == (
+        not streamed and (t, block_q, block_k) != (27, 8, 4))
     q, k, v = (a[:, :t] for a in qkv)
     got = flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k, interpret=True
@@ -134,19 +168,24 @@ BACKWARD_TILINGS = [
 ]
 
 
-@pytest.mark.parametrize("streamed", [False, True], ids=["by_rule", "two_kernels"])
+@pytest.mark.parametrize("held", [None, "_one_pass_fits", "_resident_fits"],
+                         ids=["by_rule", "two_kernels", "streaming_forward"])
 @pytest.mark.parametrize("t,block_q,block_k,causal,one_pass", BACKWARD_TILINGS)
 def test_blocked_backward_matches_reference(qkv, monkeypatch, t, block_q, block_k,
-                                            causal, one_pass, streamed):
+                                            causal, one_pass, held):
     """The flash backward must reproduce reference gradients across
-    multi-tile loops, odd lengths, and causal skipping: in the form the rule
-    gives the shape (the one-pass kernel for all but one), and held to the
-    dQ and dK/dV kernels, which long sequences keep."""
-    if streamed:
-        monkeypatch.setattr(attention_kernel, "_one_pass_fits", lambda *a: False)
+    multi-tile loops, odd lengths, and causal skipping: in the forms the
+    rules give the shape (the resident forward and the one-pass backward for
+    all but one), with the backward held to the dQ and dK/dV kernels, which
+    long sequences keep, and with the forward held to its streaming kernel:
+    either forward's lse feeds either backward."""
+    if held:
+        monkeypatch.setattr(attention_kernel, held, lambda *a: False)
     q, k, v = (a[:, :t] for a in qkv)
     assert _takes_one_pass(t, D, q.dtype, block_q, block_k) == (
-        one_pass and not streamed)
+        one_pass and held != "_one_pass_fits")
+    assert _takes_resident(t, D, q.dtype, block_q, block_k) == (
+        one_pass and held != "_resident_fits")
     got, want = _flash_and_reference_grads(
         q, k, v, causal, block_q=block_q, block_k=block_k)
     for g, r in zip(got, want):
@@ -179,6 +218,86 @@ def test_one_pass_backward_at_head_widths(t, causal, d, dtype, tol):
         assert g.dtype == dtype
         err = np.linalg.norm(np.asarray(g, np.float32) - np.asarray(r))
         assert err <= tol * np.linalg.norm(np.asarray(r))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 0.01)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t,causal", [(1024, True), (600, True), (600, False)])
+def test_resident_forward_at_head_widths(t, causal, d, dtype, tol):
+    """Head 64 and 128 at the default tile (2 x 2 of 512 rows), T a multiple
+    of it and not (padded keys masked, padded rows cut), bf16 and float32:
+    the output against the float32 reference, and output and lse against the
+    streaming kernel's at the same tiles (the same operations on the same
+    tiles in the same order: a rounding of the CPU's matmul apart here)."""
+    rng = np.random.default_rng(d + t)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, t, 1, d)), dtype) for _ in range(3))
+    forward, block_q, block_k = attention_kernel._forward_plan(t, d, dtype, None, None)
+    assert forward is attention_kernel._forward_resident
+    assert block_q == block_k == attention_kernel._RESIDENT_TILE
+    out, lse = forward(q, k, v, causal, block_q, block_k, True)
+    want = dot_product_attention(*(a.astype(jnp.float32) for a in (q, k, v)),
+                                 causal=causal)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    assert lse.shape == (1, 1024, 1)
+    err = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(want))
+    assert err <= tol * np.linalg.norm(np.asarray(want))
+    s_out, s_lse = attention_kernel._flash_forward(
+        q, k, v, causal, block_q, block_k, True)
+    gap = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(s_out, np.float32))
+    assert gap <= tol * np.linalg.norm(np.asarray(want))
+    np.testing.assert_allclose(np.asarray(lse[:, :t]), np.asarray(s_lse[:, :t]),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize(
+    "t,d,dtype,block_q,block_k,k_shift,resident",
+    [
+        (1024, 64, jnp.bfloat16, None, None, 0, True),    # gpt2-medium.pretrain-1k
+        (512, 64, jnp.bfloat16, None, None, 0, True),
+        (2048, 64, jnp.bfloat16, None, None, 0, True),    # 16 pairs
+        (2048, 128, jnp.bfloat16, None, None, 0, True),   # the chip smoke's row
+        (2048, 128, jnp.float32, None, None, 0, True),    # 13.6 MB of 16.8
+        (2560, 64, jnp.bfloat16, None, None, 0, False),   # 25 pairs to unroll
+        (4096, 128, jnp.bfloat16, None, None, 0, False),
+        (8192, 128, jnp.bfloat16, None, None, 0, False),  # starcoderbase-1b
+        (1024, 64, jnp.bfloat16, 128, 128, 0, False),     # 64 pairs to unroll
+        (2048, 64, jnp.bfloat16, 1024, 1024, 0, True),
+        (4096, 64, jnp.bfloat16, 1024, 1024, 0, False),   # 16 pairs; head + score tile 23 MB
+        (8192, 128, jnp.bfloat16, 2048, 2048, 0, False),  # 16 pairs; the head alone 25 MB
+        (1024, 64, jnp.bfloat16, None, None, 1, False),   # a ring block's shifted diagonal
+        (32, 8, jnp.float32, 8, 8, 0, True),
+    ],
+)
+def test_forward_form_follows_the_shape(t, d, dtype, block_q, block_k, k_shift,
+                                        resident):
+    """The rule itself: which form a (T, head dim, dtype, tiles, shift)
+    takes, and that a tile left open takes that form's default."""
+    _, bq, bk = attention_kernel._forward_plan(t, d, dtype, block_q, block_k, k_shift)
+    assert _takes_resident(t, d, dtype, block_q, block_k, k_shift) == resident
+    if block_q is None:
+        (stream_bq, _), stream_bk = attention_kernel._default_blocks(d)
+        tile = attention_kernel._RESIDENT_TILE
+        assert (bq, bk) == ((tile, tile) if resident else (stream_bq, stream_bk))
+    else:
+        assert (bq, bk) == (block_q, block_k)
+
+
+def test_forward_lse_keeps_the_streaming_kernel(qkv):
+    """Ring attention's and the serving prefill's per-block entry point
+    stays on the streaming kernel at a shape whose whole-sequence forward is
+    resident (the serving programs must not change), and both name their
+    kernel."""
+    q, k, v = qkv
+    assert _takes_resident(T, D, q.dtype, None, None)
+
+    def names(fn):
+        return set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)())))
+
+    assert names(lambda: flash_forward_lse(q, k, v, causal=True, interpret=True)) == {
+        "flash_fwd"}
+    assert names(lambda: flash_attention(q, k, v, causal=True, interpret=True)) == {
+        "flash_fwd_resident"}
 
 
 @pytest.mark.parametrize(

@@ -277,8 +277,9 @@ def _flash(q, k, v):
 
 
 def _flash_streamed(q, k, v):
-    """More tile pairs than the one-pass backward unrolls: the shape takes
-    the dQ and dK/dV kernels, as a head too long for VMEM does."""
+    """More tile pairs than the resident forward and the one-pass backward
+    unroll: the shape takes the kernels that stream tiles (the forward's,
+    the dQ and dK/dV pair), as a head too long for VMEM does."""
     from tpudml.ops.attention_kernel import flash_attention
 
     return flash_attention(q, k, v, causal=True, interpret=True, block_q=2,
@@ -311,8 +312,9 @@ _HEAD = [jnp.ones((16, 32), jnp.float32), jnp.ones((32, 256), jnp.float32),
          jnp.zeros((16,), jnp.int32)]
 
 KERNELS = [
-    ("flash-forward", _flash, _QKV, None, ["flash_fwd"]),
-    ("flash-gradient", _flash, _QKV, (0, 1, 2), ["flash_fwd", "flash_bwd"]),
+    ("flash-forward", _flash, _QKV, None, ["flash_fwd_resident"]),
+    ("flash-forward-streamed", _flash_streamed, _QKV, None, ["flash_fwd"]),
+    ("flash-gradient", _flash, _QKV, (0, 1, 2), ["flash_fwd_resident", "flash_bwd"]),
     ("flash-gradient-streamed", _flash_streamed, _QKV, (0, 1, 2),
      ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     ("ln-forward", _ln, _ROWS, None, ["ln_fwd"]),

@@ -1,30 +1,30 @@
 """Fused attention kernels (Pallas, TPU) — flash-attention tiling, both
-directions.
+directions, each one algorithm at two tilings chosen from T, head dim and
+dtype alone (``_forward_plan``, ``_backward_plan``).
 
-Forward: softmax(QKᵀ)V with BOTH operands blocked — the [T, T] score
-matrix never exists. K/V stream through VMEM one [block_k, D] tile at a
-time into an online softmax held in scratch (running max m, normalizer l,
-f32 output accumulator, rescaled by exp(m_prev − m_new) per tile), and the
-row log-sum-exp is emitted as a residual. Per-step VMEM is
-O(block_q·D + block_k·D), independent of T.
+Forward: softmax(QKᵀ)V with BOTH operands blocked — the [T, T] score matrix
+never exists: an online softmax over K/V tiles (running max m, normalizer
+l, f32 accumulator, rescaled by exp(m_prev − m_new) a tile); the row
+log-sum-exp is emitted as a residual.
+- resident (``flash_fwd_resident``): a head's Q, K, V are one VMEM block, the
+  tile loops run inside the kernel, m, l and the accumulator are values;
+- streaming (``flash_fwd``): K/V tiles stream through the grid into scratch,
+  VMEM O(block_q·D + block_k·D) whatever T: heads too long to sit in VMEM,
+  ring attention's and the serving prefill's ``flash_forward_lse``.
 
-Backward: the flash recipe — no O(T²) transient. With the forward's lse
-and Δ = rowsum(dO ⊙ O), each visible score tile is recomputed: p =
-exp(s − lse), dp = dO·Vᵀ, ds = p ⊙ (dp − Δ); dV += pᵀ·dO, dK += scale ·
-dsᵀ·Q, dQ += scale · ds·K, float32 accumulators. One algorithm at two
-tilings, chosen from T, head dim and dtype alone (``_backward_plan``):
-- one pass (``flash_bwd``): a head's Q, K, V, dO, lse, Δ are one VMEM
-  block, the tile loop runs inside the kernel and every tile is recomputed
-  once for all three products (GPT-2's T 1024 × D 64: 4.1× the two below);
-- two kernels (``flash_bwd_dq``, K innermost; ``flash_bwd_dkv``, Q
-  innermost) stream tiles and recompute each twice: heads too long to sit
-  in VMEM, and ring attention's per-block ``flash_block_grads``.
-Causal runs skip the tiles above the diagonal everywhere (~2× fewer
-FLOPs); Q and K pad independently to their tile multiples and masks use
-global positions, so any T works. ``blocked_backward=False`` recomputes
-through the reference math under vjp (debugging aid).
-Validated against the reference math on a real v5e chip; on non-TPU
-platforms ``flash_attention`` dispatches to the reference math unless
+Backward: the flash recipe — no O(T²) transient. With the forward's lse and
+Δ = rowsum(dO ⊙ O), each visible score tile is recomputed: p = exp(s − lse),
+dp = dO·Vᵀ, ds = p ⊙ (dp − Δ); dV += pᵀ·dO, dK += scale · dsᵀ·Q, dQ +=
+scale · ds·K, float32 accumulators.
+- one pass (``flash_bwd``): a head's Q, K, V, dO, lse, Δ are one VMEM block
+  and every tile is recomputed once for all three products;
+- two kernels (``flash_bwd_dq``, ``flash_bwd_dkv``) stream tiles and
+  recompute each twice: long heads, ring attention's ``flash_block_grads``.
+Causal runs skip the tiles above the diagonal everywhere (~2× fewer FLOPs); Q
+and K pad independently to their tile multiples and masks use global
+positions, so any T works. ``blocked_backward=False`` recomputes through the
+reference math under vjp (debugging aid). On non-TPU platforms
+``flash_attention`` dispatches to the reference math unless
 ``interpret=True`` forces the Pallas interpreter (tests).
 """
 
@@ -513,21 +513,100 @@ def _backward_one_pass(qf, kf, vf, dof, lse, delta, b, h, t, d, causal,
     )(qf, kf, vf, dof, lse, delta)
 
 
+# ------------------------------------------- forward, a head resident in VMEM
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
+                         block_q, block_k, t_valid):
+    """``_fwd_kernel``'s online softmax over one (batch, head) resident in
+    VMEM: the loops over the visible (Q tile, K tile) pairs run here,
+    unrolled (static bounds: the pairs above the causal diagonal do not
+    exist), and a Q tile's running max, normaliser and float32 accumulator
+    are values carried across its K tiles, not scratch read and rescaled a
+    grid step; O and lse are written once a Q tile. K tile 0 opens the
+    state, which is what ``_fwd_kernel`` computes there from m = -inf,
+    l = 0 (every row sees key 0 in it: no ``k_shift`` here)."""
+    nq = q_ref.shape[1] // block_q
+    nk = k_ref.shape[1] // block_k
+    nn = (((1,), (0,)), ((), ()))
+    for qi in range(nq):
+        qs = slice(qi * block_q, (qi + 1) * block_q)
+        q = q_ref[0, qs, :]
+        # K tiles up to the diagonal of the tile's last row (a padded Q row's
+        # may lie past the last K tile)
+        last = (qi + 1) * block_q - 1
+        for kj in range(min(nk, last // block_k + 1) if causal else nk):
+            ks = slice(kj * block_k, (kj + 1) * block_k)
+            s = _scores(
+                q, k_ref[0, ks, :], qi, kj, scale=scale,
+                # a tile wholly below the diagonal needs no causal mask
+                causal=causal and qi * block_q < (kj + 1) * block_k - 1,
+                block_q=block_q, block_k=block_k, t_valid=t_valid, nk=nk,
+            )
+            v = v_ref[0, ks, :]
+            m_tile = jnp.max(s, axis=-1, keepdims=True)
+            m_new = m_tile if kj == 0 else jnp.maximum(m, m_tile)
+            p = jnp.exp(s - m_new)
+            l_tile = jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, nn, preferred_element_type=jnp.float32)
+            if kj == 0:
+                l, acc = l_tile, pv
+            else:
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + l_tile
+                acc = acc * alpha + pv
+            m = m_new
+        o_ref[0, qs, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, qs, :] = m + jnp.log(l)
+
+
+def _forward_resident(q, k, v, causal, block_q, block_k, interpret):
+    """``_flash_forward`` as one pallas_call, a (batch, head) a program:
+    (out [B,T,H,D], lse [B·H, t_pad_q, 1] f32)."""
+    b, t, h, d = q.shape
+    block_q, block_k, t_pad_q, t_pad_k = _plan(t, block_q, block_k)
+    (qf,) = _fold_pad((q,), b, h, t, d, t_pad_q)
+    kf, vf = _fold_pad((k, v), b, h, t, d, t_pad_k)
+    head_q = pl.BlockSpec((1, t_pad_q, d), lambda i: (i, 0, 0))
+    head_k = pl.BlockSpec((1, t_pad_k, d), lambda i: (i, 0, 0))
+    rows = pl.BlockSpec((1, t_pad_q, 1), lambda i: (i, 0, 0))
+    out, lse = pl.pallas_call(
+        partial(
+            _resident_fwd_kernel, scale=1.0 / (d ** 0.5), causal=causal,
+            block_q=block_q, block_k=block_k, t_valid=t,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(qf.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, t_pad_q, 1), jnp.float32),
+        ],
+        grid=(b * h,),
+        in_specs=[head_q, head_k, head_k],
+        out_specs=[head_q, rows],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ONE_PASS_VMEM_LIMIT),
+        name="flash_fwd_resident",
+        interpret=interpret,
+    )(qf, kf, vf)
+    return _unfold(out, b, h, t, d), lse
+
+
 # ------------------------------------------------------------- dispatch
 
 
 # Measured-best default tiles by head dim (v5e, T=1024 sweeps) for the
 # kernels that stream tiles — the forward and the two-kernel backward:
 # - forward wants the largest Q tile that fits VMEM (fewer grid
-#   programs, bigger MXU ops: 0.43 vs 0.71 ms/layer at dh=64 for
-#   (512,512) vs (128,512));
+#   programs, bigger MXU ops; 8,192 tokens a layer at (512,512): 0.83 ms
+#   at dh=64, 0.74 a layer inside gpt2-medium's step);
 # - the dQ and dK/dV kernels carry more scratch/live values per program
 #   and prefer smaller Q tiles (8,192 tokens a layer: 3.31 ms at dh=64,
 #   T=1024; 4.61 ms at dh=128, T=8192);
 # - at dh>=128 (full-lane tiles) larger K blocks win in BOTH directions
-#   (fwd 0.067 ms at bk=1024 vs 0.131 at 512; bwd (256,1024) 0.56 ms vs
-#   (128,512) 0.90 ms per layer).
-# The one-pass backward has its own tile (``_ONE_PASS_TILE``).
+#   (fwd 0.61 ms at bk=1024 vs 0.83 at 512, T=2048 1.00 vs 1.37; bwd
+#   (256,1024) 0.56 ms vs (128,512) 0.90 ms per layer).
+# The resident forms have their own tiles (``_RESIDENT_TILE``,
+# ``_ONE_PASS_TILE``).
 def _default_blocks(d: int) -> tuple[tuple[int, int], int]:
     """((fwd_block_q, bwd_block_q), block_k) by head dim."""
     if d >= 128:
@@ -582,14 +661,72 @@ def _backward_plan(t: int, d: int, dtype, block_q: int | None,
             *_plan(t, block_q or default_bq, block_k or default_bk))
 
 
+# The forward with a head resident in VMEM against the kernel that streams
+# K/V tiles: one algorithm at two tilings, chosen as the backward's are. The
+# head must fit — what the pipeline double-buffers (Q, K, V in; O, lse out),
+# minor dims padded to 128 lanes, plus a score tile's s and p: 5.8 MB at
+# T 1024 x D 64 bf16, 8.9 MB at T 2048 — and the unrolled tile loop must stay
+# short. Measured on both sides (v5e, 8,192 tokens a layer, bf16, ms:
+# streaming at ``_default_blocks`` / resident at 512): causal, D 64 at T 512
+# 0.583 / 0.244, T 1024 0.833 / 0.326, T 2048 1.374 / 0.514; D 128 at T 1024
+# 0.608 / 0.325, T 2048 0.999 / 0.513; not causal, D 64 at T 1024 0.963 /
+# 0.410, D 128 at T 2048 (16 pairs) 1.094 / 0.802; float32, D 64 at T 1024
+# 1.002 / 0.431, D 128 0.652 / 0.440. Past 16 pairs the resident form still
+# runs faster and costs more to build than it is worth: T 2560 (25 pairs)
+# 1.667 / 0.606 causal, 1.639 / 0.971 not, 3.3 and 10.2 s to compile; at
+# T 3072 not causal and T 4096 (36 visible pairs) the chip's compiler refuses
+# it (40 MB of scoped VMEM under the 32 MiB limit); T 4096 at 256-row tiles
+# 2.441 / 0.830 after 15.6 s. Tiles at T 512 / 1024 / 2048, D 64, causal:
+# 512 0.244 / 0.326 / 0.514, 256 0.289 / 0.316 / 0.474, (256, 512) 0.255 /
+# 0.327 / 0.508, 128 0.270 / 0.345 / 0.908, (1024, 512) - / 0.410 / 0.601;
+# not causal at T 1024, 512 0.410, 256 0.458: 512, which is also the tile
+# the backward pads lse to.
+_RESIDENT_TILE = 512
+_RESIDENT_MAX_PAIRS = 16
+
+
+def _resident_fits(block_q: int, block_k: int, t_pad_q: int, t_pad_k: int,
+                   d: int, dtype) -> bool:
+    itemsize = jnp.dtype(dtype).itemsize
+    lanes = _round_up(d, 128)
+    pipelined = 2 * (t_pad_q * (2 * lanes * itemsize + 128 * 4)  # Q, O; lse
+                     + t_pad_k * 2 * lanes * itemsize)
+    tile = block_q * block_k * (4 + 4 + itemsize)  # s, p; p as the operand
+    pairs = (t_pad_q // block_q) * (t_pad_k // block_k)
+    return (pairs <= _RESIDENT_MAX_PAIRS
+            and pipelined + tile <= _ONE_PASS_VMEM_BUDGET)
+
+
+def _forward_plan(t: int, d: int, dtype, block_q: int | None,
+                  block_k: int | None, k_shift: int = 0):
+    """(forward, block_q, block_k): which form runs (``_forward_resident``
+    or ``_flash_forward``) at which tiles; a tile the caller left open takes
+    that form's measured best. A shifted diagonal (ring attention's blocks)
+    streams: the resident kernel has none."""
+    tiles = (block_q or _RESIDENT_TILE, block_k or _RESIDENT_TILE)
+    if k_shift == 0 and _resident_fits(*_plan(t, *tiles), d, dtype):
+        return (_forward_resident, *tiles)
+    (default_bq, _), default_bk = _default_blocks(d)
+    return _flash_forward, block_q or default_bq, block_k or default_bk
+
+
+def _planned_forward(q, k, v, causal, block_q, block_k, interpret):
+    """The whole-sequence forward in the form its shape takes."""
+    forward, block_q, block_k = _forward_plan(
+        q.shape[1], q.shape[3], q.dtype, block_q, block_k)
+    return forward(q, k, v, causal, block_q, block_k, interpret)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, block_q, block_k, interpret, blocked_backward):
-    out, _ = _flash_forward(q, k, v, causal, block_q[0], block_k[0], interpret)
+    out, _ = _planned_forward(
+        q, k, v, causal, block_q[0], block_k[0], interpret)
     return out
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, blocked_backward):
-    out, lse = _flash_forward(q, k, v, causal, block_q[0], block_k[0], interpret)
+    out, lse = _planned_forward(
+        q, k, v, causal, block_q[0], block_k[0], interpret)
     res = (q, k, v, out, lse) if blocked_backward else (q, k, v)
     return out, res
 
@@ -630,10 +767,10 @@ def flash_attention(
 
     ``block_q``: one int for both directions, or a (forward, backward)
     pair; ``block_q``/``block_k`` default (None) to the measured-best
-    tiles: the forward's by head dim (``_default_blocks``), the
-    backward's by the form its shape takes (``_backward_plan``: one pass
-    over a head resident in VMEM, or the dQ and dK/dV kernels). ``_plan``
-    still caps every block at the padded T.
+    tiles of the form each direction's shape takes (``_forward_plan``,
+    ``_backward_plan``: one pass over a head resident in VMEM, or the
+    kernels that stream tiles). ``_plan`` still caps every block at the
+    padded T.
 
     Under a GSPMD engine (an active ``parallel.sharding.KernelLayout``)
     the call runs per shard of batch and heads: the SPMD partitioner
@@ -654,14 +791,9 @@ def _flash_attention_local(q, k, v, causal, block_q, block_k, interpret,
         if jax.default_backend() != "tpu":
             return dot_product_attention(q, k, v, causal=causal)
         interpret = False
-    # (forward, backward) tiles; a backward tile left None is chosen with
-    # the backward's form (``_backward_plan``).
-    (fwd_bq, _), fwd_bk = _default_blocks(q.shape[-1])
-    if block_q is None:
-        bq = (fwd_bq, None)
-    elif isinstance(block_q, int):
-        bq = (block_q, block_q)
-    else:
-        bq = tuple(block_q)
-    bk = (fwd_bk, None) if block_k is None else (block_k, block_k)
+    # (forward, backward) tiles; a tile left None is chosen with its
+    # direction's form (``_forward_plan``, ``_backward_plan``).
+    pair = block_q is not None and not isinstance(block_q, int)
+    bq = tuple(block_q) if pair else (block_q, block_q)
+    bk = (block_k, block_k)
     return _flash(q, k, v, causal, bq, bk, interpret, blocked_backward)
