@@ -1,6 +1,10 @@
 """tpudml.obs — the unified observability layer (docs/OBSERVABILITY.md).
 
 - :mod:`tpudml.obs.tracer`    — structured spans → Perfetto ``trace.json``.
+- :mod:`tpudml.obs.passlog`   — the pass log: what every run of the serving
+  and training loops keeps of its passes with no ``Tracer`` installed and no
+  profiler session (a fixed-size row a pass, the slow ones whole);
+  ``last_pass_log("serve" | "train")`` after the run.
 - :mod:`tpudml.obs.stepstats` — in-graph :class:`StepStats` telemetry.
 - :mod:`tpudml.obs.convert`   — serve event log → trace spans (pure).
 - :mod:`tpudml.obs.drift`     — static-vs-measured drift monitor
@@ -9,6 +13,7 @@
 """
 
 from tpudml.obs.convert import serve_trace_events, write_serve_trace
+from tpudml.obs.passlog import PassLog, last_pass_log, pass_log
 from tpudml.obs.stepstats import StepStats, make_step_stats
 from tpudml.obs.tracer import (
     NULL_SPAN,
@@ -29,6 +34,7 @@ from tpudml.obs.tracer import (
 __all__ = [
     "NULL_SPAN",
     "NULL_TRACER",
+    "PassLog",
     "TRACE_SCHEMA_VERSION",
     "Span",
     "StepStats",
@@ -36,8 +42,10 @@ __all__ = [
     "chrome_trace_doc",
     "dump_trace",
     "get_tracer",
+    "last_pass_log",
     "make_step_stats",
     "merge_chrome_traces",
+    "pass_log",
     "serve_trace_events",
     "set_tracer",
     "span",

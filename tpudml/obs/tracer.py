@@ -22,10 +22,14 @@ fixed event log produces byte-identical ``trace.json`` — the property
 the serving-trace golden tests pin (events carry the engine's virtual
 clock, not wall time).
 
-Disabled tracers record NOTHING: ``Tracer(enabled=False).span(...)``
-returns the bare annotation and allocates no :class:`Span` (the
-module-level ``SPANS_ALLOCATED`` counter lets tests assert this), so the
-off position costs one inert annotation (under a microsecond) per span.
+A disabled tracer records no :class:`Span`: ``Tracer(enabled=False).span(...)``
+returns the bare annotation (the module-level ``SPANS_ALLOCATED`` counter
+lets tests assert this), so the off position costs one inert annotation
+(about a microsecond) per span. That is not all that is kept with no
+``Tracer`` installed: while ``ServingEngine.run`` or ``train_loop`` runs,
+the spans of its loop (``serve/...``, ``train/...``) also feed that run's
+pass log (:mod:`tpudml.obs.passlog`: a fixed-size row a pass, the longest
+passes whole), whichever tracer is ambient.
 """
 
 from __future__ import annotations
@@ -39,6 +43,8 @@ from pathlib import Path
 from typing import Iterator
 
 import jax
+
+from tpudml.obs import passlog
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -91,11 +97,11 @@ class _RecordedSpan:
     """The enabled path of :meth:`Tracer.span`: the profiler annotation
     plus a :class:`Span` in the tracer when the region closes."""
 
-    __slots__ = ("_tracer", "_annotation", "_name", "_cat", "_sync", "_args", "_t0")
+    __slots__ = ("_tracer", "_annotation", "_name", "_cat", "_args", "_t0")
 
-    def __init__(self, tracer, annotation, name, cat, sync, args):
+    def __init__(self, tracer, annotation, name, cat, args):
         self._tracer, self._annotation = tracer, annotation
-        self._name, self._cat, self._sync, self._args = name, cat, sync, args
+        self._name, self._cat, self._args = name, cat, args
 
     def set_metadata(self, **args) -> None:
         """Counters known only once the region has run (the annotation's
@@ -110,8 +116,6 @@ class _RecordedSpan:
 
     def __exit__(self, *exc):
         tracer = self._tracer
-        if self._sync is not None:
-            jax.block_until_ready(self._sync)
         ts_us = int((self._t0 - tracer._t0) * 1e6)
         dur_us = int((tracer._clock() - self._t0) * 1e6)
         tracer._record(Span(self._name, self._cat, ts_us, dur_us, "X",
@@ -132,9 +136,7 @@ class Tracer:
 
     Nesting is positional (Chrome complete events nest by containment per
     ``tid``); each OS thread gets its own track, numbered densely in
-    first-seen order. ``sync=`` values are blocked on before a span
-    closes (``jax.block_until_ready``), charging async-dispatched XLA
-    work to the span that launched it.
+    first-seen order.
     """
 
     def __init__(self, enabled: bool = True, clock=time.perf_counter):
@@ -162,16 +164,19 @@ class Tracer:
         with self._lock:
             self.events.append(span)
 
-    def span(self, name: str, cat: str = "host", sync=None, args: dict | None = None):
+    def span(self, name: str, cat: str = "host", args: dict | None = None):
         """Context manager around a host region: a profiler annotation
         ``tpudml:<cat>/<name>`` carrying ``args`` (ints, floats or short
         strings) always, and a complete span in this tracer when it is
-        enabled. Disabled: the bare annotation, no :class:`Span`."""
-        annotation = jax.profiler.TraceAnnotation(
+        enabled. Disabled: the bare annotation, no :class:`Span`. Either
+        way a span of a loop whose pass log is open on this thread feeds
+        that log (:mod:`tpudml.obs.passlog`)."""
+        region = jax.profiler.TraceAnnotation(
             f"{ANNOTATION_PREFIX}{cat}/{name}", **(args or {}))
-        if not self.enabled:
-            return annotation
-        return _RecordedSpan(self, annotation, name, cat, sync, args)
+        if self.enabled:
+            region = _RecordedSpan(self, region, name, cat, args)
+        log = passlog.active(cat)
+        return region if log is None else passlog.LoggedSpan(log, region, name, args)
 
     def instant(self, name: str, cat: str = "host", args: dict | None = None,
                 ts_us: int | None = None) -> None:

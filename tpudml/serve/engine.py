@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpudml.capabilities import CompositionError, reject
+from tpudml.obs.passlog import pass_log
 from tpudml.obs.tracer import span
 from tpudml.ops.decode_head import fused_decode_head, fused_decode_head_int8
 from tpudml.serve.cache import KINDS, decode_kernel, row_scatter
@@ -411,6 +412,10 @@ class ServeReport:
     busy_slot_steps: int = 0  # Σ over steps of active-slot count
     slots: int = 0  # engine slot count (occupancy denominator)
     pool_stats: dict | None = None  # paged only: prefix hits/evictions
+    # The run's pass log, summed up (obs/passlog.py ``PassLog.summary``):
+    # per class of pass its count, p50 / p99 / max ms, and the longest
+    # passes whole with the evidence that places a stall.
+    passes: dict | None = None
 
     @property
     def generated_tokens(self) -> int:
@@ -472,7 +477,8 @@ class ServeReport:
         + one decode step). Over every request the loop staged, the wait
         before admission in its two parts: lateness (arrival → staged:
         the loop was busy in a step and had not looked yet) and queueing
-        (staged → admission start)."""
+        (staged → admission start). And of the loop's steady passes (one
+        decode step fetched, nobody admitted: the pass log) p50 and max."""
         gaps, e2e, ttft = [], [], []
         late = [s.staged - s.arrival for s in self.requests.values()
                 if s.staged is not None]
@@ -491,6 +497,10 @@ class ServeReport:
         def pct(xs, q):
             return float(np.percentile(np.asarray(xs), q)) if xs else float("nan")
 
+        def steady(key):
+            ms = self.passes["classes"]["steady"][key] if self.passes else None
+            return float("nan") if ms is None else ms * 1e-3
+
         return {
             "per_token_p50_s": pct(gaps, 50),
             "per_token_p99_s": pct(gaps, 99),
@@ -502,6 +512,8 @@ class ServeReport:
             "stage_lateness_p99_s": pct(late, 99),
             "queue_wait_p50_s": pct(queued, 50),
             "queue_wait_p99_s": pct(queued, 99),
+            "steady_pass_p50_s": steady("p50_ms"),
+            "steady_pass_max_s": steady("max_ms"),
         }
 
 
@@ -941,7 +953,16 @@ class ServingEngine:
         is dropped, and the slot frees one step later. With a lookahead of
         0 (speculative, paged) a pass fetches and commits the step it
         dispatched.
+
+        Every run keeps a pass log (``tpudml.obs.passlog``, fed by the
+        loop's spans): its summary is ``ServeReport.passes``.
         """
+        with pass_log("serve") as passes:
+            report = self._serve(requests, passes)
+        report.passes = passes.summary()
+        return report
+
+    def _serve(self, requests: list[Request], passes) -> ServeReport:
         cfg = self.cfg
         b = cfg.slots
         arrivals = deque(sorted(requests, key=lambda r: (r.arrival_time, r.rid)))
@@ -978,6 +999,7 @@ class ServingEngine:
                 (steps if n is None else n) * cfg.step_time_s + v_extra)
         else:
             now = lambda n=None: time.perf_counter() - t0  # noqa: E731
+        passes.clock = now  # a pass's start, on the clock of RequestStats
 
         while arrivals or queue or active.any() or in_flight:
             t = now()

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from tpudml.metrics import MetricsWriter
 from tpudml.nn.layers import Module
 from tpudml.nn.losses import accuracy, softmax_cross_entropy
+from tpudml.obs.passlog import pass_log
 from tpudml.obs.tracer import span
 from tpudml.optim import Optimizer
 
@@ -593,49 +594,50 @@ def train_loop(
         start_epoch, skip_batches = 0, 0
     t0 = time.time()
     metrics = None  # device values; materialized to floats only on log/exit
-    for epoch in range(start_epoch, num_epochs):
-        if hasattr(train_loader, "set_epoch"):
-            train_loader.set_epoch(epoch)
-        batches = iter(train_loader)
-        for i in count():
-            # One pass of the loop: ``step`` is the step it dispatches
-            # (a pass that fast-forwards, or finds the loader exhausted,
-            # has only the ``next_batch`` child).
-            with span("iter", "train", step=counter + 1):
-                with span("next_batch", "train", step=counter + 1):
-                    batch = next(batches, None)
-                if batch is None:
-                    break
-                if epoch == start_epoch and i < skip_batches:
-                    continue  # fast-forward the sampler to the resume point
-                images, labels = batch
-                with span("step", "train", step=counter + 1):
-                    ts, metrics = step(ts, images, labels)
-                counter += 1
-                if log_every and counter % log_every == 0:
-                    # The one host sync of the loop: the loss comes to the host.
-                    with span("log_sync", "train", step=counter):
-                        loss = float(metrics["loss"])
-                        if writer is not None:
-                            writer.add_scalar("Train Loss", loss, counter)
-                            stats = metrics.get("step_stats")
-                            if stats is not None and hasattr(stats, "to_scalars"):
-                                # In-graph telemetry (tpudml.obs): the
-                                # StepStats pytree streams as obs/* scalars
-                                # on the same cadence as the loss.
-                                writer.add_scalars(
-                                    {
-                                        f"obs/{k}": float(v)
-                                        for k, v in stats.to_scalars().items()
-                                    },
-                                    counter,
-                                )
-                        print(f"epoch {epoch} iter {counter}: loss {loss:.4f}")
-                if hooks:
-                    with span("hooks", "train", step=counter):
-                        for h in hooks:
-                            h(epoch=epoch, step=counter, train_state=ts,
-                              metrics=metrics)
+    with pass_log("train") as passes:  # every run keeps one (obs/passlog.py)
+        for epoch in range(start_epoch, num_epochs):
+            if hasattr(train_loader, "set_epoch"):
+                train_loader.set_epoch(epoch)
+            batches = iter(train_loader)
+            for i in count():
+                # One pass of the loop: ``step`` is the step it dispatches
+                # (a pass that fast-forwards, or finds the loader exhausted,
+                # has only the ``next_batch`` child).
+                with span("iter", "train", step=counter + 1):
+                    with span("next_batch", "train", step=counter + 1):
+                        batch = next(batches, None)
+                    if batch is None:
+                        break
+                    if epoch == start_epoch and i < skip_batches:
+                        continue  # fast-forward the sampler to the resume point
+                    images, labels = batch
+                    with span("step", "train", step=counter + 1):
+                        ts, metrics = step(ts, images, labels)
+                    counter += 1
+                    if log_every and counter % log_every == 0:
+                        # The one host sync of the loop: the loss comes to the host.
+                        with span("log_sync", "train", step=counter):
+                            loss = float(metrics["loss"])
+                            if writer is not None:
+                                writer.add_scalar("Train Loss", loss, counter)
+                                stats = metrics.get("step_stats")
+                                if stats is not None and hasattr(stats, "to_scalars"):
+                                    # In-graph telemetry (tpudml.obs): the
+                                    # StepStats pytree streams as obs/* scalars
+                                    # on the same cadence as the loss.
+                                    writer.add_scalars(
+                                        {
+                                            f"obs/{k}": float(v)
+                                            for k, v in stats.to_scalars().items()
+                                        },
+                                        counter,
+                                    )
+                            print(f"epoch {epoch} iter {counter}: loss {loss:.4f}")
+                    if hooks:
+                        with span("hooks", "train", step=counter):
+                            for h in hooks:
+                                h(epoch=epoch, step=counter, train_state=ts,
+                                  metrics=metrics)
     jax.block_until_ready(ts.params)
     train_time = time.time() - t0
     print(f"Training time: {train_time:.3f}s")
@@ -655,4 +657,7 @@ def train_loop(
     )
     last_metrics["train_time_s"] = train_time
     last_metrics["steps"] = counter
+    # The loop's passes on the host clock, summed up: per class its count,
+    # p50 / p99 / max ms of ``train/iter``, and the longest iters whole.
+    last_metrics["passes"] = passes.summary()
     return ts, last_metrics
