@@ -3,8 +3,10 @@
 Load-bearing property: the kernels are the same function as the reference
 ``dot_product_attention`` — forward (all block sizes, causal on/off,
 bfloat16) in both its forms, a head resident in VMEM and K/V tiles streamed
-(the rule between them is ``_forward_plan``), and gradients via every
-backward path: the one-pass kernel (a head resident in VMEM), the dQ and
+(the rule between them is ``_forward_plan``), the resident kernels in both
+operand forms — the model's own [B, T, H·D] rows, a lane block of whole heads
+a program, and each head folded first (``_heads_per_block``) — and gradients
+via every backward path: the one-pass kernel (a head resident in VMEM), the dQ and
 dK/dV kernels that stream tiles (``_backward_plan``), and the custom_vjp
 reference-recompute fallback.
 """
@@ -35,6 +37,10 @@ def qkv():
         jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32))
         for _ in range(3)
     )
+
+
+def _kernel_names(fn) -> set:
+    return set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)())))
 
 
 def _takes_resident(t, d, dtype, block_q, block_k, k_shift=0) -> bool:
@@ -239,15 +245,149 @@ def test_resident_forward_at_head_widths(t, causal, d, dtype, tol):
     want = dot_product_attention(*(a.astype(jnp.float32) for a in (q, k, v)),
                                  causal=causal)
     assert out.dtype == dtype and lse.dtype == jnp.float32
-    assert lse.shape == (1, 1024, 1)
+    assert lse.shape == (1, 1, 1, 1024)  # rows: lane-dense, not a [T, 1] column
     err = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(want))
     assert err <= tol * np.linalg.norm(np.asarray(want))
     s_out, s_lse = attention_kernel._flash_forward(
         q, k, v, causal, block_q, block_k, True)
     gap = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(s_out, np.float32))
     assert gap <= tol * np.linalg.norm(np.asarray(want))
-    np.testing.assert_allclose(np.asarray(lse[:, :t]), np.asarray(s_lse[:, :t]),
+    np.testing.assert_allclose(np.asarray(lse[0, 0, :, :t]), np.asarray(s_lse[:, :t, 0]),
                                rtol=2e-5, atol=2e-6)
+
+
+def _rel_err(got, want) -> float:
+    want = np.asarray(want, np.float32)
+    return float(np.linalg.norm(np.asarray(got, np.float32) - want)
+                 / np.linalg.norm(want))
+
+
+# (H, D, heads a lane block): which operand form the resident kernels take
+# follows (H, D) alone — the model's own [B, T, H·D] rows where they split into
+# lane blocks of whole heads, else each head folded to rows of its own.
+ROW_FORMS = [(4, 64, 2), (3, 64, 0), (2, 128, 1), (8, 32, 4), (6, 32, 0),
+             (1, 256, 1), (2, 96, 0)]
+
+
+@pytest.mark.parametrize("h,d,heads", ROW_FORMS)
+def test_operand_form_follows_heads_and_width(h, d, heads):
+    assert attention_kernel._heads_per_block(h, d) == heads
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("tiles,dtype,tol", [
+    (None, jnp.float32, 5e-4), (None, jnp.bfloat16, 0.03), (16, jnp.float32, 5e-4),
+], ids=["one_tile-f32", "one_tile-bf16", "3x3_tiles-f32"])
+@pytest.mark.parametrize("h,d,heads", ROW_FORMS[:5],
+                         ids=["d64_pair", "d64_odd_h_fold", "d128", "d32_four",
+                              "d32_h6_fold"])
+def test_resident_forms_match_reference(h, d, heads, tiles, causal, dtype, tol):
+    """Both operand forms of the resident kernels, forward and backward,
+    against ``dot_product_attention``: heads that share a lane block (a pair
+    at D 64, four at D 32), a head a block (D 128) and the folded heads of a
+    shape whose rows do not split; T 37 padded to one tile of 40 rows and to
+    3 x 3 tiles of 16 (padded keys masked, padded rows cut; the CPU's
+    interpreter refuses bf16 there, in a causal backward of one head a
+    block, at the parent too); the output, and dQ, dK, dV, which hang on the
+    forward's lse rows and the Δ taken inside."""
+    t = 37
+    rng = np.random.default_rng(h * d)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, t, h, d)), dtype) for _ in range(3))
+    assert _takes_resident(t, d, dtype, tiles, tiles)
+    assert attention_kernel._backward_plan(t, d, dtype, tiles, tiles, heads)[0] is (
+        attention_kernel._backward_one_pass)
+    blocks = dict(block_q=tiles, block_k=tiles)
+    suffix = "_rows" if heads else ""
+    assert _kernel_names(lambda: jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, causal=causal, interpret=True, **blocks).astype(jnp.float32)))(q)
+    ) == {"flash_fwd_resident" + suffix, "flash_bwd" + suffix}
+    out = flash_attention(q, k, v, causal=causal, interpret=True, **blocks)
+    want = dot_product_attention(*(a.astype(jnp.float32) for a in (q, k, v)),
+                                 causal=causal)
+    assert out.dtype == dtype and out.shape == q.shape
+    assert _rel_err(out, want) <= tol / 3
+    got, ref = _flash_and_reference_grads(q, k, v, causal, **blocks)
+    for g, r in zip(got, ref):
+        assert g.dtype == dtype and g.shape == q.shape
+        assert _rel_err(g, r) <= tol
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128), (8, 32)],
+                         ids=["d64_pair", "d128", "d32_four"])
+def test_rows_and_folded_forms_agree(monkeypatch, h, d):
+    """The same shape through both operand forms (the folded one held by
+    the rule's own function): the output and lse to the last bit — a head's
+    scores over its block's lanes add the other heads' zeros, exactly
+    nothing, and P·V's kept lanes are the head's own dot products — and the
+    gradients to float32 rounding (Δ sums a head's lanes among the block's,
+    in another order than alone)."""
+    rng = np.random.default_rng(d)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 48, h, d)), jnp.float32)
+               for _ in range(3))
+
+    def run():
+        out, lse = attention_kernel._forward_resident(q, k, v, True, 16, 16, True)
+        grads, _ = _flash_and_reference_grads(q, k, v, True, block_q=16, block_k=16)
+        return out, lse.reshape(2, h, 48), grads
+
+    rows = run()
+    monkeypatch.setattr(attention_kernel, "_heads_per_block", lambda h, d: 0)
+    jax.clear_caches()  # the resident calls are jitted: the rule is read at the trace
+    folded = run()
+    monkeypatch.undo()
+    jax.clear_caches()  # and no later test meets the folded trace of this shape
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(folded[0]))
+    np.testing.assert_array_equal(np.asarray(rows[1]), np.asarray(folded[1]))
+    for a, b in zip(rows[2], folded[2]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("held,forward,backward", [
+    ("_one_pass_fits", "flash_fwd_resident_rows", {"flash_bwd_dq", "flash_bwd_dkv"}),
+    ("_resident_fits", "flash_fwd", {"flash_bwd_rows"}),
+], ids=["rows_forward_two_kernels", "streaming_forward_rows_backward"])
+def test_lse_passes_between_the_forms(monkeypatch, held, forward, backward):
+    """The resident forward's lse is rows, the streaming forward's a column
+    a head; either backward takes either (a head too long for one side of
+    the rule and not the other: float32 at T 2048 x D 128)."""
+    monkeypatch.setattr(attention_kernel, held, lambda *a: False)
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 37, 2, 64)), jnp.float32)
+               for _ in range(3))
+    assert _kernel_names(lambda: jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, causal=True, interpret=True, block_q=16, block_k=16)))(q)
+    ) == {forward} | backward
+    got, want = _flash_and_reference_grads(q, k, v, True, block_q=16, block_k=16)
+    for g, r in zip(got, want):
+        assert _rel_err(g, r) <= 5e-4
+
+
+def test_rows_form_per_shard_with_heads_sharded():
+    """Under a GSPMD engine's layout the kernels run per shard, and the rule
+    sees the shard's heads: 4 heads of 64 over 2 devices are a pair a shard
+    (the rows form), 2 heads over 2 devices one head a shard (folded)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpudml.parallel.sharding import kernel_layout
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
+    rng = np.random.default_rng(9)
+    for h, names in ((4, {"flash_fwd_resident_rows", "flash_bwd_rows"}),
+                     (2, {"flash_fwd_resident", "flash_bwd"})):
+        q, k, v = (jax.device_put(
+            jnp.asarray(rng.normal(size=(2, 40, h, 64)), jnp.float32),
+            NamedSharding(mesh, P(None, None, "model", None))) for _ in range(3))
+
+        def grads(q, k, v):
+            with kernel_layout(mesh, head="model"):
+                return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+                    q, k, v, causal=True, interpret=True) ** 2), (0, 1, 2))(q, k, v)
+
+        assert _kernel_names(lambda: grads(q, k, v)) == names
+        want = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+            q, k, v, causal=True) ** 2), (0, 1, 2))(q, k, v)
+        for g, r in zip(jax.jit(grads)(q, k, v), want):
+            assert _rel_err(g, r) <= 5e-4
 
 
 @pytest.mark.parametrize(
@@ -291,13 +431,10 @@ def test_forward_lse_keeps_the_streaming_kernel(qkv):
     q, k, v = qkv
     assert _takes_resident(T, D, q.dtype, None, None)
 
-    def names(fn):
-        return set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)())))
-
-    assert names(lambda: flash_forward_lse(q, k, v, causal=True, interpret=True)) == {
-        "flash_fwd"}
-    assert names(lambda: flash_attention(q, k, v, causal=True, interpret=True)) == {
-        "flash_fwd_resident"}
+    assert _kernel_names(lambda: flash_forward_lse(
+        q, k, v, causal=True, interpret=True)) == {"flash_fwd"}
+    assert _kernel_names(lambda: flash_attention(
+        q, k, v, causal=True, interpret=True)) == {"flash_fwd_resident"}
 
 
 @pytest.mark.parametrize(
@@ -350,8 +487,7 @@ def test_block_grads_keep_the_two_kernels(qkv):
             block_q=8, block_k=8, interpret=True)
 
     assert _takes_one_pass(half, D, q.dtype, 8, 8)
-    assert set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(
-        lambda: block(0, 1))()))) == {"flash_bwd_dq", "flash_bwd_dkv"}
+    assert _kernel_names(lambda: block(0, 1)) == {"flash_bwd_dq", "flash_bwd_dkv"}
     g = [[block(i, j) for j in (0, 1)] for i in (0, 1)]
     got = (
         jnp.concatenate([g[i][0][0] + g[i][1][0] for i in (0, 1)], 1),

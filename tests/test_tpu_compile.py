@@ -90,14 +90,20 @@ def test_flash_attention_fwd_bwd_bf16(chip):
         q, k, v, causal=True, interpret=False), (0, 1, 2)), qkv, qkv, qkv)
 
 
-# The two forms of each direction, each at a shape that takes it: the training
-# cell's (gpt2-medium.pretrain-1k, a head resident in VMEM: one kernel a
-# direction) and starcoderbase-1b's context (too long to sit there: the
-# forward streams K/V tiles, the backward is the dQ and dK/dV kernels).
+_FLASH_KERNEL = r"\bflash_(?:fwd_resident_rows|fwd_resident|fwd|bwd_rows|bwd_dq|bwd_dkv|bwd)\b"
+
+
+# The forms of each direction, each at a shape that takes it: the training
+# cell's (gpt2-medium.pretrain-1k, a pair of heads resident in VMEM, read from
+# the model's own rows: one kernel a direction), the same with an odd head
+# count (the rows do not split into lane blocks: each head folded) and
+# starcoderbase-1b's context (too long to sit there: the forward streams K/V
+# tiles, the backward is the dQ and dK/dV kernels).
 @pytest.mark.parametrize("shape,kernels", [
-    ((8, 1024, 16, 64), {"flash_fwd_resident", "flash_bwd"}),
+    ((8, 1024, 16, 64), {"flash_fwd_resident_rows", "flash_bwd_rows"}),
+    ((8, 1024, 15, 64), {"flash_fwd_resident", "flash_bwd"}),
     ((1, 8192, 16, 128), {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
-], ids=["gpt2-medium-one_pass", "long_head128-two_kernels"])
+], ids=["gpt2-medium-rows", "odd_heads-folded", "long_head128-two_kernels"])
 def test_flash_backward_form_on_the_chip(chip, shape, kernels):
     from tpudml.ops.attention_kernel import flash_attention
 
@@ -105,8 +111,67 @@ def test_flash_backward_form_on_the_chip(chip, shape, kernels):
     text = chip(_grad_sum(lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=False), (0, 1, 2)), qkv, qkv, qkv)
     # whole names: the frame table also holds the function ``_flash_fwd``
-    assert set(re.findall(
-        r"\bflash_(?:fwd_resident|fwd|bwd_dq|bwd_dkv|bwd)\b", text)) == kernels
+    assert set(re.findall(_FLASH_KERNEL, text)) == kernels
+
+
+# One attention layer as the model runs it: three projections of the residual
+# stream, causal flash attention, the out projection; forward and backward.
+# Where H·D splits into lane blocks of whole heads the kernels read the
+# projections' own [B, T, H·D] rows: no transpose, no pad of a 64-wide minor
+# dimension to 128 lanes and no [B·H, T, 1] column (128-fold in memory) exists
+# around them; at PR 42 the first shape held eight such copies and a column.
+@pytest.mark.parametrize("shape,kernels,folds", [
+    ((8, 1024, 16, 64), {"flash_fwd_resident_rows", "flash_bwd_rows"}, 0),
+    ((4, 2048, 8, 128), {"flash_fwd_resident_rows", "flash_bwd_rows"}, 0),
+    ((8, 1024, 15, 64), {"flash_fwd_resident", "flash_bwd"}, 8),
+], ids=["gpt2-medium", "head128", "odd_heads-folded"])
+def test_attention_layer_reads_the_projections_rows(chip, shape, kernels, folds):
+    from tpudml.ops.attention_kernel import flash_attention
+
+    b, t, h, d = shape
+
+    def layer(x, wq, wk, wv, wo):
+        q, k, v = ((x @ w).reshape(b, t, h, d) for w in (wq, wk, wv))
+        o = flash_attention(q, k, v, causal=True, interpret=False)
+        return o.reshape(b, t, h * d) @ wo
+
+    w = ((h * d, h * d), bf16)
+    text = chip(_grad_sum(layer, (0, 1, 2, 3, 4)), ((b, t, h * d), bf16), w, w, w, w)
+    assert set(re.findall(_FLASH_KERNEL, text)) == kernels
+    # a fold moves all of q, k, v, o or a gradient (bf16); the lse or Δ
+    # column is [B·H, T, 1] float32
+    relaid = [n for n in _copied_bytes(text, "copy|transpose") if n >= 2 * b * t * h * d]
+    columns = re.findall(rf"f32\[{b * h},{t},1\]", text)
+    assert (len(relaid), len(columns)) == (folds, 0), (relaid, columns)
+
+
+def test_fused_step_holds_a_repeated_layer_once(topo, monkeypatch):
+    """The fused LM step is compiled with identical fusions deduplicated (one
+    copy of a layer's code down the stack): the chip's compiler takes the
+    option ``tpudml.train`` hands it on a TPU, and the program's code is a
+    fraction of what it is without (gpt2-medium's step: 36 MB for 320, which
+    is what fits a persistent compile cache)."""
+    from tpudml import train
+    from tpudml.core.prng import seed_key
+    from tpudml.models import TransformerLM
+    from tpudml.optim import make_optimizer
+
+    assert train._one_copy_of_a_repeated_layer() is None  # the CPU's compiler has no such option
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # kernels, and the option
+    options = train._one_copy_of_a_repeated_layer()
+    model = TransformerLM(vocab_size=1024, embed_dim=256, num_heads=4, num_layers=6,
+                          max_len=256, impl="flash", fused_ln=True)
+    opt = make_optimizer("adam", 1e-3)
+    body = train.make_lm_fused_train_step_body(model, opt)
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda k: train.TrainState.create(model, opt, k), seed_key(0)))
+    tokens = jax.ShapeDtypeStruct((2, 256), i32, sharding=one)
+    lowered = jax.jit(body, donate_argnums=(0,)).lower(state, tokens, tokens)
+    code = [lowered.compile(compiler_options=o).memory_analysis().generated_code_size_in_bytes
+            for o in (None, options)]
+    assert code[1] < 0.5 * code[0]
 
 
 def test_fused_add_layernorm_fwd_bwd(chip):
@@ -176,14 +241,16 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
              "u32": 4, "f32": 4}
 
 
-def _copied_bytes(text: str) -> list[int]:
-    """Bytes of the result of every ``copy`` in a compiled program."""
+def _copied_bytes(text: str, ops: str = "copy") -> list[int]:
+    """Bytes of the result of every ``copy`` (or other ``ops``, a regex) in a
+    compiled program; a ``copy-start`` / ``-done`` pair, the compiler's
+    prefetch of an operand, is none."""
     import math
     import re
 
     return [math.prod(int(d) for d in dims.split(",") if d) * _ITEMSIZE[dt]
             for dt, dims in re.findall(
-                r"= (\w+)\[([\d,]*)\]\{[^}]*\} copy\(", text)]
+                rf"= (\w+)\[([\d,]*)\]\{{[^}}]*\}} (?:{ops})\(", text)]
 
 
 @pytest.fixture
