@@ -557,12 +557,14 @@ def test_save_s_auto_threshold():
     from tpudml.ops.xent_kernel import _auto_save_s
 
     bn, bv = 256, 2048
-    assert _auto_save_s(8192, 32768, bn, bv) is True     # flagship
-    assert _auto_save_s(16384, 32768, bn, bv) is True    # --large (2 GiB)
-    assert _auto_save_s(16640, 32768, bn, bv) is False   # just past budget
-    assert _auto_save_s(131072, 32768, bn, bv) is False  # long-context
+    auto = lambda n, v: _auto_save_s(n, 512, v, jnp.bfloat16, jnp.bfloat16,
+                                     bn, bv)
+    assert auto(8192, 32768) is True     # flagship
+    assert auto(16384, 32768) is True    # --large (2 GiB)
+    assert auto(16640, 32768) is False   # just past budget
+    assert auto(131072, 32768) is False  # long-context
     # Padding counts: n=1 still pads to a block row multiple of 8.
-    assert _auto_save_s(1, 256, bn, bv) is True
+    assert auto(1, 256) is True
 
 
 def test_save_s_auto_threshold_sharded(monkeypatch):
@@ -579,20 +581,21 @@ def test_save_s_auto_threshold_sharded(monkeypatch):
 
     bn, bv = 256, 2048
     n, v, shards = 16640, 32768, 4
+    bf16 = jnp.bfloat16
     # Unsharded: one padded block row past the 2 GiB budget.
-    assert xk._auto_save_s(n, v, bn, bv) is False
+    assert xk._auto_save_s(n, 512, v, bf16, bf16, bn, bv) is False
     _, _, n_pad, v_pad = xk._padded_dims(n, v, bn, bv)
     assert (n_pad - bn) * v_pad * 4 == xk.SAVE_S_AUTO_MAX_BYTES
     # Each shard's residual is exactly 1/W of that -> back under budget.
-    assert xk._auto_save_s(n, v // shards, bn, bv) is True
+    assert xk._auto_save_s(n, 512, v // shards, bf16, bf16, bn, bv) is True
 
     # And sharded_linear_cross_entropy really uses the local slice.
     seen = []
     real = xk._auto_save_s
 
-    def spy(n, v, block_n, block_v):
+    def spy(n, d, v, *rest):
         seen.append((n, v))
-        return real(n, v, block_n, block_v)
+        return real(n, d, v, *rest)
 
     monkeypatch.setattr(xk, "_auto_save_s", spy)
     mesh = make_mesh(MeshConfig({"model": 4}), jax.devices()[:4])
@@ -611,24 +614,3 @@ def test_save_s_auto_threshold_sharded(monkeypatch):
         in_specs=(P(), P(None, "model"), P()), out_specs=P(),
     )(x, w, labels)
     assert (nn, vv // 4) in seen
-
-
-def test_pick_bv_dw_divisor_contract():
-    from tpudml.ops.xent_kernel import _pick_bv_dw
-
-    # Non-power-of-two block_v (the ADVICE case): halving 384 would
-    # strand above a 256 cap; the divisor pick lands on 256 | 1536.
-    assert _pick_bv_dw(1536, 384, 256) == 256
-    # Power-of-two happy path unchanged.
-    assert _pick_bv_dw(4096, 2048, 1024) == 1024
-    # Cap below 128 clamps to the 128 floor.
-    assert _pick_bv_dw(1024, 2048, 64) == 128
-    # Small-vocab clamp (v_pad = block_v < 128) keeps the full tile — the
-    # 128 floor must NOT override a tile that already fits (it would not
-    # divide v_pad and the dW grid would be empty).
-    assert _pick_bv_dw(64, 64, 1024) == 64
-    # v_pad is always a multiple of block_v by construction.
-    for v_pad, bv, cap in [(1536, 384, 256), (8192, 2048, 896), (1536, 512, 512)]:
-        got = _pick_bv_dw(v_pad, bv, cap)
-        assert got % 128 == 0 and v_pad % got == 0
-        assert got <= max(128, min(bv, cap))

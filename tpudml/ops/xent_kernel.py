@@ -19,20 +19,22 @@ classifier head:
   backward split:
   dX (V innermost): dx_tile += dlogits @ W_tileᵀ;
   dW (N innermost): dW_tile += x_tileᵀ @ dlogits.
-- backward, save-s mode (round 4): the forward additionally streams its
-  f32 score tiles to HBM, and both backward kernels read them instead
-  of recomputing — the backward drops from 4 matmuls' worth of MXU work
-  to the 2 the cotangents actually need (recomputing s cost ~2 ms at
-  [8192,512]×[512,32k]; XLA's lean path wins at memory-fitting sizes
-  for exactly this reason — it keeps the logits). Saved scores are f32,
-  so gradients are bit-identical to the lean mode's recomputation. The
-  trade is an N_pad·V_pad·4-byte residual in place of the O(N)
-  contract; since round 5 the DEFAULT (``save_s=None``) picks the mode
+- backward, save-s mode: the forward additionally streams its f32 score
+  tiles to HBM, and both backward kernels read them instead of
+  recomputing — the backward drops from 4 matmuls' worth of MXU work to
+  the 2 the cotangents actually need. Saved scores are f32 and both modes
+  take the same tiles, so gradients are bit-identical to the lean mode's
+  recomputation. The trade is an N_pad·V_pad·4-byte residual in place of
+  the O(N) contract; the DEFAULT (``save_s=None``) picks the mode
   automatically — save-s while that residual fits
-  ``SAVE_S_AUTO_MAX_BYTES`` (2 GiB), the lean O(N) contract beyond
-  (measured in-situ: save-s 19.29 ms/step vs lean 21.54 at the
-  flagship, BASELINE.md round 5). Pass ``save_s=False`` to force the
-  O(N) guarantee regardless of size.
+  ``SAVE_S_AUTO_MAX_BYTES`` (2 GiB), the lean O(N) contract beyond. Pass
+  ``save_s=False`` to force the O(N) guarantee regardless of size.
+- tiles: every kernel re-reads one operand once a block of the other
+  axis (the forward and dX the whole head once a ROW block, dW all of x
+  once a VOCABULARY tile), so small tiles make a matmul-shaped kernel
+  memory-bound. ``_plan`` chooses them from the call's shape so that the
+  re-reads cost less than the matmul, and each kernel states the VMEM its
+  tiles need (``_vmem_params``); the chip's numbers are beside ``_plan``.
 
 Exactness: same math as ``softmax_cross_entropy`` over the materialized
 logits (f32 statistics); pinned by tests against the XLA reference.
@@ -42,7 +44,9 @@ Dispatch: compiled kernel on TPU; reference math elsewhere (tests force
 
 from __future__ import annotations
 
+import itertools
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +54,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-from tpudml.ops.tiling import WIDE_TILE_PARAMS
 from tpudml.ops.tiling import round_up as _round_up  # shared tiling helper
 
 
@@ -121,7 +124,8 @@ def _fused_forward(x, w, b, labels, block_n, block_v, interpret,
     n, d = x.shape
     d2, v = w.shape
     assert d == d2, (x.shape, w.shape)
-    block_n, block_v, n_pad, v_pad = _padded_dims(n, v, block_n, block_v)
+    plan = _plan(n, d, v, x.dtype, w.dtype, block_n, block_v)
+    (block_n, block_v), n_pad, v_pad = plan.tile, plan.n_pad, plan.v_pad
     xf = jnp.pad(x, ((0, n_pad - n), (0, 0))) if n_pad != n else x
     wf = jnp.pad(w, ((0, 0), (0, v_pad - v))) if v_pad != v else w
     bf = (jnp.pad(b, (0, v_pad - v)) if v_pad != v else b)[None, :]
@@ -160,7 +164,8 @@ def _fused_forward(x, w, b, labels, block_n, block_v, interpret,
         ],
         name="xent_fwd_save" if save_s else "xent_fwd",
         interpret=interpret,
-        compiler_params=WIDE_TILE_PARAMS,
+        compiler_params=_vmem_params("fwd", plan.tile, d, x.dtype, w.dtype,
+                                     save_s),
     )(xf, wf, bf, lf)
     if save_s:
         lse, picked, s = outs
@@ -227,21 +232,24 @@ def _dw_s_kernel(s_ref, x_ref, label_ref, lse_ref, dw_ref, db_ref, acc_ref,
         db_ref[:] = db_acc[:].astype(db_ref.dtype)
 
 
-def _bwd_prologue(x, w, labels, lse, block_n, block_v):
-    """Shared backward setup for BOTH modes: block clamping and the
+def _bwd_prologue(x, w, labels, lse, block_n, block_v, save_s):
+    """Shared backward setup for BOTH modes: the call's plan and the
     padded-row contract — labels pad to -1 (match no column) and lse
     pads to +inf so p = exp(s − lse) = 0 on padded rows, making their
     dlogits exactly zero in every backward kernel."""
     n, d = x.shape
     _, v = w.shape
-    block_n, block_v, n_pad, v_pad = _padded_dims(n, v, block_n, block_v)
+    plan = _plan(n, d, v, x.dtype, w.dtype, block_n, block_v)
+    n_pad, v_pad = plan.n_pad, plan.v_pad
     xf = jnp.pad(x, ((0, n_pad - n), (0, 0))) if n_pad != n else x
     wf = jnp.pad(w, ((0, 0), (0, v_pad - v))) if v_pad != v else w
     lf = jnp.pad(labels.astype(jnp.int32), (0, n_pad - n),
                  constant_values=-1)[:, None]
     lsef = jnp.pad(lse.astype(jnp.float32), (0, n_pad - n),
                    constant_values=jnp.inf)[:, None]
-    return n, d, v, block_n, block_v, n_pad, v_pad, xf, wf, lf, lsef
+    params = partial(_vmem_params, d=d, x_dtype=x.dtype, w_dtype=w.dtype,
+                     save_s=save_s)
+    return n, d, v, plan, params, xf, wf, lf, lsef
 
 
 def _scale_cotangents(dx, dw, db, g, x, w, b):
@@ -256,29 +264,13 @@ def _scale_cotangents(dx, dw, db, g, x, w, b):
     )
 
 
-def _pick_bv_dw(v_pad: int, block_v: int, bv_cap: int) -> int:
-    """dW vocab tile: ``block_v`` when it already meets the VMEM cap,
-    else the largest 128-multiple divisor of ``v_pad`` under the cap —
-    repeated halving could strand a non-power-of-two ``block_v`` (e.g.
-    384) above it. When ``block_v`` exceeds the cap it is ≥ 256 and a
-    multiple of 128 (small vocabs clamp block_v to v_pad ≤ cap), so 128
-    always divides ``v_pad`` and the search cannot come up empty; the
-    ``block_v`` fallback keeps the pre-search behavior (tile above cap)
-    for any exotic hand-picked block size."""
-    cap = max(128, bv_cap)
-    if block_v <= cap:
-        return block_v
-    for cand in range(cap - cap % 128, 127, -128):
-        if v_pad % cand == 0:
-            return cand
-    return block_v
-
-
 def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
                           interpret):
-    (n, d, v, block_n, block_v, n_pad, v_pad, xf, wf, lf, lsef
-     ) = _bwd_prologue(x, w, labels, lse, block_n, block_v)
+    n, d, v, plan, params, xf, wf, lf, lsef = _bwd_prologue(
+        x, w, labels, lse, block_n, block_v, True)
+    n_pad, v_pad = plan.n_pad, plan.v_pad
     assert s.shape == (n_pad, v_pad), (s.shape, n_pad, v_pad)
+    block_n, block_v = plan.tile
     dx = pl.pallas_call(
         partial(_dx_s_kernel, block_v=block_v, v_valid=v, inv_n=1.0 / n),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
@@ -293,16 +285,9 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         name="xent_bwd_dx_saved",
         interpret=interpret,
-        compiler_params=WIDE_TILE_PARAMS,
+        compiler_params=params("dx", plan.tile),
     )(s, wf, lf, lsef)[:n]
-    # dW tile cap: the f32 s tiles + f32 accumulator must fit scoped VMEM
-    # (~16 MB): 4·d·bv (acc) + 8·bn·bv (s ×2 buffers) + 8·d·bv (dw out
-    # ×2, f32 worst case) ≤ ~12 MB. Pick the largest 128-multiple divisor
-    # of v_pad under the cap (_pick_bv_dw) — 128 always qualifies.
-    bv_cap = max(
-        128, (12 * 1024 * 1024) // (12 * d + 8 * block_n) // 128 * 128
-    )
-    bv_dw = _pick_bv_dw(v_pad, block_v, bv_cap)
+    block_n, bv_dw = plan.dw
     dw, db = pl.pallas_call(
         partial(_dw_s_kernel, block_v=bv_dw, v_valid=v, inv_n=1.0 / n),
         out_shape=[
@@ -326,7 +311,7 @@ def _fused_backward_saved(x, w, b, labels, lse, s, g, block_n, block_v,
         ],
         name="xent_bwd_dw_saved",
         interpret=interpret,
-        compiler_params=WIDE_TILE_PARAMS,
+        compiler_params=params("dw", plan.dw),
     )(s, xf, lf, lsef)
     return _scale_cotangents(dx, dw[:, :v], db[0, :v], g, x, w, b)
 
@@ -394,14 +379,10 @@ def _dw_kernel(w_ref, x_ref, b_ref, label_ref, lse_ref, dw_ref, db_ref,
 
 
 def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
-    (n, d, v, block_n, block_v, n_pad, v_pad, xf, wf, lf, lsef
-     ) = _bwd_prologue(x, w, labels, lse, block_n, block_v)
-    # The dW kernel holds a [d, block_v] f32 scratch PLUS double-buffered
-    # [d, block_v] in/out W tiles; cap its vocab tile so the working set
-    # stays under the ~16 MB scoped-VMEM limit (5 live [d, bv] f32 tiles
-    # + x/dlog  ->  bv <= 12 MB / (5 * 4 * d)).
-    bv_budget = max(128, (12 * 1024 * 1024) // (5 * 4 * d) // 128 * 128)
-    block_v_dw = min(block_v, bv_budget)
+    n, d, v, plan, params, xf, wf, lf, lsef = _bwd_prologue(
+        x, w, labels, lse, block_n, block_v, False)
+    n_pad, v_pad = plan.n_pad, plan.v_pad
+    block_n, block_v = plan.tile
     bf = (jnp.pad(b, (0, v_pad - v)) if v_pad != v else b)[None, :]
     dx = pl.pallas_call(
         partial(_dx_kernel, block_v=block_v, v_valid=v, inv_n=1.0 / n),
@@ -418,18 +399,16 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         name="xent_bwd_dx",
         interpret=interpret,
-        compiler_params=WIDE_TILE_PARAMS,
+        compiler_params=params("dx", plan.tile),
     )(xf, wf, bf, lf, lsef)[:n]
-    v_pad_dw = _round_up(v, block_v_dw)
-    wfd = jnp.pad(w, ((0, 0), (0, v_pad_dw - v))) if v_pad_dw != v else w
-    bfd = (jnp.pad(b, (0, v_pad_dw - v)) if v_pad_dw != v else b)[None, :]
+    block_n, block_v_dw = plan.dw
     dw, db = pl.pallas_call(
         partial(_dw_kernel, block_v=block_v_dw, v_valid=v, inv_n=1.0 / n),
         out_shape=[
-            jax.ShapeDtypeStruct(wfd.shape, w.dtype),
-            jax.ShapeDtypeStruct((1, v_pad_dw), jnp.float32),
+            jax.ShapeDtypeStruct(wf.shape, w.dtype),
+            jax.ShapeDtypeStruct((1, v_pad), jnp.float32),
         ],
-        grid=(1, v_pad_dw // block_v_dw, n_pad // block_n),
+        grid=(1, v_pad // block_v_dw, n_pad // block_n),
         in_specs=[
             pl.BlockSpec((d, block_v_dw), lambda _, j, i: (0, j)),
             pl.BlockSpec((block_n, d), lambda _, j, i: (i, 0)),
@@ -447,8 +426,8 @@ def _fused_backward(x, w, b, labels, lse, g, block_n, block_v, interpret):
         ],
         name="xent_bwd_dw",
         interpret=interpret,
-        compiler_params=WIDE_TILE_PARAMS,
-    )(wfd, xf, bfd, lf, lsef)
+        compiler_params=params("dw", plan.dw),
+    )(wf, xf, bf, lf, lsef)
     return _scale_cotangents(dx, dw[:, :v], db[0, :v], g, x, w, b)
 
 
@@ -489,34 +468,187 @@ def _fused_bwd(block_n, block_v, interpret, save_s, res, g):
 _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-# save_s auto threshold (round 5, VERDICT r4 item 5): the speed mode's
-# f32 score residual is N_pad·V_pad·4 bytes; keep it on by default while
-# that stays a modest slice of v5e-class HBM (16 GB) and fall back to the
-# O(N) lean mode beyond. 2 GiB covers the flagship (8k×32k = 1 GiB) and
-# the chip-filling config (16k×32k = 2 GiB) with room for the model;
-# 131k-token long-context regimes (16 GiB of scores) auto-drop to lean —
-# exactly the regime the O(N) contract exists for. The speed win was
-# measured at kernel granularity in round 5 (2026-07-31, older than this
-# code).
+# The speed mode's f32 score residual is N_pad·V_pad·4 bytes; it is on by
+# default while that stays a modest slice of a v5e's HBM (16 GB) and the
+# O(N) lean mode runs beyond: 2 GiB covers 8k x 50k (1.66 GB) and
+# 16k x 32k with room for the model; a 131k-token context (16 GiB of
+# scores) drops to lean — the regime the O(N) contract exists for.
 SAVE_S_AUTO_MAX_BYTES = 2 * 1024**3
 
 
+class _Plan(NamedTuple):
+    """One call's tiling: (rows, vocabulary columns) a grid step takes in
+    the forward and dX, the same in dW, and the padded problem all three
+    tile."""
+
+    tile: tuple[int, int]
+    dw: tuple[int, int]
+    n_pad: int
+    v_pad: int
+
+
+# The tiles, measured on both sides (v5e, PR 46; N 8,192 x d 1,024 x V 50,257,
+# bf16, save-s: gpt2-medium.pretrain-1k's head; ms a call by the kernel's own
+# name in a device trace, rows x vocabulary columns; the matmul alone is 4.32 ms
+# at the peak). A grid step fetches its W tile anew, so the whole head
+# (105 MB) is read once a ROW block and the f32 scores (1.66 GB) move once:
+# at 256 rows that is 3.4 + 1.7 GB = 6.2 ms at the memory's roof for 4.4 ms of
+# matmul, and dW reads all of x once a VOCABULARY tile into an accumulator it
+# rewrites every 256 rows.
+# Columns of a line: x512 x1024 x1152 x1408 x1536 x2048 (- = not run).
+#   forward  256 rows  9.14  8.31  8.11  7.93  7.84  7.80 (the rule until PR 46)
+#            512       6.24  5.51  5.33  5.24  5.19  5.20
+#            1,024     5.89  5.18  5.04  4.92  4.89  4.98
+#            2,048     5.79  5.13  4.99  4.90  4.89  6.01
+#   dX       256       6.99  6.68  6.61  6.62  6.62  6.68 (until PR 46)
+#            512       5.10  4.95  4.87  4.78  4.75  4.61
+#            1,024     4.61  4.72  4.67  4.67  4.59  4.48
+#            2,048     4.50  4.63  4.59  4.64  4.51  4.49
+#   dW       256       6.18  5.39  5.25  5.11  5.04  4.95; x640 5.99 (until PR 46)
+#            512       5.70  5.09  4.98  4.87  4.86  4.80; x2560 4.75
+#            1,024     5.32  4.83  4.73  4.70  4.71  4.69; x2560 4.69
+#            2,048     4.93  4.66  4.59  4.56  4.57  4.62
+#            4,096     4.83  5.53   -     -     -     -
+# 1,024 rows bring every kernel within 6-13 % of its matmul and the width
+# then moves it by 2 %: of the 128-multiples from half the widest tile up the
+# plan takes the one that pads the vocabulary least (50,257 -> 1,536-wide,
+# v_pad 50,688; 2,048 pads it to 51,200 for 1 % more of everything). 2,048
+# rows read 1.6 % less over the three kernels for twice the VMEM and twice
+# the padding of a ragged N: not taken. The other loop order of the forward
+# (vocabulary outer, the W tile resident while row blocks stream, the running
+# max / normaliser / pick as [N, 1] columns resident across the grid: a
+# scratch copy of this kernel, never in the tree) at x2048: 256 rows 5.24, 512
+# 5.10, 1,024 4.98; x1024: 512 5.47, 1,024 5.19; x4096: 256 5.37, 512 5.26 —
+# it needs no deep row block, and at 1,024 rows it equals the kept order (4.98
+# for 4.98; the chip compiler schedules both bodies in the same 23.5k bundles a
+# step), so the rows-outer order stays: its statistics are per row block
+# (any N), and dX has no such order (its [N, d] f32 partial sums would cross
+# HBM). Lean mode recomputes s, two matmuls a backward kernel: forward 256 x
+# 2048 5.13, 1,024 x 1024 5.16, x2048 5.01; dX 8.91 / 8.83 / 8.82; dW 256 x
+# 640 9.25, 1,024 x 1024 8.90, x2048 8.87 — MXU-bound at any of these.
+_ROWS = 1024                # rows a grid step takes (the contraction dW makes)
+_TILE_V = 2048              # widest vocabulary tile
+_VMEM_BUDGET = 64 * 2**20   # what a kernel's tiles may hold, as modelled
+_VMEM_CEILING = 100 * 2**20  # of v5e's 128 MiB
+
+
+def _vmem_bytes(kernel: str, tile: tuple[int, int], d: int, x_item: int,
+                w_item: int, save_s: bool) -> int:
+    """What a grid step of ``kernel`` ("fwd", "dx", "dw") holds in VMEM at
+    ``tile``: the blocks the pipeline double-buffers, the scratch, and the
+    body's temporaries — two float32 [rows, columns] tiles (s; p or
+    dlogits), a copy of each operand tile as the MXU wants it, and the
+    backward's float32 product before it is accumulated. Generous: compiled
+    for a described v5e at 1,024 x 2,048, d 1,024, bf16, the kernels need
+    34 / 33 / 47 MiB (lean 25 / 27 / 40) where this says 55 / 60 / 68
+    (39 / 48 / 60)."""
+    bn, bv = tile
+    x_tile, w_tile, s_tile = bn * d * x_item, d * bv * w_item, bn * bv * 4
+    column = bn * 128 * 4  # a [bn, 1] float32 as VMEM holds it
+    row = 8 * bv * 4       # a [1, bv] one
+    temporaries = 2 * s_tile + x_tile + w_tile
+    if kernel == "fwd":
+        pipelined = (x_tile + w_tile + row + 3 * column
+                     + (s_tile if save_s else 0))
+        scratch = 3 * column
+    elif kernel == "dx":
+        pipelined = ((s_tile if save_s else x_tile + row) + w_tile
+                     + 2 * column + x_tile)
+        scratch = bn * d * 4
+        temporaries += bn * d * 4
+    else:
+        pipelined = ((s_tile if save_s else w_tile + row) + x_tile
+                     + 2 * column + w_tile + row)
+        scratch = d * bv * 4 + row
+        temporaries += d * bv * 4
+    return 2 * pipelined + scratch + temporaries
+
+
+def _vmem_params(kernel: str, tile: tuple[int, int], d: int, x_dtype,
+                 w_dtype, save_s: bool) -> pltpu.CompilerParams:
+    """The kernel's own VMEM ceiling, from its tiles (a quarter over the
+    model, never under ``WIDE_TILE_PARAMS``' 32 MiB, which is
+    ``ops/decode_head.py``'s to keep): the default scope of 16 MiB is under
+    what the plan's tiles need."""
+    need = _vmem_bytes(kernel, tile, d, jnp.dtype(x_dtype).itemsize,
+                       jnp.dtype(w_dtype).itemsize, save_s)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(max(need + need // 4, 32 * 2**20),
+                             _VMEM_CEILING))
+
+
 def _padded_dims(n: int, v: int, block_n: int, block_v: int):
-    """The kernel tiling rule, in one place: clamp blocks to the
-    rounded-up problem (rows to 8, vocab to 128), pad the problem to a
-    block multiple. Every consumer — forward, backward prologue, and
-    the save-s auto threshold — must see the SAME (block_n, block_v,
-    n_pad, v_pad) or residual-size estimates drift from reality."""
+    """Clamp blocks to the rounded-up problem (rows to 8, vocab to 128)
+    and pad the problem to a block multiple (``ops/decode_head.py`` tiles
+    by the same rule)."""
     block_n = min(block_n, _round_up(n, 8))
     block_v = min(block_v, _round_up(v, 128))
     return block_n, block_v, _round_up(n, block_n), _round_up(v, block_v)
 
 
-def _auto_save_s(n: int, v: int, block_n: int, block_v: int) -> bool:
+def _halvings(start: int, least: int) -> list[int]:
+    return [start >> i for i in range(start.bit_length())
+            if start >> i >= least]
+
+
+def _open_rows(n: int) -> int:
+    """The row block of a call that left it open: ``_ROWS``, halved (to a
+    quarter at most) while the padding it asks for is over a sixteenth of
+    the rows."""
+    for rows in (_ROWS, _ROWS // 2):
+        if _round_up(n, min(rows, _round_up(n, 8))) - n <= n // 16:
+            return rows
+    return _ROWS // 4
+
+
+def _open_tile_v(v: int, widest: int) -> int:
+    """The vocabulary tile of a call that left it open: of the
+    128-multiples from half of ``widest`` up, the one that pads the
+    vocabulary least (the wider of two that pad alike)."""
+    if _round_up(v, 128) <= widest:
+        return widest  # one tile: ``_padded_dims`` clamps it
+    return min(range(widest, widest // 2 - 1, -128),
+               key=lambda tile: _round_up(v, tile))
+
+
+def _plan(n: int, d: int, v: int, x_dtype, w_dtype,
+          block_n: int | None = None, block_v: int | None = None) -> _Plan:
+    """The tiling of one call, from what the call can see: rows, width,
+    vocabulary, operand dtypes. Not the mode: lean and save-s take the same
+    tiles (ones that fit VMEM in both), so their sums run in the same order
+    and their gradients agree to the last bit. Every consumer — forward,
+    backward prologue and the save-s threshold — asks here, so all see the
+    SAME (n_pad, v_pad) and the residual's size cannot drift from its
+    estimate.
+    ``block_n`` / ``block_v`` given are honoured as they come (clamped to
+    the problem); left open they take the measured best that fits VMEM."""
+    items = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+
+    def fits(kernel, tile):
+        return max(_vmem_bytes(kernel, tile, d, *items, mode)
+                   for mode in (True, False)) <= _VMEM_BUDGET
+
+    # rows first, then ever narrower tiles; blocks given over the budget run
+    # as given (the loop ends on its last candidate)
+    for rows, widest in itertools.product(
+            [block_n] if block_n else _halvings(_open_rows(n), 8),
+            [block_v] if block_v else _halvings(_TILE_V, 128)):
+        bn, bv, n_pad, v_pad = _padded_dims(
+            n, v, rows, block_v or _open_tile_v(v, widest))
+        if fits("fwd", (bn, bv)) and fits("dx", (bn, bv)):
+            break
+    # dW: as deep as the row block, the widest divisor of v_pad that fits
+    dw_v = next((tile for tile in range(bv, 127, -128)
+                 if v_pad % tile == 0 and fits("dw", (bn, tile))), bv)
+    return _Plan((bn, bv), (bn, dw_v), n_pad, v_pad)
+
+
+def _auto_save_s(n: int, d: int, v: int, x_dtype, w_dtype,
+                 block_n: int | None, block_v: int | None) -> bool:
     """save_s=None resolution: speed mode iff the padded f32 score
     residual fits the auto budget."""
-    _, _, n_pad, v_pad = _padded_dims(n, v, block_n, block_v)
-    return n_pad * v_pad * 4 <= SAVE_S_AUTO_MAX_BYTES
+    plan = _plan(n, d, v, x_dtype, w_dtype, block_n, block_v)
+    return plan.n_pad * plan.v_pad * 4 <= SAVE_S_AUTO_MAX_BYTES
 
 
 def _reference_xent(xn, w, b, ln):
@@ -567,8 +699,8 @@ def linear_cross_entropy(
     labels: jax.Array,
     bias: jax.Array | None = None,
     *,
-    block_n: int = 256,
-    block_v: int = 2048,
+    block_n: int | None = None,
+    block_v: int | None = None,
     interpret: bool | None = None,
     save_s: bool | None = None,
 ) -> jax.Array:
@@ -580,13 +712,13 @@ def linear_cross_entropy(
     outside [0, V) contribute loss = lse (no pull-up) — mask such rows
     out beforehand. ``save_s=True`` is the SPEED mode: it keeps the
     [N_pad, V_pad] f32 scores as a backward residual (2 fewer backward
-    matmuls — 8.21 → 5.97 ms at [8192,32k] at kernel granularity,
-    21.54 → 19.29 ms/step in-situ: round 5, 2026-07-31, older than this
-    code); the
+    matmuls); the
     default ``save_s=None`` resolves it AUTOMATICALLY: speed mode while
     the score residual fits ``SAVE_S_AUTO_MAX_BYTES``, the O(N) lean
     mode beyond (the long-context regimes the memory contract exists
-    for). Pass ``False`` to force the O(N) contract regardless. On
+    for). Pass ``False`` to force the O(N) contract regardless.
+    ``block_n`` / ``block_v`` left open take the tiles ``_plan`` chooses
+    from the shape; given, they are honoured (tests, sweeps). On
     non-TPU backends dispatches to the XLA reference math unless
     ``interpret=True`` forces the Pallas interpreter.
 
@@ -602,7 +734,8 @@ def linear_cross_entropy(
     if xn.shape[0] != ln.shape[0]:
         raise ValueError(f"{x.shape} rows != {labels.shape} labels")
     if save_s is None:
-        save_s = _auto_save_s(xn.shape[0], v, block_n, block_v)
+        save_s = _auto_save_s(xn.shape[0], d, v, x.dtype, w.dtype, block_n,
+                              block_v)
     b = jnp.zeros((v,), w.dtype) if bias is None else bias
     return _fused_xent_unsharded_jit(
         xn, w, b, ln, block_n, block_v, interpret, save_s
@@ -743,8 +876,8 @@ def sharded_linear_cross_entropy(
     bias: jax.Array | None = None,
     *,
     axis_name: str,
-    block_n: int = 256,
-    block_v: int = 2048,
+    block_n: int | None = None,
+    block_v: int | None = None,
     interpret: bool | None = None,
     save_s: bool | None = None,
 ) -> jax.Array:
@@ -770,7 +903,8 @@ def sharded_linear_cross_entropy(
     if xn.shape[0] != ln.shape[0]:
         raise ValueError(f"{x.shape} rows != {labels.shape} labels")
     if save_s is None:
-        save_s = _auto_save_s(xn.shape[0], v_local, block_n, block_v)
+        save_s = _auto_save_s(xn.shape[0], d, v_local, x.dtype, w.dtype,
+                              block_n, block_v)
     b = jnp.zeros((v_local,), w.dtype) if bias is None else bias
     return _fused_xent_sharded_jit(
         xn, w, b, ln, axis_name, block_n, block_v, interpret, save_s
