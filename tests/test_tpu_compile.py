@@ -463,6 +463,41 @@ def test_window_and_full_layers_prefill_chunk_leaves_the_cache_in_place(topo):
     assert max(_copied_bytes(text)) < ring.size * ring.dtype.itemsize
 
 
+def test_latent_layers_decode_step_reads_the_latent_cache_once_where_it_lies(
+        topo, on_chip_kernel):
+    """DeepSeek-V2's widths, one latent + dense and one latent + expert layer:
+    the step writes each latent cache by a scatter and reads it with
+    ``decode_attn_latent``, ONE cache operand a call (key whole, value its first
+    512 lanes); nothing as large as a layer's W_UKV is copied, so neither the
+    cache (donated, 2.7 GB a layer) nor a weight is."""
+    from tpudml.models import HybridLM
+    from tpudml.serve.engine import make_stateful_decode_step
+
+    model = HybridLM(
+        vocab_size=1024, pattern="LDLE", embed_dim=5120, num_heads=128, q_rank=1536,
+        kv_rank=512, nope_dim=128, rope_dim=64, v_head_dim=128,
+        yarn=(40, 4096, 32, 1, 0.707, 0.707), dense_dim=1024, num_experts=160, top_k=6,
+        expert_dim=1536, shared_dim=3072, gated_experts=True, routed_scale=16.0,
+        norm_topk=False, held=(0, 4), moe_scoring="softmax", moe_groups=(8, 3), eps=1e-6,
+        dtype=bf16)
+    slots, rows = 256, 4096
+    assert model.cache_forms(rows, "bf16") == (True, True)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    caches = jax.eval_shape(lambda: model.init_decode_cache(slots, rows, "bf16"))
+    assert caches[0].rows.shape == (256, 4096, 640) and caches[1] is None
+    state = jax.ShapeDtypeStruct((3, slots), i32, sharding=one)
+    text = make_stateful_decode_step(model).lower(
+        _described(params, one), _described(caches, one), state).compile().as_text()
+    assert " scatter(" in text and " while(" not in text
+    calls = re.findall(r" custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == 2 and all("decode_attn_latent" in c for c in calls)
+    assert all(c.count("bf16[256,4096,640]") == 1 for c in calls)  # read once: one operand
+    kv_up = params["layer0"]["mixer"]["kv_up"]["kernel"]
+    assert kv_up.shape == (128, 512, 256)  # W_UK and W_UV are its halves, held once
+    assert max(_copied_bytes(text)) <= kv_up.size * kv_up.dtype.itemsize
+
+
 def test_shared_cache_model_decode_step_copies_neither_the_table_nor_a_cache(
         topo, on_chip_kernel):
     """Phi-4-mini-flash-reasoning's widths, one layer of each kind, all

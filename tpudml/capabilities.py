@@ -303,8 +303,8 @@ _ENTRIES = (
         message=(
             "a pattern model (tpudml.models.hybrid) serves with "
             "cache_layout='dense' only: the page pool holds K/V pages and "
-            "has no page for a recurrent state, nor a rule for sharing "
-            "one across a prefix"
+            "has no page for a recurrent state or a latent row, nor a rule "
+            "for sharing one across a prefix"
         ),
         when=lambda c: bool(_g(c, "serve_pattern"))
         and _g(c, "serve_cache_layout", "dense") != "dense",
@@ -315,7 +315,8 @@ _ENTRIES = (
         message=(
             "a pattern model does not compose with spec_k>0 yet: a "
             "rejected draft token has already advanced the recurrent "
-            "state, and the verify window has no way to roll it back"
+            "state, and the verify window has no way to roll it back (nor "
+            "has a latent layer a window step)"
         ),
         when=lambda c: bool(_g(c, "serve_pattern"))
         and _g(c, "serve_spec_k", 0) > 0,
@@ -326,7 +327,8 @@ _ENTRIES = (
         message=(
             "a pattern model does not compose with tensor-parallel "
             "serving yet: TPServing's shard_map body knows the GPT block "
-            "only (no recurrent state, no expert exchange)"
+            "only (no recurrent state, no expert exchange, no latent cache, "
+            "which has no head axis to shard)"
         ),
         when=lambda c: bool(_g(c, "serve_pattern")) and bool(_g(c, "serve_tp")),
     ),
@@ -382,6 +384,17 @@ _ENTRIES = (
             "carries no per-row scales yet"
         ),
         when=lambda c: bool(_g(c, "serve_pattern_window"))
+        and str(_g(c, "serve_cache_kind", "f32")).startswith("int8"),
+    ),
+    Capability(
+        key="serve_pattern_latent_int8",
+        owner="tpudml.models.hybrid",
+        message=(
+            "a pattern model with latent attention layers (`L`) does not "
+            "store its cache int8: a latent row is key and value of every "
+            "head at once and has no per-head scale to quantize by"
+        ),
+        when=lambda c: bool(_g(c, "serve_pattern_latent"))
         and str(_g(c, "serve_cache_kind", "f32")).startswith("int8"),
     ),
     Capability(

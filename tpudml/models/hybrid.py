@@ -7,7 +7,9 @@ or, ``gated_experts``, SwiGLU experts; a shared expert where ``shared_dim`` is
 not 0), ``D`` a dense gated feed-forward (`tpudml.nn.layers.GatedMLP`), ``G`` a
 gated memory unit (`tpudml.nn.layers.GatedMemoryUnit`), and causal attention
 with an explicit head size: ``*`` without positional encoding, ``F`` full and
-``W`` windowed, and ``X`` cross attention (a query projection only).
+``W`` windowed, ``X`` cross attention (a query projection only), and ``L``
+latent attention (`tpudml.nn.attention.LatentAttention`: low-rank queries, one
+compressed K/V row a token beside a rotary key all heads share, YaRN RoPE).
 
 ``F`` and ``W`` come in two forms. By default they are grouped-query attention
 (`tpudml.nn.attention.MultiHeadAttention`, as ``*``) with RoPE on the head's
@@ -38,8 +40,9 @@ per-slot state (`tpudml.serve.cache`): for ``*`` and ``F`` a ``KVCache`` of
 ``max_len`` rows, for ``W`` a ring of ``window`` rows, with the layer's K/V
 head count and K and V at their stored widths (``stored_width``: a 192-wide
 key in 256 lanes; differential layers with ``pair_rows``: two 64-wide heads a
-128-lane row, a token's rows one after the other); a ``RecurrentState`` for
-``M`` and ``S``; ``None`` for ``E``, ``D``, ``G`` and ``X``. ``cache_forms``,
+128-lane row, a token's rows one after the other); for ``L`` a ``LatentCache``
+of ``max_len`` rows, ``kv_rank + rope_dim`` values each as stored; a
+``RecurrentState`` for ``M`` and ``S``; ``None`` for ``E``, ``D``, ``G`` and ``X``. ``cache_forms``,
 ``cache_bytes`` and ``live_rows`` tell the engine what its
 ``serve/dispatch`` span says of them. Because a
 recurrent state or a ring has no mask to hide stale or padded tokens behind,
@@ -61,7 +64,8 @@ routes, is kept of a chunk. A pattern that ends in layers which only read
 
 ``held = (first, count)`` gives every ``E`` layer one chip's share of the
 experts (`SigmoidMoE`): it routes over all ``num_experts`` and computes its
-own experts' part.
+own experts' part. ``moe_scoring`` and ``moe_groups`` are its ``scoring`` and
+``groups``; with groups the decode step's counters gain ``moe_group_hit``.
 """
 
 from __future__ import annotations
@@ -76,13 +80,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpudml.capabilities import reject
-from tpudml.nn.attention import DifferentialAttention, MultiHeadAttention
+from tpudml.nn.attention import (DifferentialAttention, LatentAttention,
+                                 MultiHeadAttention)
 from tpudml.nn.layers import GatedMemoryUnit, GatedMLP, LayerNorm, Module, RMSNorm
 from tpudml.nn.mamba import Mamba1, Mamba2
 from tpudml.nn.moe import SigmoidMoE
 
-KINDS = "ME*FWDSGX"
+KINDS = "ME*FWDSGXL"
 ATTENTION = "*FW"  # the kinds that own a K/V cache
+LATENT = "L"  # the kind that owns a latent cache
 RECURRENT = "MS"  # the kinds that own a recurrent state
 
 
@@ -118,6 +124,15 @@ class HybridLM(Module):
     window_kv_heads: int = 2
     window_rope_base: float = 1e4
     window_sink: bool = True
+    # latent attention (`L`): heads as `*`, the value head `v_head_dim` (None:
+    # nope_dim); yarn = (factor, original context, beta_fast, beta_slow,
+    # mscale, mscale_all_dim), None: plain RoPE
+    q_rank: int = 48
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    latent_rope_base: float = 1e4
+    yarn: tuple | None = None
     # dense gated feed-forward (`D`)
     dense_dim: int = 128
     # Mamba-1 (`S`; state_size and conv_kernel as `M`) and the memory units (`G`)
@@ -139,6 +154,8 @@ class HybridLM(Module):
     routed_scale: float = 1.0
     norm_topk: bool = True
     held: tuple[int, int] | None = None
+    moe_scoring: str = "sigmoid"  # "softmax": no selection bias
+    moe_groups: tuple[int, int] | None = None  # (n_group, topk_group): `SigmoidMoE.groups`
     norm: str = "rms"  # "layer": LayerNorm with a bias
     tied: bool = False  # the head is the embedding
     eps: float = 1e-5
@@ -147,8 +164,12 @@ class HybridLM(Module):
 
     # What ServingEngine reads (see the module docstring).
     stateful = True
-    counter_names = ("moe_routed", "moe_held", "experts_touched", "expert_load_max")
     max_positions = None  # no position table bounds the cache
+
+    @property
+    def counter_names(self) -> tuple[str, ...]:
+        names = ("moe_routed", "moe_held", "experts_touched", "expert_load_max")
+        return names + ("moe_group_hit",) if self.moe_groups else names
 
     def __post_init__(self):
         bad = set(self.pattern) - set(KINDS)
@@ -180,7 +201,7 @@ class HybridLM(Module):
         """Pattern entries a prefill chunk runs: up to the last that writes
         per-slot state or reports routes (the module docstring)."""
         return 1 + max((i for i, k in enumerate(self.pattern)
-                        if k in ATTENTION + RECURRENT + "E"), default=-1)
+                        if k in ATTENTION + LATENT + RECURRENT + "E"), default=-1)
 
     def _source(self, i: int) -> int:
         """The entry whose K/V cache the ``X`` at entry i reads."""
@@ -205,9 +226,14 @@ class HybridLM(Module):
             return SigmoidMoE(self.embed_dim, self.num_experts, self.top_k,
                               self.expert_dim, self.shared_dim, self.routed_scale,
                               self.norm_topk, self.held, self.dtype,
-                              self.gated_experts)
+                              self.gated_experts, self.moe_scoring, self.moe_groups)
         if kind == "D":
             return GatedMLP(self.embed_dim, self.dense_dim, self.dtype)
+        if kind == "L":
+            return LatentAttention(
+                self.embed_dim, self.num_heads, self.q_rank, self.kv_rank, self.nope_dim,
+                self.rope_dim, self.v_head_dim or self.nope_dim, self.latent_rope_base,
+                self.yarn, self.eps, self.dtype)
         if self.differential and kind in "FWX":
             depth = sum(k not in "DE" for k in self.pattern[:i])
             return DifferentialAttention(
@@ -283,10 +309,13 @@ class HybridLM(Module):
         """The per-layer cache tuple for ``batch`` slots. ``kind`` governs
         the K/V caches only; a recurrent state is ``state_dtype`` and its
         convolution window the stream's dtype."""
-        from tpudml.serve.cache import init_cache, init_recurrent_state
+        from tpudml.serve.cache import (init_cache, init_latent_cache,
+                                        init_recurrent_state)
 
         if kind.startswith("int8") and "W" in self.pattern:
             reject("serve_pattern_ring_int8")
+        if kind.startswith("int8") and "L" in self.pattern:
+            reject("serve_pattern_latent_int8")
 
         def make(layer: str):
             if layer in ATTENTION:
@@ -294,6 +323,8 @@ class HybridLM(Module):
                 if self.differential and self.pair_rows:  # flat: `DifferentialAttention`
                     rows, kv_heads = rows * kv_heads, 1
                 return init_cache(batch, rows, kv_heads, k_dim, kind, v_dim)
+            if layer == "L":
+                return init_latent_cache(batch, max_len, self.kv_rank + self.rope_dim, kind)
             if layer == "M":
                 m = self._mixer("M")
                 return init_recurrent_state(
@@ -326,23 +357,33 @@ class HybridLM(Module):
     def cache_forms(self, max_len: int, kind: str) -> tuple[bool, bool]:
         """(row_scatter, decode_kernel): whether EVERY attention layer's
         decode step writes its rows by one scatter, and reads them with the
-        kernel (`tpudml.serve.cache`); what ``serve/dispatch`` reports."""
-        from tpudml.serve.cache import decode_kernel, kernel_block, row_scatter
+        kernel (`tpudml.serve.cache`); what ``serve/dispatch`` reports. A
+        latent cache answers as one K/V head ``stored_width`` wide whose value
+        is its first ``kv_rank`` lanes."""
+        from tpudml.serve.cache import (decode_kernel, kernel_block, row_scatter,
+                                        stored_width)
 
         shapes = [self._cache_shape(k, max_len) for k in self.pattern if k in ATTENTION]
+        if "L" in self.pattern:
+            shapes.append((max_len, 1, stored_width(self.kv_rank + self.rope_dim),
+                           self.kv_rank))
         reads = kernel_block if self.differential else decode_kernel
         return (all(row_scatter(k) and row_scatter(v) for _, _, k, v in shapes),
                 all(bool(reads(kind, rows, kv_heads, self.num_heads, k, v))
                     for rows, kv_heads, k, v in shapes))
 
     def cache_bytes(self, caches) -> dict:
-        """Allocated bytes of the ``max_len``-row K/V caches and of the rings."""
+        """Allocated bytes of the ``max_len``-row K/V caches, of the rings and
+        (a model with ``L`` layers) of the latent caches."""
         from tpudml.serve.cache import cache_bytes
 
         by = {"cache_bytes_full": 0, "cache_bytes_window": 0}
+        if "L" in self.pattern:
+            by["cache_bytes_latent"] = 0
         for layer, c in zip(self.pattern, caches):
-            if layer in ATTENTION:
-                by["cache_bytes_window" if layer == "W" else "cache_bytes_full"] += cache_bytes(c)
+            if layer in ATTENTION + LATENT:
+                by["cache_bytes_" + {"W": "window", "L": "latent"}.get(layer, "full")] += (
+                    cache_bytes(c))
         return by
 
     def live_rows(self, pos, max_len: int) -> dict:
@@ -352,13 +393,17 @@ class HybridLM(Module):
         rings, and ``rows_read_full``, the full caches' live rows times the
         layers that read them (an ``X`` reads its ``F``'s; equal to
         ``rows_full`` without one). ``state_bytes``: the recurrent state of
-        those slots, which the step reads and writes back."""
+        those slots, which the step reads and writes back. A model with ``L``
+        layers adds ``rows_latent``, the latent caches' live rows."""
         ring = min(self.window, max_len)
         full = int((pos + 1).sum())
-        return {"rows_full": full * sum(k in "*F" for k in self.pattern),
+        rows = {"rows_full": full * sum(k in "*F" for k in self.pattern),
                 "rows_window": int(np.minimum(pos + 1, ring).sum()) * self.pattern.count("W"),
                 "rows_read_full": full * sum(k in "*FX" for k in self.pattern),
                 "state_bytes": len(pos) * self._state_bytes_slot}
+        if "L" in self.pattern:
+            rows["rows_latent"] = full * self.pattern.count("L")
+        return rows
 
     @cached_property
     def _state_bytes_slot(self) -> int:
@@ -394,7 +439,7 @@ class HybridLM(Module):
                 out = mixer.forward(p, u, made["m"])
             elif kind == "X":
                 out, _ = mixer.apply_prefill(p, new[self._source(i)], u, slot, start)
-            elif kind in ATTENTION:
+            elif kind in ATTENTION + LATENT:
                 out, new[i] = mixer.apply_prefill(p, caches[i], u, slot, start, n_real)
             elif kind == "D":
                 out = mixer.apply(p, {}, u)[0]
@@ -417,7 +462,9 @@ class HybridLM(Module):
         slots only: ``moe_routed`` (token, choice) pairs, ``moe_held`` of
         them on held experts, ``experts_touched`` held experts with at
         least one token (summed over layers), ``expert_load_max`` the most
-        tokens on one expert of one layer."""
+        tokens on one expert of one layer; with ``moe_groups`` also
+        ``moe_group_hit``, (token, layer) pairs whose kept groups include a
+        held expert's."""
         new = list(caches)
         seen, made = [], {}
 
@@ -431,7 +478,7 @@ class HybridLM(Module):
             elif kind == "X":  # the F's cache as this step's F left it; unwritten
                 at = self._source(i)
                 out, new[at] = mixer.apply_decode(p, new[at], u, pos)
-            elif kind in ATTENTION:
+            elif kind in ATTENTION + LATENT:
                 out, new[i] = mixer.apply_decode(p, caches[i], u, pos)
             elif kind == "D":
                 out = mixer.apply(p, {}, u)[0]
@@ -447,6 +494,7 @@ class HybridLM(Module):
             "moe_held": sum((c["held"] for c in seen), zero),
             "experts_touched": sum((c["touched"] for c in seen), zero),
             "expert_load_max": jnp.max(jnp.stack([c["load_max"] for c in seen] or [zero])),
+            "moe_group_hit": sum((c["group_hit"] for c in seen), zero),
         }
         routes = self._routes([c["choices"] for c in seen], tokens.shape[0])
         return self._logits(params, h)[:, 0, :], tuple(new), counters, routes

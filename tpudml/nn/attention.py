@@ -16,14 +16,16 @@ framework, so attention is built TPU-first from the start:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpudml.comm.collectives import axis_size
-from tpudml.nn.layers import Dense, Module
+from tpudml.nn.layers import Dense, Module, RMSNorm
 
 NEG_INF = -1e30  # large-finite mask value: avoids inf-inf → NaN in softmax
 
@@ -57,15 +59,54 @@ def rotary_embedding(
     its own depth.
     """
     d = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(d, dtype=jnp.float32) / d)  # [d]
+    return rotary_by_table(x, positions, base ** (-jnp.arange(d, dtype=jnp.float32) / d))
+
+
+def rotary_by_table(
+    x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float = 1.0
+) -> jax.Array:
+    """:func:`rotary_embedding` from the table itself: ``inv_freq`` [D / 2],
+    the angle a position turns lane pair (i, i + D / 2) by (YaRN's are not
+    powers of one base: :func:`yarn_inv_freq`); ``factor`` scales cos and
+    sin."""
+    d = x.shape[-1] // 2
     # [T, d] or [B, T, d]; the batch dim (if any) then aligns with x's.
-    angles = positions.astype(jnp.float32)[..., :, None] * freqs
-    cos = jnp.cos(angles)[..., :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., :, None, :].astype(x.dtype)
+    angles = positions.astype(jnp.float32)[..., :, None] * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = factor * cos, factor * sin
+    cos = cos[..., :, None, :].astype(x.dtype)
+    sin = sin[..., :, None, :].astype(x.dtype)
     if positions.ndim == 1:
         cos, sin = cos[None], sin[None]
     x1, x2 = x[..., :d], x[..., d:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 where
+    nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary table [dim / 2] float32 (Peng et al. 2023, as DeepSeek-V2
+    uses it): pair i turns at ``(1 - r_i) * base^(-2i/dim) / factor + r_i *
+    base^(-2i/dim)`` with ``r_i = 1 - clip((i - lo) / (hi - lo), 0, 1)``,
+    ``lo`` / ``hi`` the floored / ceiled pair indices (clipped to [0, dim - 1])
+    that make ``beta_fast`` / ``beta_slow`` turns over the ``original``
+    context: fast pairs keep their frequency, slow ones are interpolated by
+    ``factor``. ``factor`` 1 is plain RoPE."""
+    def pair_of(turns: float) -> float:
+        return dim * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    pairs = np.arange(dim // 2, dtype=np.float32)
+    plain = np.float32(base) ** (-2.0 * pairs / dim)
+    keep = 1.0 - np.clip((pairs - lo) / ((hi - lo) or 0.001), 0.0, 1.0)
+    return ((1.0 - keep) * plain / factor + keep * plain).astype(np.float32)
 
 
 def dot_product_attention(
@@ -914,3 +955,186 @@ class DifferentialAttention(Module):
         k, v = read_slot_prefix(cache, slot, (start + c) * per, x.dtype)
         a1, a2 = self._attend(q, k, v, q_pos, jnp.arange(start + c))
         return self._finish(params, a1, a2, x.dtype), cache
+
+
+# ------------------------------------------------------- latent attention
+
+
+@dataclass(frozen=True)
+class LatentAttention(Module):
+    """Causal multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434)
+    with its serving paths over a `tpudml.serve.cache.LatentCache`. On the
+    normed stream x:
+
+        c_q = RMSNorm(x W_DQ);  [q_nope | q_rope]_h = c_q W_UQ   (H heads)
+        [c_kv | k_r] = x W_DKV; c_kv = RMSNorm(c_kv); k_r = RoPE(k_r)
+        [k_nope | v]_h = c_kv W_UKV;  k_h = [k_nope_h | k_r]
+        out = concat_h softmax(q_h . k_h * s) v_h  W_O
+
+    ``k_r`` is ONE rotary key that all heads share, so what a token leaves
+    behind is ``[c_kv | k_r]``, ``kv_rank + rope_dim`` values a layer (576
+    against 128 heads x (192 + 128)), and that row is what the cache holds:
+    ``c_kv`` after its norm, ``k_r`` after RoPE. RoPE turns ``rope_dim`` lanes by
+    a table (:func:`rotary_by_table`), YaRN's where ``yarn = (factor, original
+    context, beta_fast, beta_slow, mscale, mscale_all_dim)`` is given; the
+    softmax scale is ``(nope_dim + rope_dim)^-0.5 * m^2`` with ``m =
+    yarn_mscale(factor, mscale_all_dim)`` (`_scale`), handed to every path as
+    the caller's ``scale``.
+
+    Three paths. ``apply`` (the whole sequence) and ``apply_prefill`` (a chunk
+    over the slot's cached prefix and itself) run the published form: the rows
+    are expanded through ``W_UKV`` to per-head keys and values and attended
+    with :func:`attention_by_position` at 192 / 128. A chunk of C queries makes
+    the absorbed form cost ``C * H * (kv_rank + rope_dim + kv_rank)`` a cached
+    row against the expansion's ``kv_rank * H * (nope_dim + v_dim)`` plus ``C *
+    H * (nope_dim + rope_dim + v_dim)``: the expansion is fewer operations
+    from C > 170 at the published sizes (1.9x at the engine's 512), and the
+    only form here. ``apply_decode`` is ABSORBED: ``q~_h = q_nope_h W_UK,h^T``
+    [kv_rank], ``score = q~_h . c_kv + q_rope_h . k_r``, ``o_h = (P c_kv)
+    W_UV,h``: H query heads over one cached row a token whose first
+    ``kv_rank`` lanes are also the value, read once where it lies by
+    `tpudml.ops.decode_attn.decode_attn_latent` (an einsum off the TPU).
+    ``W_UK`` and ``W_UV`` are the two halves of the stored ``kv_up`` [H,
+    kv_rank, nope_dim + v_dim]; there is no second copy of it."""
+
+    embed_dim: int
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_base: float = 10000.0
+    yarn: tuple | None = None
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.rope_dim % 2:
+            raise ValueError(f"rope requires an even rotary width, got {self.rope_dim}")
+        if self.yarn is not None and len(self.yarn) != 6:
+            raise ValueError("yarn = (factor, original, beta_fast, beta_slow, mscale, "
+                             "mscale_all_dim)")
+
+    @property
+    def row_width(self) -> int:
+        """Values a token leaves in the cache: ``[c_kv | k_r]``."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def _scale(self) -> float:
+        m = yarn_mscale(self.yarn[0], self.yarn[5]) if self.yarn else 1.0
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+    def _rope(self, x, positions):
+        """RoPE over the whole of x [B, T, H, rope_dim]."""
+        if self.yarn is None:
+            return rotary_embedding(x, positions, self.rope_base)
+        factor, original, fast, slow, mscale, mscale_all = self.yarn
+        table = yarn_inv_freq(self.rope_dim, self.rope_base, factor, original, fast, slow)
+        return rotary_by_table(x, positions, table,
+                               yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all))
+
+    def init(self, key):
+        keys = jax.random.split(key, 5)
+        h, n = self.num_heads, self.nope_dim + self.v_dim
+
+        def kernel(k, rows, cols):
+            return Dense(rows, cols, False, dtype=self.dtype).init(k)[0]
+
+        kv_up = kernel(keys[3], self.kv_rank, h * n)["kernel"]
+        ones = lambda width: RMSNorm(width, self.eps, self.dtype).init(key)[0]  # noqa: E731
+        return {
+            "q_down": kernel(keys[0], self.embed_dim, self.q_rank),
+            "q_norm": ones(self.q_rank),
+            "q_up": kernel(keys[1], self.q_rank, h * (self.nope_dim + self.rope_dim)),
+            "kv_down": kernel(keys[2], self.embed_dim, self.row_width),
+            "kv_norm": ones(self.kv_rank),
+            "kv_up": {"kernel": kv_up.reshape(self.kv_rank, h, n).transpose(1, 0, 2)},
+            "out": kernel(keys[4], h * self.v_dim, self.embed_dim),
+        }, {}
+
+    # ------------------------------------------------------------ pieces
+
+    def _norm(self, p, x):
+        return RMSNorm(x.shape[-1], self.eps, self.dtype).apply(p, {}, x)[0]
+
+    def _queries(self, params, x, positions):
+        """(q_nope [B, T, H, nope_dim], q_rope [B, T, H, rope_dim] turned)."""
+        b, t, _ = x.shape
+        q = self._norm(params["q_norm"], x @ params["q_down"]["kernel"]) @ params["q_up"]["kernel"]
+        if (self.nope_dim + self.rope_dim) % 128:
+            # A 192-wide head: keep the chip's compiler from transposing the
+            # weight for it (`MultiHeadAttention._project`; PERF.md §6, PR 37).
+            q = jax.lax.optimization_barrier(q)
+        q = q.reshape(b, t, self.num_heads, self.nope_dim + self.rope_dim)
+        return q[..., :self.nope_dim], self._rope(q[..., self.nope_dim:], positions)
+
+    def latent_rows(self, params, x, positions):
+        """[B, T, row_width]: what x [B, T, d] at ``positions`` leaves in the
+        cache, ``c_kv`` after its norm beside ``k_r`` after RoPE."""
+        down = x @ params["kv_down"]["kernel"]
+        k_r = self._rope(down[..., None, self.kv_rank:], positions)[..., 0, :]
+        return jnp.concatenate(
+            [self._norm(params["kv_norm"], down[..., :self.kv_rank]), k_r], axis=-1)
+
+    def _attend_expanded(self, params, q_nope, q_rope, rows, q_pos, k_pos):
+        """The published form: q [B, Tq, H, .] at ``q_pos`` over latent
+        ``rows`` [B, Tk, >= row_width] at ``k_pos`` -> out [B, Tq, d]."""
+        b, tk = rows.shape[:2]
+        kv = jnp.einsum("btr,hrn->bthn", rows[..., :self.kv_rank], params["kv_up"]["kernel"])
+        k_r = jnp.broadcast_to(rows[:, :, None, self.kv_rank:self.row_width],
+                               (b, tk, self.num_heads, self.rope_dim))
+        o = attention_by_position(
+            jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([kv[..., :self.nope_dim], k_r], axis=-1),
+            kv[..., self.nope_dim:], q_pos, k_pos, scale=self._scale)
+        return o.reshape(b, q_nope.shape[1], -1) @ params["out"]["kernel"]
+
+    # ------------------------------------------------------------- paths
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        at = jnp.arange(x.shape[1])
+        return self._attend_expanded(params, *self._queries(params, x, at),
+                                     self.latent_rows(params, x, at), at, at), state
+
+    def apply_prefill(self, params, cache, x, slot, start: int, n_real=None):
+        """Prefill one chunk of one slot: x [1, C, d] at positions [start,
+        start + C) (``start`` static). Writes the chunk's latent rows, then
+        attends over the slot's rows [0, start + C) as the cache holds them
+        (its own among them, rounded as stored: what decode will read).
+        Returns (out [1, C, d], the cache). A padded tail lands in rows the
+        mask hides until decode overwrites them, as in a K/V cache."""
+        from tpudml.serve.cache import read_latent_prefix, write_latent_chunk
+
+        c = x.shape[1]
+        at = start + jnp.arange(c)
+        cache = write_latent_chunk(cache, self.latent_rows(params, x, at), slot, start)
+        rows = read_latent_prefix(cache, slot, start + c, x.dtype)
+        return self._attend_expanded(params, *self._queries(params, x, at), rows, at,
+                                     jnp.arange(start + c)), cache
+
+    def apply_decode(self, params, cache, x, pos):
+        """One decode step, absorbed: x [B, 1, d] at per-slot positions
+        ``pos`` [B]. Writes the token's latent row at ``pos``, reads the cache
+        once, returns (out [B, 1, d], the cache)."""
+        from tpudml.serve.cache import decode_kernel, fit_width, write_latent_token
+
+        b, r = x.shape[0], self.kv_rank
+        q_nope, q_rope = self._queries(params, x, pos[:, None])
+        cache = write_latent_token(cache, self.latent_rows(params, x, pos[:, None]), pos)
+        kv_up = params["kv_up"]["kernel"]  # [H, r, nope | v]: W_UK and W_UV where they lie
+        q = jnp.concatenate(
+            [jnp.einsum("hrn,bqhn->bqhr", kv_up[..., :self.nope_dim], q_nope), q_rope], axis=-1)
+        q = fit_width(q, cache.rows.shape[-1])  # the stored row's zero lanes
+        if decode_kernel(cache.kind, cache.max_len, 1, self.num_heads, cache.rows.shape[-1], r):
+            from tpudml.ops.decode_attn import decode_attn_latent, kernel_interpret
+
+            o = decode_attn_latent(q, cache.rows, pos, v_dim=r, scale=self._scale,
+                                   interpret=kernel_interpret())
+        else:
+            rows = cache.rows.astype(x.dtype)[:, :, None, :]
+            o = attention_by_position(q, rows, rows[..., :r], pos[:, None],
+                                      jnp.arange(cache.max_len), scale=self._scale)
+        o = jnp.einsum("bqhr,hrv->bqhv", o, kv_up[..., self.nope_dim:])
+        return o.reshape(b, 1, -1) @ params["out"]["kernel"], cache

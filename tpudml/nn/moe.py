@@ -393,7 +393,13 @@ class SigmoidMoE(Module):
     In float32, ``s = sigmoid(u @ W_r)`` over the router's whole width
     ``num_experts``; a token's experts are the top-k of ``s + bias`` (the
     bias only chooses); their weights are ``routed_scale * s_e / (sum of
-    the k chosen s + 1e-20)`` (``norm_topk``). An expert is two matrices,
+    the k chosen s + 1e-20)`` (``norm_topk``; without it, ``routed_scale *
+    s_e``). ``scoring="softmax"``: ``s = softmax(u @ W_r)`` and no bias.
+    ``groups = (n_group, topk_group)`` limits the choice (DeepSeek-V2's
+    ``group_limited_greedy``): the experts are ``n_group`` equal groups in
+    order, a group's score is its largest choosing score, the best
+    ``topk_group`` groups stay (ties: the first), every other group's scores
+    are set to 0 before the top-k. An expert is two matrices,
     ``relu(u @ up)^2 @ down``, or with ``gated`` three, ``(silu(u @ gate) *
     (u @ up)) @ down`` (SwiGLU); the shared expert, of width ``shared_dim``
     and of the same form, sees every token (``shared_dim`` 0: there is none).
@@ -428,8 +434,17 @@ class SigmoidMoE(Module):
     held: tuple[int, int] | None = None
     dtype: Any = jnp.float32
     gated: bool = False
+    scoring: str = "sigmoid"
+    groups: tuple[int, int] | None = None
 
     def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring must be 'sigmoid' or 'softmax', got {self.scoring!r}")
+        if self.groups is not None:
+            n_group, keep = self.groups
+            if self.num_experts % n_group or not 1 <= keep <= n_group:
+                raise ValueError(f"groups {self.groups}: {self.num_experts} experts in "
+                                 "n_group equal groups, of which 1..n_group stay")
         first, count = self._held
         if not (0 <= first and count >= 1 and first + count <= self.num_experts):
             raise ValueError(
@@ -448,11 +463,12 @@ class SigmoidMoE(Module):
         kr, ku, kd, ksu, ksd = jax.random.split(key, 5)
         params = {
             "router": {"kernel": _uniform_fan_in(kr, (d, self.num_experts), d,
-                                                 jnp.float32),
-                       "bias": jnp.zeros((self.num_experts,), jnp.float32)},
+                                                 jnp.float32)},
             "experts": {"up": _uniform_fan_in(ku, (n, d, h), d, self.dtype),
                         "down": _uniform_fan_in(kd, (n, h, d), h, self.dtype)},
         }
+        if self.scoring == "sigmoid":  # the selection bias of an aux-free balancer
+            params["router"]["bias"] = jnp.zeros((self.num_experts,), jnp.float32)
         if hs:
             params["shared"] = {
                 "up": _uniform_fan_in(ksu, (d, hs), d, self.dtype),
@@ -474,26 +490,47 @@ class SigmoidMoE(Module):
 
     def scores(self, params, tokens):
         """s [G, num_experts] float32 of tokens [G, d], computed in float32."""
-        return jax.nn.sigmoid(jnp.dot(
-            tokens.astype(jnp.float32), params["router"]["kernel"],
-            precision=lax.Precision.HIGHEST))
+        logits = jnp.dot(tokens.astype(jnp.float32), params["router"]["kernel"],
+                         precision=lax.Precision.HIGHEST)
+        if self.scoring == "softmax":
+            return jax.nn.softmax(logits, axis=-1)
+        return jax.nn.sigmoid(logits)
 
-    def route(self, params, tokens):
-        """(experts [G, k] int32, weights [G, k] float32) of tokens [G, d]."""
+    def kept_groups(self, select):
+        """[G, n_group] bool: the ``topk_group`` groups whose largest of
+        ``select`` [G, num_experts] is largest."""
+        n_group, keep = self.groups
+        best = jnp.max(select.reshape(select.shape[0], n_group, -1), axis=-1)
+        _, top = lax.top_k(best, keep)
+        return jnp.zeros(best.shape, bool).at[jnp.arange(best.shape[0])[:, None], top].set(True)
+
+    def _route(self, params, tokens):
+        """`route`, and the kept groups [G, n_group] (None without groups)."""
         s = self.scores(params, tokens)
-        _, topi = lax.top_k(s + params["router"]["bias"], self.top_k)
+        select = s + params["router"]["bias"] if "bias" in params["router"] else s
+        kept = None
+        if self.groups is not None:
+            kept = self.kept_groups(select)
+            select = jnp.where(jnp.repeat(kept, self.num_experts // self.groups[0], axis=1),
+                               select, 0.0)
+        _, topi = lax.top_k(select, self.top_k)
         w = jnp.take_along_axis(s, topi, axis=-1)
         if self.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return topi.astype(jnp.int32), self.routed_scale * w
+        return topi.astype(jnp.int32), self.routed_scale * w, kept
+
+    def route(self, params, tokens):
+        """(experts [G, k] int32, weights [G, k] float32) of tokens [G, d]."""
+        return self._route(params, tokens)[:2]
 
     def forward(self, params, x, active=None):
         """x [..., d] -> (y [..., d], counts). ``active`` [...] bool marks
         the tokens that count (default all): the others reach no expert.
         ``counts`` are int32 scalars over active tokens: ``routed`` (token,
         choice) pairs, ``held`` of them on held experts, ``touched`` held
-        experts with at least one token, ``load_max`` tokens on the busiest;
-        and ``choices`` [G, k] int32, every token's experts as the router
+        experts with at least one token, ``load_max`` tokens on the busiest,
+        ``group_hit`` the tokens whose kept groups include a held expert's
+        (0 without ``groups``); and ``choices`` [G, k] int32, every token's experts as the router
         chose them (of all ``num_experts``, held or not, active or not).
 
         ``touched`` counts what a step would have to read if it read only
@@ -504,7 +541,7 @@ class SigmoidMoE(Module):
         tokens = x.reshape(-1, self.embed_dim)
         g, k = tokens.shape[0], self.top_k
         first, count = self._held
-        topi, w = self.route(params, tokens)
+        topi, w, kept = self._route(params, tokens)
         live = jnp.ones((g,), bool) if active is None else active.reshape(g)
         local = topi - first
         mine = (local >= 0) & (local < count) & live[:, None]  # [G, k]
@@ -521,7 +558,12 @@ class SigmoidMoE(Module):
             y = y + self._hidden(sh, tokens, "gd,dh->gh") @ sh["down"]
         counts = {"routed": jnp.sum(live).astype(jnp.int32) * k,
                   "held": jnp.sum(load), "touched": jnp.sum(load > 0).astype(jnp.int32),
-                  "load_max": jnp.max(load), "choices": topi}
+                  "load_max": jnp.max(load), "choices": topi,
+                  "group_hit": jnp.zeros((), jnp.int32)}
+        if kept is not None:
+            size = self.num_experts // self.groups[0]
+            mine_groups = kept[:, first // size:(first + count - 1) // size + 1]
+            counts["group_hit"] = jnp.sum(live & jnp.any(mine_groups, axis=1)).astype(jnp.int32)
         return y.reshape(shape), counts
 
     def apply(self, params, state, x, *, train=False, rng=None):

@@ -210,3 +210,60 @@ def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
         name=name,
         interpret=interpret,
     )(pos.astype(jnp.int32), *operands)
+
+
+# ------------------------------------------------------------ latent form
+# Below `decode_attn` on purpose: a Pallas program's fingerprint holds its
+# kernel's source lines, and the K/V form's must not move (PERF.md §6, PR 34).
+
+
+def _latent_kernel(pos_ref, q_ref, c_ref, *rest, v_dim: int, **static):
+    """`_kernel` with ONE cache operand: the row block is the key whole and,
+    in its first ``v_dim`` lanes, the value: a view of the same VMEM block, so
+    the rows come from HBM once."""
+    _kernel(pos_ref, q_ref, c_ref, c_ref.at[:, :, :v_dim], *rest, **static)
+
+
+def decode_attn_latent(q: jax.Array, rows: jax.Array, pos: jax.Array, *,
+                       v_dim: int, scale: float, block: int | None = None,
+                       interpret: bool = False) -> jax.Array:
+    """Absorbed latent attention (`tpudml.nn.attention.LatentAttention`) as
+    one kernel, ``decode_attn_latent`` in a device trace: q [B, 1, H, W]
+    (``[q~ | q_rope]`` and the stored row's zero lanes) over the latent cache
+    ``rows`` [B, L, W] as stored, per-slot positions ``pos`` [B] -> [B, 1, H,
+    v_dim] in q's type: ``softmax(q . row * scale) row[:v_dim]`` over the rows
+    ``<= pos``. All H query heads share the one row a token, so they are the
+    matmul's rows and no mask separates heads; online softmax, mask, float
+    types and ``NEG_INF`` are `decode_attn`'s, whose body this runs. At 128
+    heads x (576 + 512) x 2 operations over a 1,152-byte row (242 FLOP a byte
+    against the v5e's ridge of 240) this is the one form here that the MXU
+    bounds about as much as the memory does. Every row block is read whatever
+    ``pos`` says, as `decode_attn` (ROADMAP.md A1)."""
+    b, _, h, w = q.shape
+    length = rows.shape[1]
+    block = block or block_rows(length, 1)
+    if length % block or rows.shape[-1] != w or not 0 < v_dim <= w:
+        raise ValueError(f"decode_attn_latent: {length} rows in blocks of {block}, "
+                         f"q {w} wide over rows of {rows.shape[-1]}, value {v_dim}")
+    at_slot = lambda i, j, pos: (i, 0, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        partial(_latent_kernel, v_dim=v_dim, scale=scale, block=block, kv_heads=1,
+                group=h, sink=False),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h, v_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, length // block),
+            in_specs=[pl.BlockSpec((None, None, h, w), at_slot),
+                      pl.BlockSpec((1, block, w), lambda i, j, pos: (i, j, 0))],
+            out_specs=pl.BlockSpec((None, None, h, v_dim), at_slot),
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),  # running max
+                pltpu.VMEM((h, 1), jnp.float32),  # running sum
+                pltpu.VMEM((h, v_dim), jnp.float32),  # output accumulator
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attn_latent",
+        interpret=interpret,
+    )(pos.astype(jnp.int32), q, rows)

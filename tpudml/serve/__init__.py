@@ -14,7 +14,7 @@ weight quantization — imported lazily, see ``tpudml.serve.fleet``).
 See docs/API.md §Serving.
 """
 
-from tpudml.serve.cache import KVCache, RecurrentState, cache_bytes, init_cache
+from tpudml.serve.cache import KVCache, LatentCache, RecurrentState, cache_bytes, init_cache
 from tpudml.serve.engine import (
     SERVE_DECODE_MARKER,
     RequestStats,
@@ -64,6 +64,7 @@ __all__ = [
     "FleetRequestStats",
     "FleetRouter",
     "KVCache",
+    "LatentCache",
     "PAGED_DECODE_MARKER",
     "PagePool",
     "PagedKVCache",

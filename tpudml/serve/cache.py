@@ -46,6 +46,10 @@ that keeps nothing between tokens — also one that reads ANOTHER layer's
 cache (a cross layer: the tuple's entry of the layer that owns the cache is
 the only one, and only its owner writes it).
 
+A third kind keeps neither keys nor values: the ``LatentCache`` of a latent
+attention layer, one ``[c_kv | k_r]`` row a token that is key and value at once
+(before the recurrent state, below).
+
 A K/V head count that is no whole sublane tile (ten pair-rows) is stored
 FLAT, ``[B, L * Hkv, 1, D]``: every function here then takes rows, not
 tokens, and the caller multiplies its positions by ``Hkv``
@@ -360,11 +364,68 @@ def read_slot_prefix(cache: KVCache, slot: jax.Array, length: int,
     return k.astype(dtype), v.astype(dtype)
 
 
-def cache_bytes(cache: KVCache) -> int:
-    """Total storage bytes (K + V + scales) — the number the int8 option
-    exists to shrink."""
-    return sum(x.size * x.dtype.itemsize
-               for x in (cache.k, cache.v, cache.k_scale, cache.v_scale))
+def cache_bytes(cache: KVCache | LatentCache) -> int:
+    """Total storage bytes (K + V + scales; a latent cache's rows) — the
+    number the int8 option exists to shrink."""
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+
+
+# -------------------------------------------------------------- latent cache
+# A third kind of per-slot state: what a latent attention layer
+# (`tpudml.nn.attention.LatentAttention`) keeps of a token is ONE row, the
+# compressed K/V beside the shared rotary key, and the row is key and (in its
+# first lanes) value at once. Positions mask it as they mask a K/V cache, so
+# nothing is zeroed when a slot changes hands.
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class LatentCache:
+    """One latent attention layer's cache."""
+
+    rows: jax.Array  # [B, L, W]: a token's [c_kv | k_r] in `stored_width` lanes, the rest zero
+    kind: str = field(metadata=dict(static=True))
+
+    @property
+    def max_len(self) -> int:
+        return self.rows.shape[1]
+
+
+def init_latent_cache(batch: int, max_len: int, row_width: int,
+                      kind: str = "f32") -> LatentCache:
+    """``max_len`` rows a slot, each ``row_width`` values in whole 128-lane
+    tiles (`stored_width`: 576 in 640), so that the row scatter and the decode
+    kernel hold for it as for a K/V cache. Stored in a float type: a row has
+    no per-head scale to quantize by."""
+    if kind not in KINDS or kind.startswith("int8"):
+        raise ValueError(f"a latent cache is stored {KINDS[:2]} or bf16_sim, not {kind!r}")
+    return LatentCache(jnp.zeros((batch, max_len, stored_width(row_width)),
+                                 _store_dtype(kind)), kind)
+
+
+def _latent_rows(cache: LatentCache, rows: jax.Array) -> jax.Array:
+    return fit_width(_encode(rows, cache.kind)[0], cache.rows.shape[-1])
+
+
+def write_latent_token(cache: LatentCache, rows: jax.Array, pos: jax.Array) -> LatentCache:
+    """`write_token` for a latent cache: rows [B, Q, row_width] at per-slot
+    rows ``pos`` .. ``pos + Q - 1``."""
+    put = _scatter_rows if row_scatter(cache.rows.shape[-1]) else _update_rows
+    return LatentCache(put(cache.rows, _latent_rows(cache, rows), pos), cache.kind)
+
+
+def write_latent_chunk(cache: LatentCache, rows: jax.Array, slot: jax.Array,
+                       start: int) -> LatentCache:
+    """`write_chunk` for a latent cache: rows [1, C, row_width] into one
+    slot's rows [start, start + C)."""
+    return LatentCache(lax.dynamic_update_slice(
+        cache.rows, _latent_rows(cache, rows), (slot, start, 0)), cache.kind)
+
+
+def read_latent_prefix(cache: LatentCache, slot: jax.Array, length: int, dtype) -> jax.Array:
+    """One slot's first ``length`` rows (static): [1, length, W]."""
+    return lax.dynamic_slice(
+        cache.rows, (slot, 0, 0), (1, length, cache.rows.shape[-1])).astype(dtype)
 
 
 # ----------------------------------------------------------- recurrent state
